@@ -52,6 +52,8 @@ def _emit_json(key: str, rows: list[dict], elapsed_s: float) -> None:
 
 def main() -> None:
     from benchmarks import common
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     wanted = sys.argv[1:] or list(BENCHES)
     failures = []
     for key in wanted:

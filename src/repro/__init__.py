@@ -1,8 +1,1 @@
-"""repro: reproduction of the reconfigurable crossbar training architecture.
-
-Importing the package installs the jax forward-compat shims (repro.dist.compat)
-so code written against jax 0.5+ spellings runs on the pinned 0.4.x.
-"""
-from repro.dist import compat as _compat
-
-_compat.install()
+"""repro: reproduction of the reconfigurable crossbar training architecture."""

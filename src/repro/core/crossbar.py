@@ -44,6 +44,13 @@ CORE_COLS = 100      # max neurons per core (crossbar is 400x200 differential)
 G_ON = 1e-4          # 1/R_on,  R_on  = 10 kOhm
 G_OFF = 1e-7         # 1/R_off, R_off = 10 MOhm (ratio 1000)
 
+# Precision of every crossbar contraction: the reference math here, the
+# simulator's compiled bodies and the Pallas kernels.  The numerics are
+# defined in f32; a TPU's default f32 matmul takes one bf16 pass, whose
+# rounding moves dot products enough to flip 3-bit ADC outputs and pulse
+# counts between two implementations that should agree.
+F32 = jax.lax.Precision.HIGHEST
+
 
 def hard_sigmoid(x: jax.Array) -> jax.Array:
     """h(x) = x/4 clipped to [-0.5, 0.5]  (paper Eq. 3, Fig. 6)."""
@@ -116,12 +123,12 @@ def init_conductances(key: jax.Array, fan_in: int, fan_out: int,
 def _xbar_matmul(x: jax.Array, g_plus: jax.Array, g_minus: jax.Array,
                  spec: CrossbarSpec) -> jax.Array:
     w = reconstruct(g_plus, g_minus)
-    return x @ w
+    return jnp.matmul(x, w, precision=F32)
 
 
 def _xbar_fwd(x, g_plus, g_minus, spec):
     w = reconstruct(g_plus, g_minus)
-    return x @ w, (x, w)
+    return jnp.matmul(x, w, precision=F32), (x, w)
 
 
 def _xbar_bwd(spec, res, dy):
@@ -130,8 +137,9 @@ def _xbar_bwd(spec, res, dy):
         # Paper III.F step 1: errors discretized to 8 bits before being
         # driven back through the crossbar columns (Fig. 9).
         dy = q.error_quantize(dy, spec.err_bits).dequantize()
-    dx = dy @ w.T                       # Eq. 7: delta_prev = W^T delta
-    dw = jnp.einsum("...i,...j->ij", x, dy)  # Eq. 6 outer product (batch-summed)
+    dx = jnp.matmul(dy, w.T, precision=F32)  # Eq. 7: delta_prev = W^T delta
+    # Eq. 6 outer product (batch-summed)
+    dw = jnp.einsum("...i,...j->ij", x, dy, precision=F32)
     # d/dg_plus = +dw, d/dg_minus = -dw: the two columns move oppositely,
     # matching the +dw/2 / -dw/2 hardware update convention.
     return dx, dw, -dw
@@ -191,7 +199,7 @@ def crossbar_apply(params: dict[str, jax.Array], x: jax.Array,
         gpt = gpp.reshape(n_tiles, spec.rows, gp.shape[1])
         gmt = gmp.reshape(n_tiles, spec.rows, gm.shape[1])
         # sub-neuron DPs -> per-tile activation -> aggregation neuron
-        sub = jnp.einsum("...tr,trn->...tn", xt, gpt - gmt)
+        sub = jnp.einsum("...tr,trn->...tn", xt, gpt - gmt, precision=F32)
         sub = hard_sigmoid(sub)
         if spec.transport_quant:  # sub-neuron outputs also ride the network
             sub = q.adc_quantize_ste(sub, spec.adc_bits)
@@ -234,7 +242,8 @@ def paper_backprop_step(layers: list[dict[str, jax.Array]], x: jax.Array,
         if spec.transport_quant and li > 0:
             h = q.adc_quantize_ste(h, spec.adc_bits)
             acts[-1] = h
-        dp = h @ reconstruct(p["g_plus"], p["g_minus"])
+        dp = jnp.matmul(h, reconstruct(p["g_plus"], p["g_minus"]),
+                        precision=F32)
         dps.append(dp)
         h = hard_sigmoid(dp)
         acts.append(h)
@@ -249,7 +258,8 @@ def paper_backprop_step(layers: list[dict[str, jax.Array]], x: jax.Array,
         if spec.error_quant:
             delta = q.error_quantize(delta, spec.err_bits).dequantize()
         local = delta * hard_sigmoid_deriv(dps[li])      # delta_j * f'(DP_j)
-        dw = 2.0 * lr * jnp.einsum("...i,...j->ij", acts[li], local)
+        dw = 2.0 * lr * jnp.einsum("...i,...j->ij", acts[li], local,
+                                   precision=F32)
         if acts[li].ndim > 1:   # batched: average the per-sample updates
             dw = dw / np.prod(acts[li].shape[:-1])
         if spec.update_quant:
@@ -259,7 +269,8 @@ def paper_backprop_step(layers: list[dict[str, jax.Array]], x: jax.Array,
             "g_minus": clip_conductance(p["g_minus"] - 0.5 * dw, spec),
         }
         # back-propagate through this layer's weights (Eq. 5 / Fig. 9)
-        delta = (delta * hard_sigmoid_deriv(dps[li])) @ w.T
+        delta = jnp.matmul(delta * hard_sigmoid_deriv(dps[li]), w.T,
+                           precision=F32)
     return new_layers, target - acts[-1]
 
 
@@ -338,7 +349,8 @@ def paper_backprop_step_scan(stacked: dict[str, jax.Array], x: jax.Array,
         if use_kernel:
             return kernel_ops.crossbar_fwd(h, p["g_plus"], p["g_minus"],
                                            activation=False)
-        return h @ reconstruct(p["g_plus"], p["g_minus"])
+        return jnp.matmul(h, reconstruct(p["g_plus"], p["g_minus"]),
+                          precision=F32)
 
     def fwd_body(h, p):
         dp = matmul(h, p)
@@ -373,13 +385,16 @@ def paper_backprop_step_scan(stacked: dict[str, jax.Array], x: jax.Array,
             delta_prev = kernel_ops.crossbar_bwd(local, p["g_plus"],
                                                  p["g_minus"])
         else:
-            dw = 2.0 * lr_eff * jnp.einsum("...i,...j->ij", a, local)
+            dw = 2.0 * lr_eff * jnp.einsum("...i,...j->ij", a, local,
+                                           precision=F32)
             if spec.update_quant:
                 dw = q.pulse_discretize(dw, spec.max_update,
                                         spec.update_levels, None)
             gp = clip_conductance(p["g_plus"] + 0.5 * dw, spec)
             gm = clip_conductance(p["g_minus"] - 0.5 * dw, spec)
-            delta_prev = local @ reconstruct(p["g_plus"], p["g_minus"]).T
+            delta_prev = jnp.matmul(
+                local, reconstruct(p["g_plus"], p["g_minus"]).T,
+                precision=F32)
         return delta_prev, {"g_plus": gp, "g_minus": gm}
 
     _, new_stacked = jax.lax.scan(bwd_body, delta, (stacked, acts, dps),

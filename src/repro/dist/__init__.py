@@ -5,8 +5,4 @@ sharding.py     ParamSpec trees, logical->physical rules, activation
                 constraints (no-ops off-mesh)
 collectives.py  bf16/int8-compressed gradient all-reduce + pure-DP step
 pipeline.py     GPipe microbatch ring over a mesh axis
-compat.py       jax 0.5+ API spellings on the pinned 0.4.x
 """
-from repro.dist import compat as _compat
-
-_compat.install()

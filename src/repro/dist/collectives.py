@@ -17,10 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.dist import compat
-
-compat.install()
-
 INT8_MAX = 127
 
 

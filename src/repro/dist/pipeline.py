@@ -22,10 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.dist import compat
-
-compat.install()
-
 
 def serial_reference(stage: Callable, params, x: jax.Array) -> jax.Array:
     """Apply the ``n_stages`` stacked stages sequentially to all

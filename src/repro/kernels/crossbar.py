@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.crossbar import F32
+
 # Logical tile: paper's 400(+bias)x100 crossbar, MXU-aligned.
 TILE_ROWS = 512     # fan-in per tile  (paper: 400)
 TILE_COLS = 128     # neurons per tile (paper: 100)
@@ -34,13 +36,14 @@ TILE_M = 128        # batch tile
 
 
 def _dimension_semantics(n_parallel: int, n_arbitrary: int):
-    try:  # only meaningful on real TPU lowering
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * n_parallel
-            + ("arbitrary",) * n_arbitrary)
-    except Exception:
-        return None
+    """TPU compiler parameters for a grid of ``n_parallel`` independent axes
+    followed by ``n_arbitrary`` revisiting (accumulating) axes.  A failure
+    to build them propagates: a kernel lowered without them would be a
+    different program from the one the tests compile."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * n_parallel
+        + ("arbitrary",) * n_arbitrary)
 
 
 def _scale_spec():
@@ -62,7 +65,7 @@ def _fwd_kernel(x_ref, gp_ref, gm_ref, o_ref, *, n_k: int, activation: bool,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     w = gp_ref[...].astype(jnp.float32) - gm_ref[...].astype(jnp.float32)
-    o_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), w,
+    o_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), w, precision=F32,
                           preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
@@ -138,7 +141,7 @@ def _bwd_kernel(*refs, n_k: int, dequant: bool):
     # dy (bm, bn) x w (bk, bn)^T -> (bm, bk)
     o_ref[...] += jax.lax.dot_general(
         dy, w,
-        dimension_numbers=(((1,), (1,)), ((), ())),
+        dimension_numbers=(((1,), (1,)), ((), ())), precision=F32,
         preferred_element_type=jnp.float32)
 
 
@@ -200,7 +203,7 @@ def _dw_kernel(*refs, n_m: int, dequant: bool):
     # x (bm, bk)^T x dy (bm, bn) -> (bk, bn), accumulated over the m axis
     o_ref[...] += jax.lax.dot_general(
         x_ref[...].astype(jnp.float32), dy,
-        dimension_numbers=(((0,), (0,)), ((), ())),
+        dimension_numbers=(((0,), (0,)), ((), ())), precision=F32,
         preferred_element_type=jnp.float32)
 
 
@@ -272,7 +275,8 @@ def _train_kernel(*refs, n_i: int, lr: float,
         y_ref[...] = jnp.zeros_like(y_ref)
 
     if compute_y:
-        y_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+        y_ref[...] += jnp.dot(x, w, precision=F32,
+                              preferred_element_type=jnp.float32)
 
     # backward error dx(i, j) accumulated over the neuron grid axis l —
     # identical order to crossbar_bwd_kernel's n axis.
@@ -281,7 +285,7 @@ def _train_kernel(*refs, n_i: int, lr: float,
         dx_ref[...] = jnp.zeros_like(dx_ref)
 
     dx_ref[...] += jax.lax.dot_general(
-        d, w, dimension_numbers=(((1,), (1,)), ((), ())),
+        d, w, dimension_numbers=(((1,), (1,)), ((), ())), precision=F32,
         preferred_element_type=jnp.float32)
 
     # weight update: gpo doubles as the fp32 dw accumulator over the batch
@@ -292,7 +296,7 @@ def _train_kernel(*refs, n_i: int, lr: float,
         gpo_ref[...] = jnp.zeros_like(gpo_ref)
 
     gpo_ref[...] += 2.0 * lr * jax.lax.dot_general(
-        x, d, dimension_numbers=(((0,), (0,)), ((), ())),
+        x, d, dimension_numbers=(((0,), (0,)), ((), ())), precision=F32,
         preferred_element_type=jnp.float32)
 
     @pl.when(i == n_i - 1)
@@ -387,7 +391,7 @@ def _upd_kernel(gp_ref, gm_ref, x_ref, d_ref, gp_out, gm_out, *,
     # accumulate dw tile = 2*lr * x^T @ delta over the batch dimension
     gp_out[...] += 2.0 * lr * jax.lax.dot_general(
         x_ref[...].astype(jnp.float32), d_ref[...].astype(jnp.float32),
-        dimension_numbers=(((0,), (0,)), ((), ())),
+        dimension_numbers=(((0,), (0,)), ((), ())), precision=F32,
         preferred_element_type=jnp.float32)
 
     @pl.when(m == n_m - 1)

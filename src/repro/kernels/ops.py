@@ -8,10 +8,11 @@ Shapes are padded to tile multiples here (the paper pads networks into
 crossbar tiles the same way, section V.B); results are sliced back.  Two
 hot-path amortizations (DESIGN.md §2.4):
 
-  * a block-size autotuner: candidate (bm, bk, bn) tilings are timed once
-    per (op, M, K, N) shape and the winner memoized (``autotune=True`` or
-    ``REPRO_XBAR_AUTOTUNE=1``; under tracing the cache is consulted but
-    never populated by timing),
+  * a block-size autotuner, opt-in (``autotune=True`` or
+    ``REPRO_XBAR_AUTOTUNE=1``): candidate (bm, bk, bn) tilings are timed
+    once per (op, M, K, N) shape and the winner memoized (under tracing the
+    cache is consulted but never populated by timing).  By default the
+    block sizes come from code (``_default_blocks``) and no table is read,
   * a conductance pad cache: static ``g±`` operands padded to tile
     multiples are reused across eager calls instead of re-padded per call.
 
@@ -62,10 +63,10 @@ def _pad_to(x: jax.Array, shape: tuple[int, ...]) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Both hot-path memos are bounded LRUs: long farm sweeps walk through many
 # (farm size x shape) keys, and an unbounded dict would grow for the life of
-# the process (ISSUE 5 satellite).  The autotune table additionally persists
-# to ``.cache/autotune-<backend>.json`` (one file per jax backend — interpret
+# the process.  Under opt-in autotuning the table additionally persists to
+# ``.cache/autotune-<backend>.json`` (one file per jax backend — interpret
 # -mode CPU timings must not pose as TPU tunings) so tuned block sizes
-# survive across runs.
+# survive across runs; nothing reads it unless tuning was asked for.
 
 _BLOCK_CACHE: OrderedDict = OrderedDict()
 _BLOCK_CACHE_MAX = 512
@@ -131,8 +132,8 @@ def save_autotune_table(path: str | None = None) -> str | None:
 
 def load_autotune_table(path: str | None = None) -> int:
     """Load a persisted block table into the in-process cache (entries
-    count toward the LRU cap and are marked as tuned).  Runs once at
-    import; safe to re-run."""
+    count toward the LRU cap and are marked as tuned).  Called by an
+    opted-in `block_config` miss; safe to re-run."""
     import json
     path = path or _autotune_table_path()
     if path is None or not os.path.exists(path):
@@ -186,10 +187,12 @@ def block_config(op: str, M: int, K: int, N: int, *,
     ``fold`` is the leading core-stack fold of the stacked entry points
     (chips x tiles) and is PART of the cache key: a farm of C chips times a
     (C*T, M, K, N) dispatch once and never re-tunes when the farm size —
-    and with it the vmapped workload — changes (ISSUE 5 satellite).  Tuned
-    entries persist to ``.cache/autotune-<backend>.json``."""
+    and with it the vmapped workload — changes.  Tuned entries persist to
+    ``.cache/autotune-<backend>.json``, which only a tuning miss reads."""
     key = (op, M, K, N) if fold is None else (op, fold, M, K, N)
     tune = _autotune_enabled(autotune)
+    if tune and key not in _TUNED_KEYS:
+        load_autotune_table()
     hit = _BLOCK_CACHE.get(key)
     if hit is not None and (key in _TUNED_KEYS or not tune
                             or time_fn is None):
@@ -206,6 +209,8 @@ def block_config(op: str, M: int, K: int, N: int, *,
     if timed:
         best, best_t = blocks, float("inf")
         for cand in _block_candidates(M, K, N):
+            # opt-in tuning only: a candidate the backend refuses is
+            # skipped; the default path never reaches this loop
             try:
                 time_fn(*cand)  # warmup / compile
                 t0 = time.perf_counter()
@@ -241,9 +246,6 @@ def _cached_pad(x: jax.Array, shape: tuple[int, ...]) -> jax.Array:
     while len(_PAD_CACHE) > _PAD_CACHE_MAX:
         _PAD_CACHE.popitem(last=False)
     return padded
-
-
-load_autotune_table()
 
 
 def _maybe_cached_pad(x: jax.Array, shape: tuple[int, ...]) -> jax.Array:

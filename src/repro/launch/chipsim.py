@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.configs.paper_apps import NETWORKS, PAPER_SPEC
 from repro.core import crossbar as xb, hw_model as hw
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.faults import MemristorFaults
 from repro.sim import VirtualChip
 
@@ -59,6 +60,7 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--json", default=None,
                     help="write the report record to this path")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     faults = MemristorFaults(stuck_on=args.stuck_on,
                              stuck_off=args.stuck_off,

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.configs.paper_apps import NETWORKS, PAPER_SPEC
 from repro.core import crossbar as xb, hw_model as hw
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim.cluster import build_farm, make_farm_mesh
 
 
@@ -52,6 +53,7 @@ def main(argv: list[str] | None = None) -> None:
                          "multiple JAX devices exist")
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     mesh = None if args.no_mesh else make_farm_mesh(args.chips)
     farm = build_farm(args.app, args.chips, seed=args.seed,

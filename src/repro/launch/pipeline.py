@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.configs.paper_apps import NETWORKS, PAPER_SPEC
 from repro.core import crossbar as xb
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim.chip import VirtualChip
 from repro.sim.fabric import build_pipeline
 
@@ -55,6 +56,7 @@ def main(argv: list[str] | None = None) -> None:
                          "assert bitwise-equal training")
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     pipe = build_pipeline(args.app, max_cores_per_chip=args.max_cores,
                           n_chips=args.pipeline_chips, seed=args.seed,

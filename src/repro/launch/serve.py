@@ -11,6 +11,7 @@ import time
 import jax
 
 from repro.configs import get_config, get_reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.runtime import BatchedServer, checkpoint as ckpt
 
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (get_reduced_config(args.arch) if args.reduced
            else get_config(args.arch))

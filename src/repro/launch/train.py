@@ -16,6 +16,7 @@ import jax
 
 from repro.configs import get_config, get_reduced_config
 from repro.data.pipeline import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.optim import adamw, cosine_schedule, make_optimizer
 from repro.runtime import Trainer
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")

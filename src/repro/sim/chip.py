@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import quantization as q
-from repro.core.crossbar import (CORE_COLS, CORE_ROWS, CrossbarSpec,
+from repro.core.crossbar import (CORE_COLS, CORE_ROWS, F32, CrossbarSpec,
                                  hard_sigmoid, hard_sigmoid_deriv)
 from repro.core.mapping import map_network
 from repro.core import hw_model as hw
@@ -353,7 +353,8 @@ class VirtualChip:
                     max_dw=spec.max_update, levels=spec.update_levels,
                     w_max=spec.w_max)
             else:
-                dw = 2.0 * (lr / B) * jnp.einsum("tmk,tmn->tkn", xs, ds)
+                dw = 2.0 * (lr / B) * jnp.einsum("tmk,tmn->tkn", xs, ds,
+                                                 precision=F32)
                 gp = jnp.clip(st.g_plus + 0.5 * dw, 0.0, spec.w_max)
                 gm = jnp.clip(st.g_minus - 0.5 * dw, 0.0, spec.w_max)
             self.placement.set_stage_stacks(si, gp, gm)

@@ -78,9 +78,10 @@ def make_farm_mesh(n_chips: int):
                  if n_chips % d == 0), 1)
     if span == 1:
         return None
-    from repro.dist import compat
-    compat.install()
-    return jax.make_mesh((span,), ("chips",))
+    # Auto axes: the eager chip-axis ops between shard_mapped dispatches
+    # run outside any mesh context, which Explicit axes would reject
+    return jax.make_mesh((span,), ("chips",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 class ChipFarm:
@@ -119,6 +120,12 @@ class ChipFarm:
                     for st in self.placement.stages]
         self._gm = [jnp.repeat(st.g_minus[None], C, axis=0)
                     for st in self.placement.stages]
+        if mesh is not None:
+            # each replica's stacks live on the device that runs its chip
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            shard = NamedSharding(mesh, P("chips"))
+            self._gp = [jax.device_put(g, shard) for g in self._gp]
+            self._gm = [jax.device_put(g, shard) for g in self._gm]
         self.chip_infer = [PhaseCounters(
             noc=NocTracker(slot_cycles=self.placement.cols))
             for _ in range(C)]
@@ -210,8 +217,6 @@ class ChipFarm:
         if self.mesh is None:
             return fn
         from jax.sharding import PartitionSpec as P
-        from repro.dist import compat
-        compat.install()
         return jax.shard_map(fn, mesh=self.mesh,
                              in_specs=(P("chips"),) * n_in,
                              out_specs=P("chips"), check_vma=False)

@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import quantization as q
-from repro.core.crossbar import hard_sigmoid, hard_sigmoid_deriv
+from repro.core.crossbar import F32, hard_sigmoid, hard_sigmoid_deriv
 from repro.kernels import ops as kernel_ops
 
 
@@ -50,17 +50,20 @@ def kernel_body_enabled() -> bool:
     """Whether the compiled scan bodies dispatch the fused Pallas kernels
     (`crossbar_train_stacked` and friends).
 
-    True on a real TPU backend (the kernels lower natively) and under
-    ``REPRO_SIM_FORCE_KERNELS=1`` (tests exercise the kernel-in-scan
-    path on CPU).  Otherwise the bodies use the bitwise-reference jnp
-    math: on CPU the kernels only exist in *interpret mode*, whose
-    per-call emulation overhead is the very dispatch tax the compiled
-    step removes (~10-35x a plain XLA contraction, growing with the core
-    stack) — the kernels remain the eager path and the differential
-    reference either way.  The flag is captured into `ChipConfig`, so
-    flipping it mid-process compiles a fresh program."""
-    if os.environ.get("REPRO_SIM_FORCE_KERNELS", "0") == "1":
-        return True
+    True on a real TPU backend (the kernels lower natively).  Otherwise
+    the bodies use the bitwise-reference jnp math: on CPU the kernels only
+    exist in *interpret mode*, whose per-call emulation overhead is the
+    very dispatch tax the compiled step removes (~10-35x a plain XLA
+    contraction, growing with the core stack) — the kernels remain the
+    eager path and the differential reference either way.
+    ``REPRO_SIM_FORCE_KERNELS=1`` or ``=0`` overrides the backend's choice:
+    tests run the kernel-in-scan path on CPU, and ``chip_smoke.py`` times
+    the jnp body against the kernel body on the chip.  The flag is
+    captured into `ChipConfig`, so flipping it mid-process compiles a
+    fresh program."""
+    force = os.environ.get("REPRO_SIM_FORCE_KERNELS")
+    if force in ("0", "1"):
+        return force == "1"
     return jax.default_backend() == "tpu"
 
 # ---------------------------------------------------------------------------
@@ -150,8 +153,10 @@ def _fwd_dispatch(xs, gp_s, gm_s, cfg: "ChipConfig"):
         return kernel_ops.crossbar_fwd_stacked(xs, gp_s, gm_s)
     w = (gp_s - gm_s).astype(jnp.float32)
     if gp_s.ndim == 3:
-        return jnp.einsum("ctmk,tkn->ctmn", xs.astype(jnp.float32), w)
-    return jnp.einsum("ctmk,ctkn->ctmn", xs.astype(jnp.float32), w)
+        return jnp.einsum("ctmk,tkn->ctmn", xs.astype(jnp.float32), w,
+                          precision=F32)
+    return jnp.einsum("ctmk,ctkn->ctmn", xs.astype(jnp.float32), w,
+                      precision=F32)
 
 
 def _stage_dp(h_ext, gp_s, gm_s, in_s, dp_s, cfg: ChipConfig) -> jax.Array:
@@ -254,7 +259,8 @@ def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
                     compute_y=False)
             else:
                 dxs = kernel_ops.crossbar_bwd_stacked(kds, gp_s, gm_s)
-                dw = 2.0 * lr_eff * jnp.einsum("tmk,tmn->tkn", kxs, kds)
+                dw = 2.0 * lr_eff * jnp.einsum("tmk,tmn->tkn", kxs, kds,
+                                               precision=F32)
                 gp2 = jnp.clip(gp_s + 0.5 * dw, 0.0, cfg.w_max)
                 gm2 = jnp.clip(gm_s - 0.5 * dw, 0.0, cfg.w_max)
             if serial:
@@ -264,9 +270,9 @@ def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
             # per-chip pulse applied locally, exactly the megakernel math.
             w = (gp_s - gm_s).astype(jnp.float32)
             bspec = "tkn" if serial else "ctkn"
-            dxs = jnp.einsum(f"ctmn,{bspec}->ctmk", ds, w)
+            dxs = jnp.einsum(f"ctmn,{bspec}->ctmk", ds, w, precision=F32)
             dwe = "ctmk,ctmn->tkn" if serial else "ctmk,ctmn->ctkn"
-            dw = 2.0 * lr_eff * jnp.einsum(dwe, xs, ds)
+            dw = 2.0 * lr_eff * jnp.einsum(dwe, xs, ds, precision=F32)
             if cfg.update_quant:
                 dw = q.pulse_discretize(dw, cfg.max_update,
                                         cfg.update_levels, None)
@@ -278,8 +284,9 @@ def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
                 dw_local = kernel_ops.crossbar_dw_stacked(xs, ds)
             else:
                 w = (gp_s - gm_s).astype(jnp.float32)
-                dxs = jnp.einsum("ctmn,ctkn->ctmk", ds, w)
-                dw_local = jnp.einsum("ctmk,ctmn->ctkn", xs, ds)
+                dxs = jnp.einsum("ctmn,ctkn->ctmk", ds, w, precision=F32)
+                dw_local = jnp.einsum("ctmk,ctmn->ctkn", xs, ds,
+                                      precision=F32)
             dw = 2.0 * lr_eff * farm_reduce_sum(dw_local, mode=reconcile)
             if cfg.update_quant:
                 dw = q.pulse_discretize(dw, cfg.max_update,

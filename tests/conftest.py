@@ -69,9 +69,12 @@ def run_subprocess(code: str, *, devices: int = 8, timeout: int = 600):
     """Run a python snippet in a fresh process with N fake devices.
 
     Multi-device tests must fork: jax locks the device count on first init.
+    The child is pinned to the CPU: the parent has already touched JAX, and
+    on a machine with an accelerator the parent holds it.
     """
     env = dict(os.environ,
                PYTHONPATH=SRC,
+               JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
     p = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=timeout, env=env, cwd=REPO)

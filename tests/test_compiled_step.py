@@ -366,6 +366,8 @@ def test_stacked_autotune_key_includes_fold_and_persists(tmp_path,
 
     table = tmp_path / "autotune.json"
     monkeypatch.setenv("REPRO_AUTOTUNE_TABLE", str(table))
+    # persistence belongs to the opt-in tuning path only
+    monkeypatch.setenv("REPRO_XBAR_AUTOTUNE", "1")
     saved = dict(ops._BLOCK_CACHE)
     saved_tuned = set(ops._TUNED_KEYS)
     ops._BLOCK_CACHE.clear()
@@ -394,7 +396,7 @@ def test_stacked_autotune_key_includes_fold_and_persists(tmp_path,
         # an untuned default (no timing pass) is cached for dispatch but
         # NEVER persisted — a persisted default would read as "already
         # tuned" on reload and suppress the timing pass forever ...
-        ops.block_config("fwd_stacked", 9, 41, 15, fold=8)
+        ops.block_config("fwd_stacked", 9, 41, 15, fold=8, autotune=False)
         ops.save_autotune_table()
         assert "fwd_stacked|8|9|41|15" not in json.load(open(table))
         # ... and a later real timing opportunity upgrades it in place

@@ -337,9 +337,7 @@ def test_farm_max_is_global_max(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.dist import compat
 from repro.dist.collectives import farm_max
-compat.install()
 mesh = jax.make_mesh((4,), ("chips",))
 x = jnp.arange(8.0).reshape(4, 2)
 fn = jax.shard_map(lambda v: farm_max(v, axis_name="chips"),
@@ -377,6 +375,8 @@ L = [xb.init_conductances(jax.random.fold_in(key, i), f, o, PAPER_SPEC)
 mesh = make_farm_mesh(4)
 assert mesh is not None and mesh.shape["chips"] == 4, mesh
 farm = ChipFarm([dict(p) for p in L], PAPER_SPEC, n_chips=4, mesh=mesh)
+# each replica's stacks live on the device that runs its chip
+assert len(farm._gp[0].sharding.device_set) == 4
 chip = VirtualChip([dict(p) for p in L], PAPER_SPEC)
 x = jax.random.uniform(jax.random.PRNGKey(9), (8, 41),
                        minval=-0.5, maxval=0.5)

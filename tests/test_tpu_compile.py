@@ -1,0 +1,120 @@
+"""The main path's kernels compile for a TPU v5e, checked without the chip.
+
+The TPU compiler is installed with JAX; it compiles for a chip that is
+described (``v5e:2x2``) and not attached.  Each stacked crossbar kernel is
+compiled at the shapes the three phases of ``chip_smoke.py`` dispatch
+(core stacks of 400x100 crossbars), and the whole compiled ``chip_train``
+program of ``mnist_class`` with the Pallas kernel body.  A compile refused
+here (tiling, VMEM, lowering) would be refused on the chip.
+
+The topology is described inside a fixture, never while a module is
+imported: only one process may load the TPU library at a time.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs.paper_apps import NETWORKS, PAPER_SPEC
+from repro.core import crossbar as xb
+from repro.kernels import ops as kernel_ops
+from repro.sim import VirtualChip, compiled as csim
+
+ROWS, COLS = 400, 100       # one crossbar core of the stage stacks
+
+# (op, cores T, microbatch M) per dispatch of the chip_smoke phases:
+#   chip mnist_class: 6 cores per stage, infer 1024, train batch 64;
+#   pipeline isolet_class k2: serve scan over 5 stages x 60 cores at one
+#     request per beat, train slices of 40 and 60 cores at batch 16;
+#   farm kdd_anomaly c4: serve scan over 4 chips x 2 stages, train at 16
+#     samples per chip through the reconciled bwd + dw path.
+CASES = [
+    ("fwd", 6, 1024), ("fwd", 6, 64), ("fwd", 300, 1), ("fwd", 60, 16),
+    ("fwd", 8, 1), ("fwd", 4, 16),
+    ("train", 6, 64), ("train", 40, 16), ("train", 60, 16),
+    ("bwd", 4, 16), ("dw", 4, 16), ("pulse", 6, 64),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without the chip; keep it out of the cache
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _spec(sharding, *shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_call(op, T, M, s):
+    g = (_spec(s, T, ROWS, COLS), _spec(s, T, ROWS, COLS))
+    xs, ds = _spec(s, T, M, ROWS), _spec(s, T, M, COLS)
+    if op == "fwd":
+        return (lambda x, gp, gm: kernel_ops.crossbar_fwd_stacked(
+            x, gp, gm, interpret=False)), (xs, *g)
+    if op == "bwd":
+        return (lambda d, gp, gm: kernel_ops.crossbar_bwd_stacked(
+            d, gp, gm, interpret=False)), (ds, *g)
+    if op == "dw":
+        return (lambda x, d: kernel_ops.crossbar_dw_stacked(
+            x, d, interpret=False)), (xs, ds)
+    if op == "pulse":
+        return (lambda gp, gm, x, d: kernel_ops.pulse_update_stacked(
+            gp, gm, x, d, lr=0.01, interpret=False)), (*g, xs, ds)
+    # the compiled scan's training body: lr pre-scaled into x, no forward
+    return (lambda gp, gm, x, d: kernel_ops.crossbar_train_stacked(
+        gp, gm, x, d, lr=1.0, max_dw=PAPER_SPEC.max_update,
+        levels=PAPER_SPEC.update_levels, w_max=PAPER_SPEC.w_max,
+        compute_y=False, interpret=False)), (*g, xs, ds)
+
+
+@pytest.mark.parametrize("op,T,M", CASES)
+def test_stacked_kernel_compiles_for_v5e(one_chip, op, T, M):
+    fn, args = _kernel_call(op, T, M, one_chip)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_mnist_chip_train_compiles_for_v5e_with_kernel_body(one_chip,
+                                                            monkeypatch):
+    # the kernels pick interpret mode from the attached backend (the CPU
+    # here); the chip's choice is steered in the test, not the program
+    monkeypatch.setattr(kernel_ops, "_default_interpret", lambda: False)
+    dims = NETWORKS["mnist_class"]
+    key = jax.random.PRNGKey(0)
+    layers = [xb.init_conductances(jax.random.fold_in(key, i), f, o,
+                                   PAPER_SPEC)
+              for i, (f, o) in enumerate(zip(dims, dims[1:]))]
+    chip = VirtualChip(layers, PAPER_SPEC)
+    st = chip._get_stacks()
+    cfg = chip._cfg._replace(use_kernels=True)
+    s = one_chip
+    idx = {k: _spec(s, *v.shape, dtype=v.dtype)
+           for k, v in st.index_pytree().items()}
+    compiled = csim.chip_train.lower(
+        _spec(s, *st.g_plus.shape), _spec(s, *st.g_minus.shape),
+        _spec(s, 64, dims[0]), _spec(s, 64, dims[-1]), idx, cfg,
+        lr_eff=_spec(s)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # the conductance stacks are donated: updated in place, not copied
+    assert mem.alias_size_in_bytes >= 2 * st.g_plus.nbytes
