@@ -114,6 +114,7 @@ def crossbar_fwd_kernel(x: jax.Array, g_plus: jax.Array, g_minus: jax.Array,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         compiler_params=None if interpret else _dimension_semantics(2, 1),
         interpret=interpret,
+        name="crossbar_fwd",
     )(x, g_plus, g_minus)
 
 
@@ -179,6 +180,7 @@ def crossbar_bwd_kernel(dy: jax.Array, g_plus: jax.Array, g_minus: jax.Array,
         out_shape=jax.ShapeDtypeStruct((M, K), jnp.float32),
         compiler_params=None if interpret else _dimension_semantics(2, 1),
         interpret=interpret,
+        name="crossbar_bwd",
     )(*args)
 
 
@@ -239,6 +241,7 @@ def crossbar_dw_kernel(x: jax.Array, dy: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((K, N), jnp.float32),
         compiler_params=None if interpret else _dimension_semantics(2, 1),
         interpret=interpret,
+        name="crossbar_dw",
     )(*args)
 
 
@@ -370,6 +373,7 @@ def crossbar_train_kernel(g_plus: jax.Array, g_minus: jax.Array,
         ],
         compiler_params=None if interpret else _dimension_semantics(0, 3),
         interpret=interpret,
+        name="crossbar_train",
     )(*args)
     return out[0], out[1], out[2], out[3]
 
@@ -436,5 +440,6 @@ def pulse_update_kernel(g_plus: jax.Array, g_minus: jax.Array, x: jax.Array,
         ],
         compiler_params=None if interpret else _dimension_semantics(2, 1),
         interpret=interpret,
+        name="pulse_update",
     )(g_plus, g_minus, x, delta)
     return out[0], out[1]

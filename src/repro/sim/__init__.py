@@ -24,6 +24,8 @@ Modules:
   fabric   ChipPipeline / PipelineServer / PipelineFarm: pipeline-parallel
            fabric for networks larger than one chip, inter-chip link
            accounting (DESIGN.md §7)
+  spans    host spans of each public call and its phases (prepare,
+           dispatch, writeback, sync, account) for the profiler's trace
 """
 from repro.sim.chip import VirtualChip  # noqa: F401
 from repro.sim.cluster import ChipFarm, FarmServer, build_farm  # noqa: F401

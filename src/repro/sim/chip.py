@@ -49,6 +49,7 @@ from repro.core.mapping import map_network
 from repro.core import hw_model as hw
 from repro.kernels import ops as kernel_ops
 from repro.sim import compiled as csim
+from repro.sim import spans
 from repro.sim.noc import NocTracker
 from repro.sim.placer import (Placement, Stage, StageStacks,
                               build_stage_stacks, place_network,
@@ -140,24 +141,28 @@ class VirtualChip:
         records (the placement's compile-time routing schedule)."""
         if counters is None:
             return
-        slots, steps = (int(v) for v in np.asarray(fcnt))
-        counters.slots["fwd"] += slots
-        counters.core_steps["fwd"] += steps
-        st = self._get_stacks()
-        for s in range(st.S):
-            counters.noc.record(self.placement.stages[s].index,
-                                st.routed[s], st.links[s], M)
+        with spans.phase(spans.SYNC):
+            slots, steps = (int(v) for v in np.asarray(fcnt))
+        with spans.phase(spans.ACCOUNT):
+            counters.slots["fwd"] += slots
+            counters.core_steps["fwd"] += steps
+            st = self._get_stacks()
+            for s in range(st.S):
+                counters.noc.record(self.placement.stages[s].index,
+                                    st.routed[s], st.links[s], M)
 
     @staticmethod
     def _apply_bwd_counters(counters: PhaseCounters | None, bcnt) -> None:
         if counters is None:
             return
-        b_slots, b_steps, u_slots, u_steps = (int(v)
-                                              for v in np.asarray(bcnt))
-        counters.slots["bwd"] += b_slots
-        counters.core_steps["bwd"] += b_steps
-        counters.slots["update"] += u_slots
-        counters.core_steps["update"] += u_steps
+        with spans.phase(spans.SYNC):
+            b_slots, b_steps, u_slots, u_steps = (int(v) for v
+                                                  in np.asarray(bcnt))
+        with spans.phase(spans.ACCOUNT):
+            counters.slots["bwd"] += b_slots
+            counters.core_steps["bwd"] += b_steps
+            counters.slots["update"] += u_slots
+            counters.core_steps["update"] += u_steps
 
     # ------------------------------------------------------------------
     # Stage execution (one batched Pallas call per stage)
@@ -204,6 +209,7 @@ class VirtualChip:
                 h = q.adc_quantize_ste(h, self.spec.adc_bits)
         return acts, dps, h
 
+    @spans.entry("chip.forward_wave")
     def forward_wave(self, x: jax.Array, *, count: bool = True,
                      train: bool = False, quantize_tail: bool = False
                      ) -> tuple[list[jax.Array], list[jax.Array], jax.Array]:
@@ -217,43 +223,54 @@ class VirtualChip:
         pipeline fabric (``repro.sim.fabric``) to run one chip's slice of a
         split network; :meth:`infer` and :meth:`train_step` are this plus
         the whole-network bookkeeping."""
-        x = jnp.atleast_2d(x)
         counters = None
         if count:
             counters = self.train_counters if train else self.infer_counters
         if not self._compiled_active():
-            return self._forward(x, counters, quantize_tail=quantize_tail)
-        st = self._get_stacks()
-        acts_p, dps_p, h, fcnt = csim.chip_forward(
-            st.g_plus, st.g_minus, x, st.index_pytree(),
-            jnp.asarray(bool(quantize_tail)), self._cfg)
+            return self._forward(jnp.atleast_2d(x), counters,
+                                 quantize_tail=quantize_tail)
+        with spans.phase(spans.PREPARE):
+            x = jnp.atleast_2d(x)
+            st = self._get_stacks()
+            idx, cfg = st.index_pytree(), self._cfg
+            tail = jnp.asarray(bool(quantize_tail))
+        with spans.phase(spans.DISPATCH):
+            acts_p, dps_p, h, fcnt = csim.chip_forward(
+                st.g_plus, st.g_minus, x, idx, tail, cfg)
         self._apply_fwd_counters(counters, fcnt, x.shape[0])
-        acts = [acts_p[s, :, 1:st.fan_in[s] + 1] for s in range(st.S)]
-        dps = [dps_p[s, :, :st.fan_out[s]] for s in range(st.S)]
-        return acts, dps, h[:, :st.out_dim]
+        with spans.phase(spans.WRITEBACK):
+            acts = [acts_p[s, :, 1:st.fan_in[s] + 1] for s in range(st.S)]
+            dps = [dps_p[s, :, :st.fan_out[s]] for s in range(st.S)]
+            return acts, dps, h[:, :st.out_dim]
 
     # ------------------------------------------------------------------
     # Inference
     # ------------------------------------------------------------------
 
+    @spans.entry("chip.infer")
     def infer(self, x: jax.Array, *, count: bool = True) -> jax.Array:
         """One recognition wave (serialized-latency semantics)."""
-        x = jnp.atleast_2d(x)
         counters = self.infer_counters if count else None
         if self._compiled_active():
-            st = self._get_stacks()
-            out, fcnt = csim.chip_infer(st.g_plus, st.g_minus, x,
-                                        st.index_pytree(), self._cfg)
+            with spans.phase(spans.PREPARE):
+                x = jnp.atleast_2d(x)
+                st = self._get_stacks()
+                idx, cfg = st.index_pytree(), self._cfg
+            with spans.phase(spans.DISPATCH):
+                out, fcnt = csim.chip_infer(st.g_plus, st.g_minus, x, idx,
+                                            cfg)
             self._apply_fwd_counters(counters, fcnt, x.shape[0])
         else:
+            x = jnp.atleast_2d(x)
             _, dps, _ = self._forward(x, counters)
             out = hard_sigmoid(dps[-1])
         if count:
-            M = x.shape[0]
-            self.infer_counters.samples += M
-            self.infer_counters.record_io(
-                self.placement.dims[0] * self.input_bits
-                + self.placement.dims[-1] * hw.ADC_BITS_OUT, M)
+            with spans.phase(spans.ACCOUNT):
+                M = x.shape[0]
+                self.infer_counters.samples += M
+                self.infer_counters.record_io(
+                    self.placement.dims[0] * self.input_bits
+                    + self.placement.dims[-1] * hw.ADC_BITS_OUT, M)
         return out
 
     def infer_stream(self, x: jax.Array) -> tuple[jax.Array, dict]:
@@ -288,6 +305,7 @@ class VirtualChip:
     # Training (the paper's fwd / bwd / update phases, Table II)
     # ------------------------------------------------------------------
 
+    @spans.entry("chip.backward_update")
     def backward_update(self, acts: list[jax.Array], dps: list[jax.Array],
                         delta: jax.Array, lr: float, *,
                         global_batch: int | None = None,
@@ -310,21 +328,26 @@ class VirtualChip:
         c = counters if counters is not None else self.train_counters
 
         if self._compiled_active():
-            st = self._get_stacks()
-            acts_p = jnp.zeros((st.S, M, st.L), jnp.float32)
-            dps_p = jnp.zeros((st.S, M, st.N_pad), jnp.float32)
-            for s in range(st.S):
-                acts_p = acts_p.at[s, :, 1:st.fan_in[s] + 1].set(acts[s])
-                dps_p = dps_p.at[s, :, :st.fan_out[s]].set(dps[s])
-            delta_p = jnp.zeros((M, st.N_pad), jnp.float32)
-            delta_p = delta_p.at[:, :delta.shape[1]].set(delta)
-            gp2, gm2, delta_fin, bcnt = csim.chip_backward(
-                st.g_plus, st.g_minus, acts_p, dps_p, delta_p,
-                st.index_pytree(), self._cfg, lr_eff=float(lr) / B)
-            st.g_plus, st.g_minus = gp2, gm2
-            st.scatter_back(self.placement)
+            with spans.phase(spans.PREPARE):
+                st = self._get_stacks()
+                acts_p = jnp.zeros((st.S, M, st.L), jnp.float32)
+                dps_p = jnp.zeros((st.S, M, st.N_pad), jnp.float32)
+                for s in range(st.S):
+                    acts_p = acts_p.at[s, :, 1:st.fan_in[s] + 1].set(acts[s])
+                    dps_p = dps_p.at[s, :, :st.fan_out[s]].set(dps[s])
+                delta_p = jnp.zeros((M, st.N_pad), jnp.float32)
+                delta_p = delta_p.at[:, :delta.shape[1]].set(delta)
+                idx, cfg = st.index_pytree(), self._cfg
+            with spans.phase(spans.DISPATCH):
+                gp2, gm2, delta_fin, bcnt = csim.chip_backward(
+                    st.g_plus, st.g_minus, acts_p, dps_p, delta_p, idx, cfg,
+                    lr_eff=float(lr) / B)
+            with spans.phase(spans.WRITEBACK):
+                st.g_plus, st.g_minus = gp2, gm2
+                st.scatter_back(self.placement)
+                delta_prev = delta_fin[:, :st.fan_in[0]]
             self._apply_bwd_counters(c, bcnt)
-            return delta_fin[:, :st.fan_in[0]]
+            return delta_prev
 
         for si in reversed(range(len(self.placement.stages))):
             st = self.placement.stages[si]
@@ -370,42 +393,47 @@ class VirtualChip:
                                      w_max=self.spec.w_max)
         return delta
 
+    @spans.entry("chip.train_step")
     def train_step(self, x: jax.Array, target: jax.Array,
                    lr: float) -> jax.Array:
         """One stochastic-BP step executed on the chip, writing the pulse
         updates into the conductance stacks in place.  Matches
         `core.crossbar.paper_backprop_step` exactly under equal specs.
         Returns the output error (target - prediction)."""
-        x = jnp.atleast_2d(x)
-        target = jnp.atleast_2d(target)
-        M = x.shape[0]
         c = self.train_counters
-
         if self._compiled_active():
             # the whole step — wave + reversed bwd/update scan — is ONE
             # donated XLA program; the conductance stacks update in place
             # and the counters come back in one transfer (DESIGN.md §8).
-            st = self._get_stacks()
-            gp2, gm2, err, fcnt, bcnt = csim.chip_train(
-                st.g_plus, st.g_minus, x, target,
-                st.index_pytree(), self._cfg, lr_eff=float(lr) / M)
-            st.g_plus, st.g_minus = gp2, gm2
-            st.scatter_back(self.placement)
+            with spans.phase(spans.PREPARE):
+                x = jnp.atleast_2d(x)
+                target = jnp.atleast_2d(target)
+                M = x.shape[0]
+                st = self._get_stacks()
+                idx, cfg = st.index_pytree(), self._cfg
+            with spans.phase(spans.DISPATCH):
+                gp2, gm2, err, fcnt, bcnt = csim.chip_train(
+                    st.g_plus, st.g_minus, x, target, idx, cfg,
+                    lr_eff=float(lr) / M)
+            with spans.phase(spans.WRITEBACK):
+                st.g_plus, st.g_minus = gp2, gm2
+                st.scatter_back(self.placement)
             self._apply_fwd_counters(c, fcnt, M)
             self._apply_bwd_counters(c, bcnt)
+        else:
+            x = jnp.atleast_2d(x)
+            target = jnp.atleast_2d(target)
+            M = x.shape[0]
+            acts, dps, _ = self._forward(x, c)
+            out = hard_sigmoid(dps[-1])
+            self.backward_update(acts, dps, target - out, lr, counters=c)
+            err = target - out
+
+        with spans.phase(spans.ACCOUNT):
             c.samples += M
             c.record_io(2 * self.placement.dims[0] * self.input_bits
                         + self.placement.dims[-1] * hw.ADC_BITS_OUT, M)
-            return err
-
-        acts, dps, _ = self._forward(x, c)
-        out = hard_sigmoid(dps[-1])
-        self.backward_update(acts, dps, target - out, lr, counters=c)
-
-        c.samples += M
-        c.record_io(2 * self.placement.dims[0] * self.input_bits
-                    + self.placement.dims[-1] * hw.ADC_BITS_OUT, M)
-        return target - out
+        return err
 
     # ------------------------------------------------------------------
     # Reporting

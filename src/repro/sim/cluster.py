@@ -58,6 +58,7 @@ from repro.core.mapping import map_network
 from repro.kernels import ops as kernel_ops
 from repro.runtime.serve_loop import RequestQueue
 from repro.sim import compiled as csim
+from repro.sim import spans
 from repro.sim.chip import VirtualChip, _tile_cols, compiled_enabled
 from repro.sim.noc import NocTracker
 from repro.sim.placer import (Placement, StageStacks, build_stage_stacks,
@@ -195,19 +196,22 @@ class ChipFarm:
         the per-chip increments are identical), plus the static NoC
         replay."""
         st = self._stacks
-        f = [int(v) for v in np.asarray(fcnt)]
-        b = [int(v) for v in np.asarray(bcnt)] if bcnt is not None else None
-        for c in counters:
-            c.slots["fwd"] += f[0]
-            c.core_steps["fwd"] += f[1]
-            for s in range(st.S):
-                c.noc.record(self.placement.stages[s].index,
-                             st.routed[s], st.links[s], Mc)
-            if b is not None:
-                c.slots["bwd"] += b[0]
-                c.core_steps["bwd"] += b[1]
-                c.slots["update"] += b[2]
-                c.core_steps["update"] += b[3]
+        with spans.phase(spans.SYNC):
+            f = [int(v) for v in np.asarray(fcnt)]
+            b = ([int(v) for v in np.asarray(bcnt)] if bcnt is not None
+                 else None)
+        with spans.phase(spans.ACCOUNT):
+            for c in counters:
+                c.slots["fwd"] += f[0]
+                c.core_steps["fwd"] += f[1]
+                for s in range(st.S):
+                    c.noc.record(self.placement.stages[s].index,
+                                 st.routed[s], st.links[s], Mc)
+                if b is not None:
+                    c.slots["bwd"] += b[0]
+                    c.core_steps["bwd"] += b[1]
+                    c.slots["update"] += b[2]
+                    c.core_steps["update"] += b[3]
 
     # ------------------------------------------------------------------
     # Chip-axis stacked dispatch (shard_mapped when a mesh is present)
@@ -284,34 +288,41 @@ class ChipFarm:
     # Inference (wave semantics; serving goes through FarmServer)
     # ------------------------------------------------------------------
 
+    @spans.entry("farm.infer")
     def infer(self, x: jax.Array, *, count: bool = True) -> jax.Array:
         """Data-parallel recognition wave: the global batch splits over
         chips, each replica computes its shard; rows come back in input
         order and equal `VirtualChip.infer` on the unsharded batch."""
-        xb = self._split(x, "infer")
         counters = self.chip_infer if count else None
         if self._compiled_active():
-            st, gp, gm = self._get_stacks()
-            out, fcnt = csim.chip_infer(gp, gm, xb, st.index_pytree(),
-                                        csim.chip_config(st, self.spec))
+            with spans.phase(spans.PREPARE):
+                xb = self._split(x, "infer")
+                st, gp, gm = self._get_stacks()
+                idx, cfg = st.index_pytree(), csim.chip_config(st, self.spec)
+            with spans.phase(spans.DISPATCH):
+                out, fcnt = csim.chip_infer(gp, gm, xb, idx, cfg)
             if count:
                 self._apply_phase_counters(counters, fcnt, None, xb.shape[1])
         else:
+            xb = self._split(x, "infer")
             _, dps = self._forward(xb, counters)
             out = hard_sigmoid(dps[-1])
         if count:
-            Mc = xb.shape[1]
-            bits = (self.placement.dims[0] * self.input_bits
-                    + self.placement.dims[-1] * hw.ADC_BITS_OUT)
-            for c in self.chip_infer:
-                c.samples += Mc
-                c.record_io(bits, Mc)
-        return out.reshape(-1, out.shape[-1])
+            with spans.phase(spans.ACCOUNT):
+                Mc = xb.shape[1]
+                bits = (self.placement.dims[0] * self.input_bits
+                        + self.placement.dims[-1] * hw.ADC_BITS_OUT)
+                for c in self.chip_infer:
+                    c.samples += Mc
+                    c.record_io(bits, Mc)
+        with spans.phase(spans.WRITEBACK):
+            return out.reshape(-1, out.shape[-1])
 
     # ------------------------------------------------------------------
     # Data-parallel training with reconciled pulse updates
     # ------------------------------------------------------------------
 
+    @spans.entry("farm.train_step")
     def train_step(self, x: jax.Array, target: jax.Array, lr: float, *,
                    reconcile: str = "none") -> jax.Array:
         """One farm step on the global batch; equals the serial
@@ -322,8 +333,9 @@ class ChipFarm:
         that same metered traffic.  Returns the (global) output error."""
         from repro.dist.collectives import farm_reduce_sum
 
-        xb = self._split(x, "train")
-        tb = self._split(jnp.atleast_2d(target), "target")
+        with spans.phase(spans.PREPARE):
+            xb = self._split(x, "train")
+            tb = self._split(jnp.atleast_2d(target), "target")
         spec = self.spec
         C, Mc = xb.shape[0], xb.shape[1]
         M = C * Mc
@@ -332,22 +344,27 @@ class ChipFarm:
             # the whole farm step — chip-axis wave, reversed bwd scan,
             # farm_reduce_sum reconciliation INSIDE the trace, pulses
             # broadcast to every replica — is ONE donated XLA program.
-            st, gp, gm = self._get_stacks()
-            gp2, gm2, err, fcnt, bcnt = csim.chip_train(
-                gp, gm, xb, tb, st.index_pytree(),
-                csim.chip_config(st, self.spec), lr_eff=float(lr) / M,
-                reconcile=reconcile)
-            self._scatter_back(gp2, gm2)
+            with spans.phase(spans.PREPARE):
+                st, gp, gm = self._get_stacks()
+                idx, cfg = st.index_pytree(), csim.chip_config(st, self.spec)
+            with spans.phase(spans.DISPATCH):
+                gp2, gm2, err, fcnt, bcnt = csim.chip_train(
+                    gp, gm, xb, tb, idx, cfg, lr_eff=float(lr) / M,
+                    reconcile=reconcile)
+            with spans.phase(spans.WRITEBACK):
+                self._scatter_back(gp2, gm2)
+                err = err.reshape(M, -1)
             self._apply_phase_counters(self.chip_train, fcnt, bcnt, Mc)
-            bits = (2 * self.placement.dims[0] * self.input_bits
-                    + self.placement.dims[-1] * hw.ADC_BITS_OUT)
-            for c in self.chip_train:
-                c.samples += Mc
-                c.record_io(bits, Mc)
-            self.train_link.record_samples(bits, M)
-            self.train_link.record_reconcile(C * self._reconcile_bits())
-            self.train_steps += 1
-            return err.reshape(M, -1)
+            with spans.phase(spans.ACCOUNT):
+                bits = (2 * self.placement.dims[0] * self.input_bits
+                        + self.placement.dims[-1] * hw.ADC_BITS_OUT)
+                for c in self.chip_train:
+                    c.samples += Mc
+                    c.record_io(bits, Mc)
+                self.train_link.record_samples(bits, M)
+                self.train_link.record_reconcile(C * self._reconcile_bits())
+                self.train_steps += 1
+            return err
 
         acts, dps = self._forward(xb, self.chip_train)
         out = hard_sigmoid(dps[-1])
@@ -418,19 +435,22 @@ class ChipFarm:
     # Serving
     # ------------------------------------------------------------------
 
+    @spans.entry("farm.serve")
     def serve(self, x: jax.Array) -> tuple[jax.Array, dict]:
         """Serve a batch of requests (one per row) through the pipelined
         farm; returns (outputs in request order, serving stats)."""
-        x = jnp.atleast_2d(x)
-        if x.shape[0] == 0:
-            return (jnp.zeros((0, self.placement.dims[-1])),
-                    {"beats": 0, "retired": 0, "beat_us": self.beat_us,
-                     "makespan_us": 0.0, "samples_per_s": 0.0,
-                     "occupancy": 0.0})
-        server = FarmServer(self)
-        queue = RequestQueue(list(x))
+        with spans.phase(spans.PREPARE):
+            x = jnp.atleast_2d(x)
+            if x.shape[0] == 0:
+                return (jnp.zeros((0, self.placement.dims[-1])),
+                        {"beats": 0, "retired": 0, "beat_us": self.beat_us,
+                         "makespan_us": 0.0, "samples_per_s": 0.0,
+                         "occupancy": 0.0})
+            server = FarmServer(self)
+            queue = RequestQueue(list(x))
         stats = server.run(queue)
-        out = jnp.stack([r.reshape(-1) for r in queue.results()])
+        with spans.phase(spans.WRITEBACK):
+            out = jnp.stack([r.reshape(-1) for r in queue.results()])
         return out, stats
 
     # ------------------------------------------------------------------
@@ -749,36 +769,38 @@ class FarmServer:
                 "the serving stacks are a snapshot")
         farm.serve_sessions += 1
         C, S = self.C, self.S
-        st, gp, gm = farm._get_stacks()
-        gp_cat = jnp.moveaxis(gp, 0, 1).reshape(C, S * st.T_max, st.rows,
-                                                st.cols)
-        gm_cat = jnp.moveaxis(gm, 0, 1).reshape(C, S * st.T_max, st.rows,
-                                                st.cols)
+        with spans.phase(spans.PREPARE):
+            st, gp, gm = farm._get_stacks()
+            gp_cat = jnp.moveaxis(gp, 0, 1).reshape(C, S * st.T_max,
+                                                    st.rows, st.cols)
+            gm_cat = jnp.moveaxis(gm, 0, 1).reshape(C, S * st.T_max,
+                                                    st.rows, st.cols)
         Q, m, q_max, n_beats = csim.run_serve_session(
             queue, st, gp_cat, gm_cat, farm.spec, C)
         self._slot_m = m
 
         # counters: the eager loop's per-beat billing, aggregated over the
         # static schedule (lane c serves ceil((Q - c) / C) requests)
-        bits = (farm.placement.dims[0] * farm.input_bits
-                + farm.placement.dims[-1] * hw.ADC_BITS_OUT)
-        for c in range(C):
-            n = (Q - c + C - 1) // C * m
-            if not n:
-                continue
-            cc = farm.chip_infer[c]
-            for stg in self.stages:
-                cc.record_phase("fwd", stg.n_cores, n)
-                cc.noc.record(stg.index, stg.lmap.routed_outputs,
-                              stg.g_plus.shape[0], n)
-            cc.samples += n
-            cc.record_io(bits, n)
-        farm.serve_link.record_samples(bits, Q * m)
-        full = Q // C
-        farm.serve_full_beats += full
-        farm.serve_full_samples += full * C * m
-        farm.serve_full_requests += full * C
-        farm.serve_beats += n_beats
+        with spans.phase(spans.ACCOUNT):
+            bits = (farm.placement.dims[0] * farm.input_bits
+                    + farm.placement.dims[-1] * hw.ADC_BITS_OUT)
+            for c in range(C):
+                n = (Q - c + C - 1) // C * m
+                if not n:
+                    continue
+                cc = farm.chip_infer[c]
+                for stg in self.stages:
+                    cc.record_phase("fwd", stg.n_cores, n)
+                    cc.noc.record(stg.index, stg.lmap.routed_outputs,
+                                  stg.g_plus.shape[0], n)
+                cc.samples += n
+                cc.record_io(bits, n)
+            farm.serve_link.record_samples(bits, Q * m)
+            full = Q // C
+            farm.serve_full_beats += full
+            farm.serve_full_samples += full * C * m
+            farm.serve_full_requests += full * C
+            farm.serve_beats += n_beats
         beat_us = farm.beat_us
         return {
             "beats": n_beats,
@@ -797,10 +819,12 @@ class FarmServer:
         uniform-shape queue runs the whole session as one jitted beat
         scan; step-wise use (partially drained pipes, beat limits, ragged
         shapes) stays on the eager per-beat path."""
-        if (self.farm._compiled_active() and max_beats is None
-                and csim.serve_session_applicable(
-                    queue, all(s is None for lane in self.pipe
-                               for s in lane), self._slot_m)):
+        with spans.phase(spans.PREPARE):
+            compiled = (self.farm._compiled_active() and max_beats is None
+                        and csim.serve_session_applicable(
+                            queue, all(s is None for lane in self.pipe
+                                       for s in lane), self._slot_m))
+        if compiled:
             return self._run_compiled(queue)
         beats = retired = 0
         limit = max_beats if max_beats is not None else 10_000_000

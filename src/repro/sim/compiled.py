@@ -44,6 +44,7 @@ import jax.numpy as jnp
 from repro.core import quantization as q
 from repro.core.crossbar import F32, hard_sigmoid, hard_sigmoid_deriv
 from repro.kernels import ops as kernel_ops
+from repro.sim import spans
 
 
 def kernel_body_enabled() -> bool:
@@ -492,27 +493,32 @@ def run_serve_session(queue, stacks, gp_cat, gm_cat, spec,
     static schedule.  Completes every request in order and returns
     (requests, microbatch m, q_max, beats); the callers replay their own
     counter/link billing from the same schedule."""
-    reqs = []
-    while True:
-        r = queue.pop()
-        if r is None:
-            break
-        reqs.append(r)
-    xs = [jnp.atleast_2d(jnp.asarray(r.x)) for r in reqs]
-    Q, (m, D) = len(reqs), xs[0].shape
-    q_max = -(-Q // n_lanes)
-    # bucket the lane depth to a power of two so varying queue lengths
-    # share compiled executables (the scan's shapes are static in Qp and
-    # n_beats).  The spare lanes/beats drive zeros and re-inject the
-    # final padded block, whose never-retired junk lands — clamped — only
-    # in rows >= q_max*n_lanes >= Q, all sliced away below; the REAL
-    # schedule (and therefore the billing the callers replay) is
-    # unchanged, so the returned q_max/beats stay the eager loop's.
-    q_pad = 1 << (q_max - 1).bit_length()
-    Qp = q_pad * n_lanes
-    x_arr = jnp.zeros((Qp, m, D), jnp.float32).at[:Q].set(jnp.stack(xs))
-    out = serve_scan(gp_cat, gm_cat, x_arr, stacks.index_pytree(),
-                     chip_config(stacks, spec), stacks.S - 1 + q_pad)
-    for i, r in enumerate(reqs):
-        queue.complete(r.rid, out[i])
+    with spans.phase(spans.PREPARE):
+        reqs = []
+        while True:
+            r = queue.pop()
+            if r is None:
+                break
+            reqs.append(r)
+        xs = [jnp.atleast_2d(jnp.asarray(r.x)) for r in reqs]
+        Q, (m, D) = len(reqs), xs[0].shape
+        q_max = -(-Q // n_lanes)
+        # bucket the lane depth to a power of two so varying queue lengths
+        # share compiled executables (the scan's shapes are static in Qp
+        # and n_beats).  The spare lanes/beats drive zeros and re-inject
+        # the final padded block, whose never-retired junk lands —
+        # clamped — only in rows >= q_max*n_lanes >= Q, all sliced away
+        # below; the REAL schedule (and therefore the billing the callers
+        # replay) is unchanged, so the returned q_max/beats stay the eager
+        # loop's.
+        q_pad = 1 << (q_max - 1).bit_length()
+        Qp = q_pad * n_lanes
+        x_arr = jnp.zeros((Qp, m, D), jnp.float32).at[:Q].set(jnp.stack(xs))
+        idx, cfg = stacks.index_pytree(), chip_config(stacks, spec)
+    with spans.phase(spans.DISPATCH):
+        out = serve_scan(gp_cat, gm_cat, x_arr, idx, cfg,
+                         stacks.S - 1 + q_pad)
+    with spans.phase(spans.WRITEBACK):
+        for i, r in enumerate(reqs):
+            queue.complete(r.rid, out[i])
     return Q, m, q_max, stacks.S - 1 + q_max

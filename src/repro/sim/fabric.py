@@ -59,6 +59,7 @@ from repro.core.mapping import map_network, split_network
 from repro.kernels import ops as kernel_ops
 from repro.runtime.serve_loop import RequestQueue
 from repro.sim import compiled as csim
+from repro.sim import spans
 from repro.sim.chip import VirtualChip, compiled_enabled
 from repro.sim.placer import (Placement, StageStacks, build_stage_stacks,
                               fold_subneuron_partials, place_network,
@@ -136,29 +137,33 @@ class ChipPipeline:
     # Wave execution (numerics identical to the serial chip)
     # ------------------------------------------------------------------
 
+    @spans.entry("pipeline.infer")
     def infer(self, x: jax.Array, *, count: bool = True) -> jax.Array:
         """One recognition wave through the chip pipeline.  Equals the
         serial `VirtualChip.infer` on the unsplit network bitwise: the
         boundary ADC is the same 3-bit quantization the serial chip
         applies between stages."""
-        h = jnp.atleast_2d(x)
+        with spans.phase(spans.PREPARE):
+            h = jnp.atleast_2d(x)
         M = h.shape[0]
         last = self.n_chips - 1
         for k, chip in enumerate(self.chips):
             _, _, h = chip.forward_wave(h, count=count,
                                         quantize_tail=k < last)
-            if count:
-                chip.infer_counters.samples += M
-                if k < last:
-                    self.link.record_fwd(
-                        k, self.boundary_dims[k] * hw.ADC_BITS_OUT, M)
         if count:
-            self.chips[0].infer_counters.record_io(
-                self.placement.dims[0] * self.input_bits, M)
-            self.chips[-1].infer_counters.record_io(
-                self.placement.dims[-1] * hw.ADC_BITS_OUT, M)
+            with spans.phase(spans.ACCOUNT):
+                for k, chip in enumerate(self.chips):
+                    chip.infer_counters.samples += M
+                    if k < last:
+                        self.link.record_fwd(
+                            k, self.boundary_dims[k] * hw.ADC_BITS_OUT, M)
+                self.chips[0].infer_counters.record_io(
+                    self.placement.dims[0] * self.input_bits, M)
+                self.chips[-1].infer_counters.record_io(
+                    self.placement.dims[-1] * hw.ADC_BITS_OUT, M)
         return h
 
+    @spans.entry("pipeline.train_step")
     def train_step(self, x: jax.Array, target: jax.Array, lr: float, *,
                    n_micro: int = 1) -> jax.Array:
         """One stochastic-BP step across the chip pipeline, bitwise equal
@@ -172,8 +177,9 @@ class ChipPipeline:
         it cannot change the numerics because the pulse update applies
         once per batch with a shared error full-scale (the same argument
         that makes the farm equal the serial chip, DESIGN.md §6.2)."""
-        x = jnp.atleast_2d(x)
-        target = jnp.atleast_2d(target)
+        with spans.phase(spans.PREPARE):
+            x = jnp.atleast_2d(x)
+            target = jnp.atleast_2d(target)
         M = x.shape[0]
         if M % n_micro:
             raise ValueError(f"batch {M} not divisible by n_micro {n_micro}")
@@ -185,48 +191,56 @@ class ChipPipeline:
             acts, dps, h = chip.forward_wave(h, train=True,
                                              quantize_tail=k < last)
             waves.append((acts, dps))
-            chip.train_counters.samples += M
-            if k < last:
-                self.link.record_fwd(
-                    k, self.boundary_dims[k] * hw.ADC_BITS_OUT, M)
         out = h
-        delta = target - out
+        with spans.phase(spans.PREPARE):
+            delta = target - out
         for k in reversed(range(self.n_chips)):
             acts, dps = waves[k]
             delta = self.chips[k].backward_update(acts, dps, delta, lr,
                                                   global_batch=M)
-            if k > 0:
+        with spans.phase(spans.WRITEBACK):
+            err = target - out
+
+        with spans.phase(spans.ACCOUNT):
+            for k, chip in enumerate(self.chips):
+                chip.train_counters.samples += M
+                if k < last:
+                    self.link.record_fwd(
+                        k, self.boundary_dims[k] * hw.ADC_BITS_OUT, M)
+            for k in reversed(range(1, self.n_chips)):
                 self.link.record_bwd(
                     k - 1, self.boundary_dims[k - 1] * hw.ERR_BITS_LINK, M)
-
-        self.chips[0].train_counters.record_io(
-            2 * self.placement.dims[0] * self.input_bits, M)
-        self.chips[-1].train_counters.record_io(
-            self.placement.dims[-1] * hw.ADC_BITS_OUT, M)
-        self.train_steps += 1
-        self.train_samples += M
-        self.batch_per_step = M
-        self.n_micro = n_micro
-        self.version += 1
-        return target - out
+            self.chips[0].train_counters.record_io(
+                2 * self.placement.dims[0] * self.input_bits, M)
+            self.chips[-1].train_counters.record_io(
+                self.placement.dims[-1] * hw.ADC_BITS_OUT, M)
+            self.train_steps += 1
+            self.train_samples += M
+            self.batch_per_step = M
+            self.n_micro = n_micro
+            self.version += 1
+        return err
 
     # ------------------------------------------------------------------
     # Serving
     # ------------------------------------------------------------------
 
+    @spans.entry("pipeline.serve")
     def serve(self, x: jax.Array) -> tuple[jax.Array, dict]:
         """Serve a batch of requests (one per row) through the pipelined
         fabric; returns (outputs in request order, serving stats)."""
-        x = jnp.atleast_2d(x)
-        if x.shape[0] == 0:
-            return (jnp.zeros((0, self.placement.dims[-1])),
-                    {"beats": 0, "retired": 0, "beat_us": self.beat_us,
-                     "makespan_us": 0.0, "samples_per_s": 0.0,
-                     "latency_us": self.serve_latency_us})
-        server = PipelineServer(self)
-        queue = RequestQueue(list(x))
+        with spans.phase(spans.PREPARE):
+            x = jnp.atleast_2d(x)
+            if x.shape[0] == 0:
+                return (jnp.zeros((0, self.placement.dims[-1])),
+                        {"beats": 0, "retired": 0, "beat_us": self.beat_us,
+                         "makespan_us": 0.0, "samples_per_s": 0.0,
+                         "latency_us": self.serve_latency_us})
+            server = PipelineServer(self)
+            queue = RequestQueue(list(x))
         stats = server.run(queue)
-        out = jnp.stack([r.reshape(-1) for r in queue.results()])
+        with spans.phase(spans.WRITEBACK):
+            out = jnp.stack([r.reshape(-1) for r in queue.results()])
         return out, stats
 
     # ------------------------------------------------------------------
@@ -533,36 +547,38 @@ class PipelineServer:
                 "was built (a train_step ran); construct a fresh server — "
                 "the serving stacks are a snapshot")
         S = self.S
-        st = pipe._get_serve_stacks()
-        gp_cat = st.g_plus.reshape(1, S * st.T_max, st.rows, st.cols)
-        gm_cat = st.g_minus.reshape(1, S * st.T_max, st.rows, st.cols)
+        with spans.phase(spans.PREPARE):
+            st = pipe._get_serve_stacks()
+            gp_cat = st.g_plus.reshape(1, S * st.T_max, st.rows, st.cols)
+            gm_cat = st.g_minus.reshape(1, S * st.T_max, st.rows, st.cols)
         Q, m, _, n_beats = csim.run_serve_session(
             queue, st, gp_cat, gm_cat, pipe.spec, 1)
         self._slot_m = m
 
         # counters: the eager loop's per-beat billing aggregated over the
         # static schedule (every request visits every stage once)
-        n = Q * m
-        for s, stg in enumerate(self.stages):
-            cc = pipe.chips[self.owner[s]].infer_counters
-            cc.record_phase("fwd", stg.n_cores, n)
-            cc.noc.record(stg.index, stg.lmap.routed_outputs,
-                          stg.g_plus.shape[0], n)
-        for s in range(S - 1):
-            k = self.owner[s]
-            if self.owner[s + 1] != k:
-                pipe.link.record_fwd(
-                    k, pipe.boundary_dims[k] * hw.ADC_BITS_OUT, n)
-        pipe.chips[0].infer_counters.record_io(
-            pipe.placement.dims[0] * pipe.input_bits, n)
-        pipe.chips[self.owner[S - 1]].infer_counters.record_io(
-            pipe.placement.dims[-1] * hw.ADC_BITS_OUT, n)
-        for c in pipe.chips:
-            c.infer_counters.samples += n
-        pipe.serve_full_beats += Q
-        pipe.serve_beats += n_beats
-        pipe.serve_samples += n
-        pipe.serve_slot_m = m
+        with spans.phase(spans.ACCOUNT):
+            n = Q * m
+            for s, stg in enumerate(self.stages):
+                cc = pipe.chips[self.owner[s]].infer_counters
+                cc.record_phase("fwd", stg.n_cores, n)
+                cc.noc.record(stg.index, stg.lmap.routed_outputs,
+                              stg.g_plus.shape[0], n)
+            for s in range(S - 1):
+                k = self.owner[s]
+                if self.owner[s + 1] != k:
+                    pipe.link.record_fwd(
+                        k, pipe.boundary_dims[k] * hw.ADC_BITS_OUT, n)
+            pipe.chips[0].infer_counters.record_io(
+                pipe.placement.dims[0] * pipe.input_bits, n)
+            pipe.chips[self.owner[S - 1]].infer_counters.record_io(
+                pipe.placement.dims[-1] * hw.ADC_BITS_OUT, n)
+            for c in pipe.chips:
+                c.infer_counters.samples += n
+            pipe.serve_full_beats += Q
+            pipe.serve_beats += n_beats
+            pipe.serve_samples += n
+            pipe.serve_slot_m = m
         beat_us = pipe.beat_us
         return {
             "beats": n_beats,
@@ -581,10 +597,12 @@ class PipelineServer:
         With the compiled executor active, a fresh server draining a
         uniform-shape queue runs the whole session as one jitted beat
         scan; step-wise use stays on the eager per-beat path."""
-        if (compiled_enabled() and max_beats is None
-                and csim.serve_session_applicable(
-                    queue, all(s is None for s in self.slots),
-                    self._slot_m)):
+        with spans.phase(spans.PREPARE):
+            compiled = (compiled_enabled() and max_beats is None
+                        and csim.serve_session_applicable(
+                            queue, all(s is None for s in self.slots),
+                            self._slot_m))
+        if compiled:
             return self._run_compiled(queue)
         beats = retired = 0
         limit = max_beats if max_beats is not None else 10_000_000
