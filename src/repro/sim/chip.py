@@ -149,7 +149,7 @@ class VirtualChip:
             st = self._get_stacks()
             for s in range(st.S):
                 counters.noc.record(self.placement.stages[s].index,
-                                    st.routed[s], st.links[s], M)
+                                    st.routed[s], st.Ts[s], M)
 
     @staticmethod
     def _apply_bwd_counters(counters: PhaseCounters | None, bcnt) -> None:
@@ -235,13 +235,11 @@ class VirtualChip:
             idx, cfg = st.index_pytree(), self._cfg
             tail = jnp.asarray(bool(quantize_tail))
         with spans.phase(spans.DISPATCH):
-            acts_p, dps_p, h, fcnt = csim.chip_forward(
+            acts, dps, h, fcnt = csim.chip_forward(
                 st.g_plus, st.g_minus, x, idx, tail, cfg)
         self._apply_fwd_counters(counters, fcnt, x.shape[0])
         with spans.phase(spans.WRITEBACK):
-            acts = [acts_p[s, :, 1:st.fan_in[s] + 1] for s in range(st.S)]
-            dps = [dps_p[s, :, :st.fan_out[s]] for s in range(st.S)]
-            return acts, dps, h[:, :st.out_dim]
+            return list(acts), list(dps), h
 
     # ------------------------------------------------------------------
     # Inference
@@ -339,13 +337,12 @@ class VirtualChip:
                 delta_p = delta_p.at[:, :delta.shape[1]].set(delta)
                 idx, cfg = st.index_pytree(), self._cfg
             with spans.phase(spans.DISPATCH):
-                gp2, gm2, delta_fin, bcnt = csim.chip_backward(
+                out = csim.chip_backward(
                     st.g_plus, st.g_minus, acts_p, dps_p, delta_p, idx, cfg,
                     lr_eff=float(lr) / B)
             with spans.phase(spans.WRITEBACK):
-                st.g_plus, st.g_minus = gp2, gm2
-                st.scatter_back(self.placement)
-                delta_prev = delta_fin[:, :st.fan_in[0]]
+                st.g_plus, st.g_minus, delta_prev, bcnt = out
+                st.scatter_back(self.placement, *csim.kept_views(out, cfg))
             self._apply_bwd_counters(c, bcnt)
             return delta_prev
 
@@ -412,12 +409,12 @@ class VirtualChip:
                 st = self._get_stacks()
                 idx, cfg = st.index_pytree(), self._cfg
             with spans.phase(spans.DISPATCH):
-                gp2, gm2, err, fcnt, bcnt = csim.chip_train(
+                out = csim.chip_train(
                     st.g_plus, st.g_minus, x, target, idx, cfg,
                     lr_eff=float(lr) / M)
             with spans.phase(spans.WRITEBACK):
-                st.g_plus, st.g_minus = gp2, gm2
-                st.scatter_back(self.placement)
+                st.g_plus, st.g_minus, err, fcnt, bcnt = out
+                st.scatter_back(self.placement, *csim.kept_views(out, cfg))
             self._apply_fwd_counters(c, fcnt, M)
             self._apply_bwd_counters(c, bcnt)
         else:

@@ -177,15 +177,13 @@ class ChipFarm:
             self._stacks_version = self.version
         return self._stacks, self._fgp, self._fgm
 
-    def _scatter_back(self, gp, gm) -> None:
-        """Write the compiled step's donated stacks back into the
-        per-stage chip-axis lists (device-side slices) and keep stage 0's
-        replica visible to `extract_chip`/`layers` consumers."""
+    def _scatter_back(self, gp, gm, views_plus, views_minus) -> None:
+        """Keep the compiled step's donated envelope and hand its
+        per-stage chip-axis views (`compiled.kept_views`) to the
+        per-stage lists that `extract_chip`/`layers` consumers read:
+        plain assignment, no device work."""
         self._fgp, self._fgm = gp, gm
-        for s in range(self._stacks.S):
-            T = self._gp[s].shape[1]
-            self._gp[s] = gp[s, :, :T]
-            self._gm[s] = gm[s, :, :T]
+        self._gp, self._gm = list(views_plus), list(views_minus)
         self.version += 1
         self._stacks_version = self.version
 
@@ -206,7 +204,7 @@ class ChipFarm:
                 c.core_steps["fwd"] += f[1]
                 for s in range(st.S):
                     c.noc.record(self.placement.stages[s].index,
-                                 st.routed[s], st.links[s], Mc)
+                                 st.routed[s], st.Ts[s], Mc)
                 if b is not None:
                     c.slots["bwd"] += b[0]
                     c.core_steps["bwd"] += b[1]
@@ -348,11 +346,12 @@ class ChipFarm:
                 st, gp, gm = self._get_stacks()
                 idx, cfg = st.index_pytree(), csim.chip_config(st, self.spec)
             with spans.phase(spans.DISPATCH):
-                gp2, gm2, err, fcnt, bcnt = csim.chip_train(
+                out = csim.chip_train(
                     gp, gm, xb, tb, idx, cfg, lr_eff=float(lr) / M,
                     reconcile=reconcile)
             with spans.phase(spans.WRITEBACK):
-                self._scatter_back(gp2, gm2)
+                gp2, gm2, err, fcnt, bcnt = out
+                self._scatter_back(gp2, gm2, *csim.kept_views(out, cfg))
                 err = err.reshape(M, -1)
             self._apply_phase_counters(self.chip_train, fcnt, bcnt, Mc)
             with spans.phase(spans.ACCOUNT):

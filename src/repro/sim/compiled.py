@@ -35,7 +35,7 @@ from __future__ import annotations
 
 import os
 from collections import Counter
-from functools import partial
+from functools import partial, wraps
 from typing import NamedTuple
 
 import jax
@@ -94,7 +94,8 @@ def reset_trace_counts() -> None:
 class ChipConfig(NamedTuple):
     """Static (hashable) configuration of a compiled chip program: the
     `StageStacks` envelope geometry plus the `CrossbarSpec` constants the
-    traced code branches on."""
+    traced code branches on.  ``Ts``/``fan_in``/``fan_out`` are the
+    per-stage core counts and widths the programs cut their outputs to."""
     S: int
     T_max: int
     r_max: int
@@ -104,6 +105,9 @@ class ChipConfig(NamedTuple):
     L: int
     N_pad: int
     out_dim: int
+    Ts: tuple[int, ...]
+    fan_in: tuple[int, ...]
+    fan_out: tuple[int, ...]
     transport_quant: bool
     adc_bits: int
     error_quant: bool
@@ -122,6 +126,7 @@ def chip_config(stacks, spec) -> ChipConfig:
         S=stacks.S, T_max=stacks.T_max, r_max=stacks.r_max,
         c_max=stacks.c_max, rows=stacks.rows, cols=stacks.cols,
         L=stacks.L, N_pad=stacks.N_pad, out_dim=stacks.out_dim,
+        Ts=stacks.Ts, fan_in=stacks.fan_in, fan_out=stacks.fan_out,
         transport_quant=bool(spec.transport_quant),
         adc_bits=int(spec.adc_bits),
         error_quant=bool(spec.error_quant), err_bits=int(spec.err_bits),
@@ -318,6 +323,63 @@ def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
     return gp_new, gm_new, delta_fin, cnt
 
 
+def _stage_views(g, cfg: ChipConfig) -> tuple[jax.Array, ...]:
+    """Per-stage conductance stacks cut from the padded envelope, in the
+    layout the simulator objects keep: ``(T_s, rows, cols)`` from the
+    serial ``(S, T_max, rows, cols)`` stacks, ``(C, T_s, rows, cols)``
+    from the farm's ``(S, C, T_max, rows, cols)``.  Returned as outputs of
+    the same program, each is its own buffer: donating the envelope to
+    the next step leaves them readable."""
+    return tuple(g[s][..., :cfg.Ts[s], :, :] for s in range(cfg.S))
+
+
+class Stepped(tuple):
+    """A training program's results, ``(gp', gm', ...)``, carrying
+    ``views``: ``(views_plus, views_minus)``, the updated stacks cut to
+    each stage's cores inside the same program (`_stage_views`).  A tuple
+    rebuilt from it (``out[2:]``, ``(a, b) + out[2:]``) is a plain tuple
+    and carries no views, so views never outlive the stacks they were cut
+    from."""
+
+    def __new__(cls, results, views):
+        self = super().__new__(cls, results)
+        self.views = tuple(views)
+        return self
+
+
+def _returns_views(n: int):
+    """Wrap a jitted training program that returns ``n`` results followed
+    by its two view tuples: the call returns the ``n`` results as a
+    `Stepped` carrying the views.  The program keeps its name (trace
+    readers match it) and its ``lower``."""
+    def wrap(program):
+        @wraps(program)
+        def call(*args, **kw):
+            out = program(*args, **kw)
+            return Stepped(out[:n], out[n:])
+        call.lower = program.lower
+        return call
+    return wrap
+
+
+@partial(jax.jit, static_argnames=("cfg",))
+def stage_views(gp, gm, cfg: ChipConfig):
+    """Compiled cut of a padded envelope pair into per-stage
+    ``(views_plus, views_minus)`` (`_stage_views`): one launch."""
+    return _stage_views(gp, cfg), _stage_views(gm, cfg)
+
+
+def kept_views(out, cfg: ChipConfig):
+    """The per-stage ``(views_plus, views_minus)`` a simulator object keeps
+    after training result ``out``: the views its program cut when ``out``
+    is the program's own `Stepped`, otherwise (a result rebuilt around
+    other stacks) `stage_views` of ``out[0]``/``out[1]``.  Either way the
+    kept state is the stacks ``out`` holds."""
+    if isinstance(out, Stepped):
+        return out.views
+    return stage_views(out[0], out[1], cfg)
+
+
 # ---------------------------------------------------------------------------
 # Jitted entry points
 # ---------------------------------------------------------------------------
@@ -326,17 +388,22 @@ def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
 def chip_forward(gp, gm, x, idx, quantize_tail, cfg: ChipConfig):
     """Compiled recognition/training wave: (acts, dps, tail h, counters).
 
-    ``x`` is chip-stacked (C, M, fan_in) or plain (M, fan_in) — the
-    serial case rank-bridges inside the program and returns per-stage
-    stacks without the chip axis.  Counters: int32 [fwd_slots,
+    ``acts`` and ``dps`` are tuples of S per-stage arrays already cut to
+    each stage's widths — inputs ``(..., M, fan_in[s])`` without the bias
+    slot, dot products ``(..., M, fan_out[s])`` — and ``h`` is
+    ``(..., M, out_dim)``.  ``x`` is chip-stacked (C, M, fan_in) or plain
+    (M, fan_in) — the serial case rank-bridges inside the program and
+    returns arrays without the chip axis.  Counters: int32 [fwd_slots,
     fwd_core_steps] per chip."""
     _mark("chip_forward", cfg, x.shape)
     serial = x.ndim == 2
     acts, dps, h, cnt = _forward_scan(
         gp, gm, x[None] if serial else x, idx, quantize_tail, cfg)
     if serial:
-        return acts[:, 0], dps[:, 0], h[0], cnt
-    return acts, dps, h, cnt
+        acts, dps, h = acts[:, 0], dps[:, 0], h[0]
+    return (tuple(acts[s][..., 1:cfg.fan_in[s] + 1] for s in range(cfg.S)),
+            tuple(dps[s][..., :cfg.fan_out[s]] for s in range(cfg.S)),
+            h[..., :cfg.out_dim], cnt)
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -354,13 +421,16 @@ def chip_infer(gp, gm, x, idx, cfg: ChipConfig):
     return (out[0] if serial else out), cnt
 
 
+@_returns_views(5)
 @partial(jax.jit, static_argnames=("cfg", "reconcile"),
          donate_argnums=(0, 1))
 def chip_train(gp, gm, x, target, idx, cfg: ChipConfig, lr_eff=0.1,
                reconcile: str | None = None):
     """Compiled training step — forward wave + reversed bwd/update scan in
-    ONE donated program.  Returns (gp', gm', err, fwd counters, bwd
-    counters); the conductance stacks update in place (donation).
+    ONE donated program.  Returns the `Stepped` (gp', gm', err, fwd
+    counters, bwd counters); the conductance stacks update in place
+    (donation), and its ``views`` are gp'/gm' cut to each stage's cores,
+    the layout the simulator objects keep (`kept_views`).
     ``x``/``target`` rank-bridge like :func:`chip_infer`; ``lr_eff`` is a
     traced scalar (an lr schedule reuses one executable)."""
     _mark("chip_train", cfg, x.shape, reconcile)
@@ -377,9 +447,11 @@ def chip_train(gp, gm, x, target, idx, cfg: ChipConfig, lr_eff=0.1,
     gp2, gm2, _, bcnt = _backward_scan(gp, gm, acts, dps, delta0, idx, cfg,
                                        lr_eff, reconcile)
     err = delta0[:, :, :cfg.out_dim]
-    return gp2, gm2, (err[0] if serial else err), fcnt, bcnt
+    return (gp2, gm2, (err[0] if serial else err), fcnt, bcnt,
+            _stage_views(gp2, cfg), _stage_views(gm2, cfg))
 
 
+@_returns_views(4)
 @partial(jax.jit, static_argnames=("cfg", "reconcile"),
          donate_argnums=(0, 1))
 def chip_backward(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
@@ -387,15 +459,18 @@ def chip_backward(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
     """Compiled bwd + update phases over a stage slice (the pipeline
     fabric's per-chip entry point).  ``delta`` arrives padded to N_pad —
     (C, M, N_pad), or (M, N_pad) to rank-bridge the serial case like
-    :func:`chip_infer`.  ``lr_eff`` is a traced scalar.  Returns
-    (gp', gm', upstream delta, counters)."""
+    :func:`chip_infer`.  ``lr_eff`` is a traced scalar.  Returns the
+    `Stepped` (gp', gm', upstream delta, counters): the upstream delta cut
+    to the slice's fan-in, the views as in :func:`chip_train`."""
     _mark("chip_backward", cfg, delta.shape, reconcile)
     serial = delta.ndim == 2
     if serial:
         acts, dps, delta = acts[:, None], dps[:, None], delta[None]
     gp2, gm2, dfin, cnt = _backward_scan(gp, gm, acts, dps, delta, idx,
                                          cfg, lr_eff, reconcile)
-    return gp2, gm2, (dfin[0] if serial else dfin), cnt
+    dfin = dfin[..., :cfg.fan_in[0]]
+    return (gp2, gm2, (dfin[0] if serial else dfin), cnt,
+            _stage_views(gp2, cfg), _stage_views(gm2, cfg))
 
 
 # ---------------------------------------------------------------------------

@@ -293,11 +293,11 @@ class StageStacks:
     L: int               # padded input-vector length (bias slot 0 + lanes)
     N_pad: int           # padded output-lane count (max col_tiles*cols)
     out_dim: int         # fan_out of the last stage
+    Ts: tuple[int, ...]            # per-stage cores = emitting links
     fan_in: tuple[int, ...]
     fan_out: tuple[int, ...]
     n_cores: tuple[int, ...]       # per-stage billed cores (grid + agg)
     routed: tuple[int, ...]        # per-stage routed outputs (NoC record)
-    links: tuple[int, ...]         # per-stage emitting links (NoC record)
     g_plus: jax.Array              # (S, T_max, rows, cols)
     g_minus: jax.Array
     in_idx: jax.Array              # (S, T_max, rows)  h_ext -> core lines
@@ -316,15 +316,15 @@ class StageStacks:
                 "prev_idx": self.prev_idx, "valid_out": self.valid_out,
                 "core_counts": self.core_counts}
 
-    def scatter_back(self, pl: "Placement") -> None:
-        """Write the padded stacks back into the placement's `Stage`
-        objects (slices, device-side) and mark the placement clean — the
-        aliasing contract of `sub_placement` keeps holding because the
-        Stage objects themselves are updated in place."""
-        for s, st in enumerate(pl.stages):
-            T = st.row_tiles * st.col_tiles
-            st.g_plus = self.g_plus[s, :T]
-            st.g_minus = self.g_minus[s, :T]
+    def scatter_back(self, pl: "Placement", views_plus, views_minus) -> None:
+        """Hand the compiled step's per-stage views (`compiled.kept_views`
+        of a ``chip_train``/``chip_backward`` result: the updated stacks
+        already cut to each stage's cores) to the placement's `Stage`
+        objects and mark the placement clean.  Plain assignment, no device work; the aliasing
+        contract of `sub_placement` keeps holding because the Stage
+        objects themselves are updated in place."""
+        for st, gp, gm in zip(pl.stages, views_plus, views_minus):
+            st.g_plus, st.g_minus = gp, gm
         pl.version += 1
         self.built_version = pl.version
 
@@ -390,11 +390,10 @@ def build_stage_stacks(pl: Placement) -> StageStacks:
 
     return StageStacks(
         S=S, T_max=T_max, r_max=r_max, c_max=c_max, rows=rows, cols=cols,
-        L=L, N_pad=N_pad, out_dim=fan_out[-1],
+        L=L, N_pad=N_pad, out_dim=fan_out[-1], Ts=tuple(Ts),
         fan_in=fan_in, fan_out=fan_out,
         n_cores=tuple(st.n_cores for st in stages),
         routed=tuple(st.lmap.routed_outputs for st in stages),
-        links=tuple(st.g_plus.shape[0] for st in stages),
         g_plus=gp, g_minus=gm,
         in_idx=jnp.asarray(in_idx), ds_idx=jnp.asarray(ds_idx),
         dp_idx=jnp.asarray(dp_idx), fold_idx=jnp.asarray(fold_idx),

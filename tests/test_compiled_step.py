@@ -261,12 +261,25 @@ def test_one_compile_per_topology_and_batch():
         for _ in range(3):
             chip.train_step(x, tgt, lr=0.1)
             chip.infer(x)
+    # the slice-wise entry points of the pipeline fabric: wave, then the
+    # bwd/update phases on the wave's per-stage views
+    for chip in chips:
+        for _ in range(3):
+            acts, dps, out = chip.forward_wave(x, train=True)
+            chip.backward_update(acts, dps, tgt - out, 0.1)
     counts = csim.trace_counts()
-    cfg = csim.chip_config(chips[0]._get_stacks(), PAPER_SPEC)
+    st = chips[0]._get_stacks()
+    cfg = csim.chip_config(st, PAPER_SPEC)
+    # the per-stage cores and widths the programs cut their outputs to
+    # are static config: one topology, one config
+    assert (cfg.Ts, cfg.fan_in, cfg.fan_out) == (st.Ts, (41, 15), (15, 41))
+    assert cfg == csim.chip_config(chips[1]._get_stacks(), PAPER_SPEC)
     key_train = ("chip_train", cfg, (4, 41), None)
     key_infer = ("chip_infer", cfg, (4, 41))
     assert counts[key_train] == 1, counts
     assert counts[key_infer] == 1, counts
+    assert counts[("chip_forward", cfg, (4, 41))] == 1, counts
+    assert counts[("chip_backward", cfg, (4, st.N_pad), None)] == 1, counts
     # an lr schedule reuses the SAME executable (lr_eff is traced) ...
     chips[0].train_step(x, tgt, lr=0.37)
     assert csim.trace_counts()[key_train] == 1
@@ -324,6 +337,152 @@ def test_repeated_steps_are_allocation_stable():
         chip.train_step(x, tgt, lr=0.1)
     assert len(jax.live_arrays()) <= base + 2, \
         "compiled training leaks device buffers per step"
+
+
+# ---------------------------------------------------------------------------
+# Writeback: the training programs return the per-stage layout
+# ---------------------------------------------------------------------------
+
+RAGGED = dict(dims=[100, 40, 30, 10], rows=40, cols=10)  # T = 12, 6, 1
+
+
+def _capture(monkeypatch, name):
+    """Wrap ``csim.<name>``: every call's outputs, in call order."""
+    calls, orig = [], getattr(csim, name)
+
+    def wrapped(*args, **kw):
+        out = orig(*args, **kw)
+        calls.append(out)
+        return out
+
+    monkeypatch.setattr(csim, name, wrapped)
+    return calls
+
+
+def _build(kind):
+    from repro.sim.cluster import ChipFarm
+    from repro.sim.fabric import ChipPipeline
+    geo = dict(rows=RAGGED["rows"], cols=RAGGED["cols"])
+    layers = _layers(RAGGED["dims"])
+    if kind == "chip":
+        return VirtualChip(layers, PAPER_SPEC, **geo)
+    if kind == "pipeline":
+        return ChipPipeline(layers, PAPER_SPEC, n_chips=2, **geo)
+    return ChipFarm(layers, PAPER_SPEC, n_chips=2, **geo)
+
+
+def _slices(obj, kind):
+    """(holders, envelope) per slice of ``obj``: what the object keeps per
+    stage after a step, and the padded envelope it donates next."""
+    if kind == "farm":
+        yield list(zip(obj._gp, obj._gm)), (obj._fgp, obj._fgm)
+        return
+    for chip in (obj.chips if kind == "pipeline" else [obj]):
+        st = chip._stacks
+        yield ([(s.g_plus, s.g_minus) for s in chip.placement.stages],
+               (st.g_plus, st.g_minus))
+
+
+def _cut(held, e, s):
+    """Stage ``s`` of envelope ``e`` cut to the cores ``held`` has."""
+    return np.asarray(e[s][..., :held.shape[-3], :, :])
+
+
+@pytest.mark.parametrize("kind", ["chip", "pipeline", "farm"])
+def test_writeback_keeps_the_programs_own_views(monkeypatch, kind):
+    """After a compiled step each stage holds the very arrays the program
+    returned, bitwise the envelope cut to the stage's cores, and the
+    per-layer conductances read the same as from that cut."""
+    import dataclasses
+    name = "chip_backward" if kind == "pipeline" else "chip_train"
+    outputs = _capture(monkeypatch, name)
+    obj = _build(kind)
+    x = _x(RAGGED["dims"], n=4)
+    tgt = _x(RAGGED["dims"], n=4, seed=3)[:, :RAGGED["dims"][-1]]
+    obj.train_step(x, tgt, 0.1)
+    seen = 0
+    for holders, env in _slices(obj, kind):
+        out, = [o for o in outputs if o[0] is env[0]]
+        assert isinstance(out, csim.Stepped)
+        for s, held in enumerate(holders):
+            for g, e, v in zip(held, env, out.views):
+                assert g is v[s]
+                np.testing.assert_array_equal(np.asarray(g), _cut(g, e, s))
+                seen += 1
+    assert seen == 2 * len(RAGGED["dims"][1:])
+    # the per-layer read-back equals the one from the envelope's slices
+    pl = obj.placement
+    if kind == "farm":
+        stages = [dataclasses.replace(
+            st, g_plus=obj._fgp[s, 0, :st.row_tiles * st.col_tiles],
+            g_minus=obj._fgm[s, 0, :st.row_tiles * st.col_tiles])
+            for s, st in enumerate(pl.stages)]
+    else:
+        chips = obj.chips if kind == "pipeline" else [obj]
+        stages = [dataclasses.replace(
+            st, g_plus=c._stacks.g_plus[s, :st.row_tiles * st.col_tiles],
+            g_minus=c._stacks.g_minus[s, :st.row_tiles * st.col_tiles])
+            for c in chips for s, st in enumerate(c.placement.stages)]
+    want = dataclasses.replace(pl, stages=stages).extract_params()
+    for a, b in zip(obj.layers(), want):
+        for k in ("g_plus", "g_minus"):
+            np.testing.assert_array_equal(np.asarray(a[k]),
+                                          np.asarray(b[k]))
+
+
+@pytest.mark.parametrize("kind", ["chip", "pipeline", "farm"])
+def test_writeback_keeps_a_rebuilt_results_stacks(monkeypatch, kind):
+    """A result rebuilt around other stacks (a plain tuple, as a wrapper of
+    the program returns) is what the object keeps: each stage holds those
+    stacks cut to its cores, not the views the program cut from its own."""
+    name = "chip_backward" if kind == "pipeline" else "chip_train"
+    orig, made = getattr(csim, name), []
+
+    def halved(*args, **kw):
+        out = orig(*args, **kw)
+        made.append(((out[0] * 0.5, out[1] * 0.5), out.views))
+        return made[-1][0] + out[2:]
+
+    monkeypatch.setattr(csim, name, halved)
+    obj = _build(kind)
+    x = _x(RAGGED["dims"], n=4)
+    tgt = _x(RAGGED["dims"], n=4, seed=3)[:, :RAGGED["dims"][-1]]
+    obj.train_step(x, tgt, 0.1)
+    seen = 0
+    for holders, env in _slices(obj, kind):
+        (rebuilt, views), = [m for m in made if m[0][0] is env[0]]
+        for s, held in enumerate(holders):
+            for g, e, v in zip(held, env, views):
+                np.testing.assert_array_equal(np.asarray(g), _cut(g, e, s))
+                assert np.any(np.asarray(v[s]))
+                np.testing.assert_array_equal(2 * np.asarray(g),
+                                              np.asarray(v[s]))
+                seen += 1
+    assert seen == 2 * len(RAGGED["dims"][1:])
+
+
+@pytest.mark.parametrize("kind", ["chip", "farm"])
+def test_views_outlive_the_donated_envelope(monkeypatch, kind):
+    """Over three donated steps the envelope keeps its buffer, and the
+    views step 1 returned stay readable after step 2 donates it."""
+    outputs = _capture(monkeypatch, "chip_train")
+    obj = _build(kind)
+    x = _x(RAGGED["dims"], n=4)
+    tgt = _x(RAGGED["dims"], n=4, seed=3)[:, :RAGGED["dims"][-1]]
+    ptrs = []
+    for step in range(3):
+        obj.train_step(x, tgt, 0.1)
+        if step == 0:
+            first = [np.asarray(v) for v in sum(outputs[0].views, ())]
+        try:
+            ptrs.append(outputs[-1][0].unsafe_buffer_pointer())
+        except (AttributeError, NotImplementedError):
+            pytest.skip("unsafe_buffer_pointer unavailable on this backend")
+    assert len(set(ptrs)) == 1, "donated envelope was copied, not reused"
+    assert outputs[0][0].is_deleted()
+    for v, host in zip(sum(outputs[0].views, ()), first):
+        assert not v.is_deleted()
+        np.testing.assert_array_equal(np.asarray(v), host)
 
 
 # ---------------------------------------------------------------------------
