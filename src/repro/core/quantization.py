@@ -81,16 +81,21 @@ class QTensor:
 
 def error_quantize(x: jax.Array, bits: int = ERROR_BITS,
                    key: jax.Array | None = None,
-                   block_axis: int | None = None) -> QTensor:
+                   block_axis: int | None = None,
+                   full_scale: jax.Array | None = None) -> QTensor:
     """Paper's error discretization: sign bit + (bits-1) magnitude bits.
 
     The hardware uses one ADC per error line with a shared full-scale; we use
     max-abs scaling per tensor (``block_axis=None``) or per row of
     ``block_axis`` (used by the gradient-compression collective, where a scale
     per parameter block keeps large and small layers independent).
+    ``full_scale`` gives the per-tensor max-abs from outside, when ``x`` is
+    one shard of the tensor (the farm agrees on it with `farm_max`).
     """
     maxmag = 2 ** (bits - 1) - 1
-    if block_axis is None:
+    if full_scale is not None:
+        scale = full_scale / maxmag
+    elif block_axis is None:
         scale = jnp.max(jnp.abs(x)) / maxmag
     else:
         scale = jnp.max(jnp.abs(x), axis=block_axis, keepdims=True) / maxmag

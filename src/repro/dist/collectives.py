@@ -73,17 +73,18 @@ def farm_reduce_sum(contrib: jax.Array, *, axis_name: str | None = None,
     pulse discipline applied once, on the SUM, so the farm's replicas stay
     bitwise in lockstep with a serial chip (DESIGN.md §6).
 
-    Inside ``shard_map`` pass ``axis_name`` (psum over the mesh axis);
-    outside, ``contrib`` carries an explicit chip axis (``chip_axis``) that
-    is summed away.
+    ``contrib`` carries the chips on ``chip_axis``.  Outside ``shard_map``
+    that axis holds every chip of the farm and is summed away.  Inside
+    ``shard_map`` pass ``axis_name``: the axis holds this device's chips,
+    which are summed, and the sums are psummed over the mesh axis.
 
     mode "none": exact f32 sum (the default — farm == serial exactly).
     mode "int8": each chip's contribution rides the host link as 8-bit
                  sign-magnitude codes with its OWN full-scale (paper III.F
                  step 1 per chip) — quarter payload vs f32, error bounded per
                  chip, so a quiet chip's update survives next to a loud
-                 one.  Inside shard_map the scale is per shard, which
-                 equals per chip only at one chip per device.
+                 one.  Chips are coded one by one before any sum, so a
+                 device that holds several chips codes each of them.
     """
     if mode == "int8":
         from repro.core import quantization as q
@@ -91,16 +92,14 @@ def farm_reduce_sum(contrib: jax.Array, *, axis_name: str | None = None,
         def code(g):
             return q.error_quantize(g, err_bits).dequantize()
 
-        if axis_name is not None:
-            contrib = code(contrib)
-        else:
-            contrib = jax.vmap(code, in_axes=chip_axis,
-                               out_axes=chip_axis)(contrib)
+        contrib = jax.vmap(code, in_axes=chip_axis,
+                           out_axes=chip_axis)(contrib)
     elif mode != "none":
         raise ValueError(f"unknown farm reduction mode: {mode!r}")
+    total = jnp.sum(contrib, axis=chip_axis)
     if axis_name is not None:
-        return jax.lax.psum(contrib, axis_name)
-    return jnp.sum(contrib, axis=chip_axis)
+        return jax.lax.psum(total, axis_name)
+    return total
 
 
 def farm_max(x: jax.Array, *, axis_name: str | None = None,

@@ -36,11 +36,19 @@ the farm scales it out the way the ROADMAP's serving story requires:
     traffic; `ChipFarm.report()` aggregates them into a `FarmReport`
     cross-validated against `hw_model.farm_cost`.
 
-With a JAX device mesh (``mesh=`` with a ``"chips"`` axis), the chip-axis
-dispatches run under ``shard_map`` — each device executes its chip slice
-of the same stacked call; reconciliation happens on the gathered
-contributions (parameter-server discipline).  Without a mesh the same
-code runs single-device (the chip axis is just an array axis).
+With a JAX device mesh (``mesh=`` with a ``"chips"`` axis), the farm is
+sharded: every stage's stacks, and the compiled step's padded envelope
+``(S, C, T_max, rows, cols)`` on its axis 1, are split over the devices by
+chip, and the global batch by rows in replica order.  ``train_step`` and
+``infer`` run the same compiled program as one device, under
+``shard_map``: each device runs its replicas' forward wave, backward scan
+and local outer products, and the reconciliation (`farm_reduce_sum`) and
+the error full-scale (`farm_max`) are collectives over ``"chips"`` inside
+that one donated program.  No replica's stacks and no input row leave
+their device outside the collectives.  Serving on a mesh, and the eager
+reference path (``REPRO_SIM_COMPILED=0``), dispatch each stacked call
+under ``shard_map`` from Python.  Without a mesh the same code runs
+single-device (the chip axis is just an array axis).
 """
 from __future__ import annotations
 
@@ -49,6 +57,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import hw_model as hw
 from repro.core import quantization as q
@@ -81,8 +91,15 @@ def make_farm_mesh(n_chips: int):
         return None
     # Auto axes: the eager chip-axis ops between shard_mapped dispatches
     # run outside any mesh context, which Explicit axes would reject
-    return jax.make_mesh((span,), ("chips",),
+    return jax.make_mesh((span,), (csim.FARM_AXIS,),
                          axis_types=(jax.sharding.AxisType.Auto,))
+
+
+def _envelope(stacks, T_max: int) -> jax.Array:
+    """(S, C, T_max, rows, cols) from per-stage (C, T_s, rows, cols) stacks,
+    the cores past ``T_s`` zero."""
+    return jnp.stack([jnp.pad(g, ((0, 0), (0, T_max - g.shape[1]),
+                                  (0, 0), (0, 0))) for g in stacks])
 
 
 class ChipFarm:
@@ -122,11 +139,11 @@ class ChipFarm:
         self._gm = [jnp.repeat(st.g_minus[None], C, axis=0)
                     for st in self.placement.stages]
         if mesh is not None:
-            # each replica's stacks live on the device that runs its chip
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            shard = NamedSharding(mesh, P("chips"))
-            self._gp = [jax.device_put(g, shard) for g in self._gp]
-            self._gm = [jax.device_put(g, shard) for g in self._gm]
+            # each replica's stacks, and its rows of a batch, live on the
+            # device that runs its chip
+            self._by_chip = NamedSharding(mesh, P(csim.FARM_AXIS))
+            self._gp = [jax.device_put(g, self._by_chip) for g in self._gp]
+            self._gm = [jax.device_put(g, self._by_chip) for g in self._gm]
         self.chip_infer = [PhaseCounters(
             noc=NocTracker(slot_cycles=self.placement.cols))
             for _ in range(C)]
@@ -146,6 +163,8 @@ class ChipFarm:
         self.train_steps = 0
         self._stacks: StageStacks | None = None   # compiled-path layout
         self._fgp = self._fgm = None              # (S, C, T_max, R, cols)
+        self._idx = None                          # index maps of _stacks
+        self._held_scalar = (None, None)          # (value, device copy)
         self._stacks_version = -1
 
     # ------------------------------------------------------------------
@@ -153,29 +172,33 @@ class ChipFarm:
     # ------------------------------------------------------------------
 
     def _compiled_active(self) -> bool:
-        """The compiled farm step runs the chip axis as an array axis on
-        one device; the shard_mapped mesh path stays on the eager
-        dispatches (its per-device placement is a different execution
-        contract)."""
-        return compiled_enabled() and self.mesh is None
+        """Whether ``train_step`` and ``infer`` run the compiled whole-step
+        program: on one device the chip axis is an array axis; on a mesh
+        each device runs its replicas' slice of the same program under
+        ``shard_map`` (`compiled.chip_train`), with the reconciliation as
+        collectives over ``"chips"`` inside it."""
+        return compiled_enabled()
 
     def _get_stacks(self):
-        """(layout, gp (S, C, T_max, rows, cols), gm) — padded chip-axis
-        stacks, rebuilt when the conductance version moved outside the
-        compiled step."""
+        """(layout, gp (S, C, T_max, rows, cols), gm, index maps) — padded
+        chip-axis stacks, rebuilt when the conductance version moved
+        outside the compiled step.  On a mesh the envelope is split by
+        chip (axis 1) like the per-stage stacks, each device padding its
+        own replicas, and every device holds the index maps, so a step
+        moves neither."""
         if self._stacks is None or self._stacks_version != self.version:
             st = self._stacks = build_stage_stacks(self.placement)
-            C = self.n_chips
-            gp = jnp.zeros((st.S, C, st.T_max, st.rows, st.cols),
-                           jnp.float32)
-            gm = jnp.zeros_like(gp)
-            for s in range(st.S):
-                T = self._gp[s].shape[1]
-                gp = gp.at[s, :, :T].set(self._gp[s])
-                gm = gm.at[s, :, :T].set(self._gm[s])
-            self._fgp, self._fgm = gp, gm
+            self._idx, out = st.index_pytree(), {}
+            if self.mesh is not None:
+                out = {"out_shardings": NamedSharding(
+                    self.mesh, P(None, csim.FARM_AXIS))}
+                self._idx = jax.device_put(
+                    self._idx, NamedSharding(self.mesh, P()))
+            pad = jax.jit(_envelope, static_argnums=1, **out)
+            self._fgp = pad(tuple(self._gp), st.T_max)
+            self._fgm = pad(tuple(self._gm), st.T_max)
             self._stacks_version = self.version
-        return self._stacks, self._fgp, self._fgm
+        return self._stacks, self._fgp, self._fgm, self._idx
 
     def _scatter_back(self, gp, gm, views_plus, views_minus) -> None:
         """Keep the compiled step's donated envelope and hand its
@@ -273,14 +296,41 @@ class ChipFarm:
                 h = q.adc_quantize_ste(h, self.spec.adc_bits)
         return acts, dps
 
-    def _split(self, x: jax.Array, what: str) -> jax.Array:
-        x = jnp.atleast_2d(x)
-        M = x.shape[0]
-        if M % self.n_chips:
+    def _rows(self, x: jax.Array, what: str) -> jax.Array:
+        """The global batch as a meshed program takes it: rows in replica
+        order, split over the devices (a batch that arrives so split is
+        left where it is)."""
+        if not isinstance(x, jax.Array) or x.ndim < 2:
+            x = jnp.atleast_2d(x)
+        if x.shape[0] % self.n_chips:
             raise ValueError(
-                f"{what} batch {M} does not divide over {self.n_chips} "
-                f"chips")
+                f"{what} batch {x.shape[0]} does not divide over "
+                f"{self.n_chips} chips")
+        if self.mesh is None or x.sharding == self._by_chip:
+            return x
+        return jax.device_put(x, self._by_chip)
+
+    def _split(self, x: jax.Array, what: str) -> jax.Array:
+        """(C, M / C, D): chip ``c`` takes the ``c``-th block of rows."""
+        x = self._rows(x, what)
+        M = x.shape[0]
         return x.reshape(self.n_chips, M // self.n_chips, x.shape[1])
+
+    def _scalar(self, v: float):
+        """``v`` as the compiled step takes it: on a mesh a float32 every
+        device holds, placed once for each value, so a step with the
+        same learning rate moves nothing to the devices."""
+        if self.mesh is None:
+            return v
+        if self._held_scalar[0] != v:
+            self._held_scalar = (v, jax.device_put(
+                np.float32(v), NamedSharding(self.mesh, P())))
+        return self._held_scalar[1]
+
+    def _meshed(self) -> bool:
+        """The compiled program runs on the mesh and takes the batch as
+        rows (`_rows`), not chip-stacked (`_split`)."""
+        return self.mesh is not None and self._compiled_active()
 
     # ------------------------------------------------------------------
     # Inference (wave semantics; serving goes through FarmServer)
@@ -294,20 +344,24 @@ class ChipFarm:
         counters = self.chip_infer if count else None
         if self._compiled_active():
             with spans.phase(spans.PREPARE):
-                xb = self._split(x, "infer")
-                st, gp, gm = self._get_stacks()
-                idx, cfg = st.index_pytree(), csim.chip_config(st, self.spec)
+                xb = (self._rows if self._meshed() else self._split)(
+                    x, "infer")
+                Mc = (xb.shape[0] // self.n_chips if xb.ndim == 2
+                      else xb.shape[1])
+                st, gp, gm, idx = self._get_stacks()
+                cfg = csim.chip_config(st, self.spec)
             with spans.phase(spans.DISPATCH):
-                out, fcnt = csim.chip_infer(gp, gm, xb, idx, cfg)
+                out, fcnt = csim.chip_infer(gp, gm, xb, idx, cfg,
+                                            mesh=self.mesh)
             if count:
-                self._apply_phase_counters(counters, fcnt, None, xb.shape[1])
+                self._apply_phase_counters(counters, fcnt, None, Mc)
         else:
             xb = self._split(x, "infer")
+            Mc = xb.shape[1]
             _, dps = self._forward(xb, counters)
             out = hard_sigmoid(dps[-1])
         if count:
             with spans.phase(spans.ACCOUNT):
-                Mc = xb.shape[1]
                 bits = (self.placement.dims[0] * self.input_bits
                         + self.placement.dims[-1] * hw.ADC_BITS_OUT)
                 for c in self.chip_infer:
@@ -332,23 +386,26 @@ class ChipFarm:
         from repro.dist.collectives import farm_reduce_sum
 
         with spans.phase(spans.PREPARE):
-            xb = self._split(x, "train")
-            tb = self._split(jnp.atleast_2d(target), "target")
+            batch = self._rows if self._meshed() else self._split
+            xb, tb = batch(x, "train"), batch(target, "target")
         spec = self.spec
-        C, Mc = xb.shape[0], xb.shape[1]
-        M = C * Mc
+        C = self.n_chips
+        M = xb.shape[0] if xb.ndim == 2 else C * xb.shape[1]
+        Mc = M // C
 
         if self._compiled_active():
             # the whole farm step — chip-axis wave, reversed bwd scan,
             # farm_reduce_sum reconciliation INSIDE the trace, pulses
-            # broadcast to every replica — is ONE donated XLA program.
+            # broadcast to every replica — is ONE donated XLA program,
+            # on a mesh split over its devices by chip.
             with spans.phase(spans.PREPARE):
-                st, gp, gm = self._get_stacks()
-                idx, cfg = st.index_pytree(), csim.chip_config(st, self.spec)
+                st, gp, gm, idx = self._get_stacks()
+                cfg = csim.chip_config(st, self.spec)
+                lr_eff = self._scalar(float(lr) / M)
             with spans.phase(spans.DISPATCH):
                 out = csim.chip_train(
-                    gp, gm, xb, tb, idx, cfg, lr_eff=float(lr) / M,
-                    reconcile=reconcile)
+                    gp, gm, xb, tb, idx, cfg, lr_eff=lr_eff,
+                    reconcile=reconcile, mesh=self.mesh)
             with spans.phase(spans.WRITEBACK):
                 gp2, gm2, err, fcnt, bcnt = out
                 self._scatter_back(gp2, gm2, *csim.kept_views(out, cfg))
@@ -427,7 +484,7 @@ class ChipFarm:
         codes — `hw_model.farm_cost` prices the same constant — so the
         metered traffic does not depend on the ``reconcile`` mode; "none"
         is a numerics idealization (exact f32 sum), not a wider link."""
-        cells = sum(int(gp[0].size) for gp in self._gp)
+        cells = sum(gp.size // gp.shape[0] for gp in self._gp)
         return 2 * cells * hw.ERR_BITS_LINK
 
     # ------------------------------------------------------------------
@@ -769,7 +826,7 @@ class FarmServer:
         farm.serve_sessions += 1
         C, S = self.C, self.S
         with spans.phase(spans.PREPARE):
-            st, gp, gm = farm._get_stacks()
+            st, gp, gm, _ = farm._get_stacks()
             gp_cat = jnp.moveaxis(gp, 0, 1).reshape(C, S * st.T_max,
                                                     st.rows, st.cols)
             gm_cat = jnp.moveaxis(gm, 0, 1).reshape(C, S * st.T_max,
@@ -817,9 +874,10 @@ class FarmServer:
         With the compiled executor active, a fresh server draining a
         uniform-shape queue runs the whole session as one jitted beat
         scan; step-wise use (partially drained pipes, beat limits, ragged
-        shapes) stays on the eager per-beat path."""
+        shapes) and a farm on a mesh stay on the eager per-beat path."""
         with spans.phase(spans.PREPARE):
-            compiled = (self.farm._compiled_active() and max_beats is None
+            compiled = (self.farm._compiled_active()
+                        and self.farm.mesh is None and max_beats is None
                         and csim.serve_session_applicable(
                             queue, all(s is None for lane in self.pipe
                                        for s in lane), self._slot_m))

@@ -40,11 +40,16 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import quantization as q
 from repro.core.crossbar import F32, hard_sigmoid, hard_sigmoid_deriv
 from repro.kernels import ops as kernel_ops
 from repro.sim import spans
+
+
+#: the mesh axis a sharded farm splits its replicas over
+FARM_AXIS = "chips"
 
 
 def kernel_body_enabled() -> bool:
@@ -217,7 +222,8 @@ def _forward_scan(gp, gm, x, idx, quantize_tail, cfg: ChipConfig):
 
 
 def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
-                   lr_eff, reconcile: str | None):
+                   lr_eff, reconcile: str | None,
+                   axis_name: str | None = None):
     """Bwd + update phases as one reversed ``lax.scan``.
 
     ``lr_eff`` (lr / global batch) is a TRACED scalar — an lr schedule
@@ -228,8 +234,12 @@ def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
     ``reconcile in ("none", "int8")`` is the farm's data-parallel path:
     local outer products, `farm_reduce_sum` reconciliation INSIDE the
     trace, the pulse discretized once on the sum and broadcast to every
-    replica.  Returns (new gp, new gm, upstream delta, counters)."""
-    from repro.dist.collectives import farm_reduce_sum
+    replica.  ``axis_name`` is the mesh axis when the program runs under
+    ``shard_map`` with this device's chips only: the error full-scale is
+    then agreed by `farm_max` and the outer products are psummed by
+    `farm_reduce_sum` over it.  Returns (new gp, new gm, upstream delta,
+    counters)."""
+    from repro.dist.collectives import farm_max, farm_reduce_sum
 
     C, M = delta.shape[0], delta.shape[1]
     B_total = C * M
@@ -241,8 +251,10 @@ def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
             # III.F step 1 with the farm-shared full-scale: quantizing the
             # flattened global tensor IS max-abs over every chip's shard.
             flat = delta.reshape(B_total, -1)
-            delta = (q.error_quantize(flat, cfg.err_bits).dequantize()
-                     .reshape(C, M, -1))
+            full = (None if axis_name is None else
+                    farm_max(jnp.max(jnp.abs(flat)), axis_name=axis_name))
+            delta = (q.error_quantize(flat, cfg.err_bits, full_scale=full)
+                     .dequantize().reshape(C, M, -1))
         local = delta * hard_sigmoid_deriv(dp_s)
         local_ext = jnp.concatenate(
             [local, jnp.zeros((C, M, 1), jnp.float32)], axis=2)
@@ -293,7 +305,8 @@ def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
                 dxs = jnp.einsum("ctmn,ctkn->ctmk", ds, w, precision=F32)
                 dw_local = jnp.einsum("ctmk,ctmn->ctkn", xs, ds,
                                       precision=F32)
-            dw = 2.0 * lr_eff * farm_reduce_sum(dw_local, mode=reconcile)
+            dw = 2.0 * lr_eff * farm_reduce_sum(dw_local, mode=reconcile,
+                                                axis_name=axis_name)
             if cfg.update_quant:
                 dw = q.pulse_discretize(dw, cfg.max_update,
                                         cfg.update_levels, None)
@@ -406,37 +419,18 @@ def chip_forward(gp, gm, x, idx, quantize_tail, cfg: ChipConfig):
             h[..., :cfg.out_dim], cnt)
 
 
-@partial(jax.jit, static_argnames=("cfg",))
-def chip_infer(gp, gm, x, idx, cfg: ChipConfig):
-    """Compiled recognition wave -> (out, counters).
-
-    ``x`` is chip-stacked (C, M, fan_in) or plain (M, fan_in) — the
-    serial case is bridged to C == 1 INSIDE the program, so the caller
-    pays no per-call reshape dispatches."""
-    _mark("chip_infer", cfg, x.shape)
-    serial = x.ndim == 2
-    _, dps, _, cnt = _forward_scan(
-        gp, gm, x[None] if serial else x, idx, jnp.asarray(False), cfg)
-    out = hard_sigmoid(dps[-1])[:, :, :cfg.out_dim]
-    return (out[0] if serial else out), cnt
+def _infer(gp, gm, x, idx, cfg: ChipConfig):
+    """Recognition wave on chip-stacked ``x`` (C, M, fan_in): (out (C, M,
+    out_dim), counters)."""
+    _, dps, _, cnt = _forward_scan(gp, gm, x, idx, jnp.asarray(False), cfg)
+    return hard_sigmoid(dps[-1])[:, :, :cfg.out_dim], cnt
 
 
-@_returns_views(5)
-@partial(jax.jit, static_argnames=("cfg", "reconcile"),
-         donate_argnums=(0, 1))
-def chip_train(gp, gm, x, target, idx, cfg: ChipConfig, lr_eff=0.1,
-               reconcile: str | None = None):
-    """Compiled training step — forward wave + reversed bwd/update scan in
-    ONE donated program.  Returns the `Stepped` (gp', gm', err, fwd
-    counters, bwd counters); the conductance stacks update in place
-    (donation), and its ``views`` are gp'/gm' cut to each stage's cores,
-    the layout the simulator objects keep (`kept_views`).
-    ``x``/``target`` rank-bridge like :func:`chip_infer`; ``lr_eff`` is a
-    traced scalar (an lr schedule reuses one executable)."""
-    _mark("chip_train", cfg, x.shape, reconcile)
-    serial = x.ndim == 2
-    if serial:
-        x, target = x[None], target[None]
+def _train(gp, gm, x, target, idx, cfg: ChipConfig, lr_eff, reconcile,
+           axis_name: str | None = None):
+    """Forward wave and reversed bwd/update scan on chip-stacked ``x`` (C,
+    M, fan_in) and ``target``: (gp', gm', err (C, M, out_dim), fwd
+    counters, bwd counters)."""
     acts, dps, _, fcnt = _forward_scan(
         gp, gm, x, idx, jnp.asarray(False), cfg)
     out = hard_sigmoid(dps[-1])
@@ -445,8 +439,90 @@ def chip_train(gp, gm, x, target, idx, cfg: ChipConfig, lr_eff=0.1,
     tpad = tpad.at[:, :, :target.shape[2]].set(target)
     delta0 = tpad - out
     gp2, gm2, _, bcnt = _backward_scan(gp, gm, acts, dps, delta0, idx, cfg,
-                                       lr_eff, reconcile)
-    err = delta0[:, :, :cfg.out_dim]
+                                       lr_eff, reconcile, axis_name)
+    return gp2, gm2, delta0[:, :, :cfg.out_dim], fcnt, bcnt
+
+
+#: how a farm program splits its operands over the farm's mesh, where
+#: each device runs its own replicas under ``shard_map`` (so the stacked
+#: kernels run per device on its chip slice, and no custom call is ever
+#: partitioned by the compiler): the envelope's chip axis (axis 1), the
+#: rows of the batch (and the per-stage views, chip-first), and what
+#: every device holds alike
+_CHIPS, _ROWS, _ALL = P(None, FARM_AXIS), P(FARM_AXIS), P()
+
+
+@partial(jax.jit, static_argnames=("cfg", "mesh"))
+def chip_infer(gp, gm, x, idx, cfg: ChipConfig, mesh=None):
+    """Compiled recognition wave -> (out, counters).
+
+    ``x`` is chip-stacked (C, M, fan_in) or plain (M, fan_in) — the
+    serial case is bridged to C == 1 INSIDE the program, so the caller
+    pays no per-call reshape dispatches.  With the farm's ``mesh``, ``x``
+    is the global batch (M, fan_in), its rows split over the devices, and
+    ``out`` is (M, out_dim) split the same way, each device running its
+    own replicas under ``shard_map``."""
+    if mesh is not None:
+        _mark("chip_infer", cfg, x.shape, tuple(mesh.shape.items()))
+
+        def body(gp, gm, x, idx):
+            out, cnt = _infer(gp, gm, x.reshape(gp.shape[1], -1, x.shape[-1]),
+                              idx, cfg)
+            return out.reshape(-1, cfg.out_dim), cnt
+
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(_CHIPS, _CHIPS, _ROWS, _ALL),
+                             out_specs=(_ROWS, _ALL),
+                             check_vma=False)(gp, gm, x, idx)
+    _mark("chip_infer", cfg, x.shape)
+    serial = x.ndim == 2
+    out, cnt = _infer(gp, gm, x[None] if serial else x, idx, cfg)
+    return (out[0] if serial else out), cnt
+
+
+@_returns_views(5)
+@partial(jax.jit, static_argnames=("cfg", "reconcile", "mesh"),
+         donate_argnums=(0, 1))
+def chip_train(gp, gm, x, target, idx, cfg: ChipConfig, lr_eff=0.1,
+               reconcile: str | None = None, mesh=None):
+    """Compiled training step — forward wave + reversed bwd/update scan in
+    ONE donated program.  Returns the `Stepped` (gp', gm', err, fwd
+    counters, bwd counters); the conductance stacks update in place
+    (donation), and its ``views`` are gp'/gm' cut to each stage's cores,
+    the layout the simulator objects keep (`kept_views`).
+    ``x``/``target`` rank-bridge like :func:`chip_infer`; ``lr_eff`` is a
+    traced scalar (an lr schedule reuses one executable).
+
+    With the farm's ``mesh`` every device runs its replicas' slice of the
+    step under ``shard_map``: ``x``/``target`` are the global batch, rows split
+    over the devices; the error full-scale and the reconciled outer
+    products are agreed across devices inside the program; ``err`` comes
+    back (M, out_dim) and the views split by chips like the stacks."""
+    if mesh is not None:
+        _mark("chip_train", cfg, x.shape, reconcile,
+              tuple(mesh.shape.items()))
+
+        def body(gp, gm, x, target, idx, lr_eff):
+            c = gp.shape[1]
+            gp2, gm2, err, fcnt, bcnt = _train(
+                gp, gm, x.reshape(c, -1, x.shape[-1]),
+                target.reshape(c, -1, target.shape[-1]), idx, cfg, lr_eff,
+                reconcile, FARM_AXIS)
+            return (gp2, gm2, err.reshape(-1, cfg.out_dim), fcnt, bcnt,
+                    _stage_views(gp2, cfg), _stage_views(gm2, cfg))
+
+        views = (_ROWS,) * cfg.S
+        return jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(_CHIPS, _CHIPS, _ROWS, _ROWS, _ALL, _ALL),
+            out_specs=(_CHIPS, _CHIPS, _ROWS, _ALL, _ALL, views, views),
+            check_vma=False)(gp, gm, x, target, idx, lr_eff)
+    _mark("chip_train", cfg, x.shape, reconcile)
+    serial = x.ndim == 2
+    if serial:
+        x, target = x[None], target[None]
+    gp2, gm2, err, fcnt, bcnt = _train(gp, gm, x, target, idx, cfg, lr_eff,
+                                       reconcile)
     return (gp2, gm2, (err[0] if serial else err), fcnt, bcnt,
             _stage_views(gp2, cfg), _stage_views(gm2, cfg))
 

@@ -350,6 +350,33 @@ print("OK")
     assert "OK" in out
 
 
+def test_int8_reconcile_codes_each_chip_on_a_mesh(subproc):
+    """Inside shard_map with two chips on each device, every chip is still
+    coded against its own full-scale: a loud and a quiet chip that share a
+    device reconcile as they do on one device."""
+    out = subproc("""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import PartitionSpec as P
+from repro.dist.collectives import farm_reduce_sum
+mesh = jax.make_mesh((4,), ("chips",))
+scales = jnp.array([100.0, 1e-3, 3.0, 2e-2, 5.0, 1e-4, 7.0, 1.0])
+x = jax.random.normal(jax.random.PRNGKey(0), (8, 3, 5)) \
+    * scales[:, None, None]
+fn = jax.shard_map(lambda v: farm_reduce_sum(v, mode="int8",
+                                             axis_name="chips"),
+                   mesh=mesh, in_specs=P("chips"), out_specs=P(),
+                   check_vma=False)
+# equal up to the order of the f32 sum (each device adds its two chips
+# before the psum); coding the pair on device 0 with one full-scale would
+# move the quiet chip 1 by up to half a step of the loud one's, about 1
+want = farm_reduce_sum(x, mode="int8")
+np.testing.assert_allclose(np.asarray(fn(x)), np.asarray(want),
+                           rtol=1e-6, atol=0)
+print("OK")
+""", devices=4)
+    assert "OK" in out
+
+
 # ---------------------------------------------------------------------------
 # Device-mesh execution (shard_map over the chip axis)
 # ---------------------------------------------------------------------------
@@ -393,6 +420,85 @@ ref = xb.mlp_forward(farm.layers(), x, PAPER_SPEC)
 assert float(jnp.abs(out - ref).max()) <= 1e-5
 print("OK")
 """, devices=4)
+    assert "OK" in out
+
+
+MESHED_COMPILED = """
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs.paper_apps import PAPER_SPEC
+from repro.core import crossbar as xb
+from repro.sim import ChipFarm, VirtualChip, compiled as csim
+from repro.sim.cluster import make_farm_mesh
+dims, C, reconcile = {dims}, {chips}, "{reconcile}"
+key = jax.random.PRNGKey(0)
+L = [xb.init_conductances(jax.random.fold_in(key, i), f, o, PAPER_SPEC)
+     for i, (f, o) in enumerate(zip(dims, dims[1:]))]
+mesh = make_farm_mesh(C)
+assert mesh.shape["chips"] == 4, mesh
+farm = ChipFarm([dict(p) for p in L], PAPER_SPEC, n_chips=C, mesh=mesh)
+one = ChipFarm([dict(p) for p in L], PAPER_SPEC, n_chips=C)
+chip = VirtualChip([dict(p) for p in L], PAPER_SPEC)
+assert farm._compiled_active()
+csim.reset_trace_counts()
+M = 2 * C
+for step in range(3):
+    x = jax.random.uniform(jax.random.PRNGKey(9 + step), (M, dims[0]),
+                           minval=-0.5, maxval=0.5)
+    t = jax.random.uniform(jax.random.PRNGKey(19 + step), (M, dims[-1]),
+                           minval=-0.5, maxval=0.5)
+    ef = farm.train_step(x, t, lr=0.1, reconcile=reconcile)
+    # the same program on one device: bitwise equal
+    assert np.array_equal(np.asarray(ef), np.asarray(
+        one.train_step(x, t, lr=0.1, reconcile=reconcile)))
+    if reconcile == "none":
+        # the serial chip runs another program (one dw contraction over
+        # the whole batch, not a sum of per-chip ones): equal to the
+        # float re-association the one-device farm pin allows
+        ec = chip.train_step(x, t, lr=0.1)
+        assert float(jnp.abs(ef - ec).max()) <= 1e-6
+assert farm.replicas_in_sync()
+for a, b, c in zip(farm.layers(), one.layers(), chip.layers()):
+    for k in ("g_plus", "g_minus"):
+        assert np.array_equal(np.asarray(a[k]), np.asarray(b[k])), k
+        if reconcile == "none":
+            assert float(jnp.abs(a[k] - c[k]).max()) <= 1e-6, k
+# one trace of the meshed step for the (topology, batch) over 3 steps
+keys = [k for k in csim.trace_counts() if k[0] == "chip_train"
+        and k[-1] == (("chips", 4),)]
+assert len(keys) == 1 and csim.trace_counts()[keys[0]] == 1, keys
+# the stacks stay split by chip over the 4 devices after the steps
+for g in farm._gp + farm._gm:
+    assert len(g.sharding.device_set) == 4
+    assert {{s.data.shape[0] for s in g.addressable_shards}} == {{C // 4}}
+st, gp, _, _ = farm._get_stacks()
+assert {{s.data.shape[1] for s in gp.addressable_shards}} == {{C // 4}}
+# the batch stays split by rows, and so does the answer
+x = jax.device_put(x, farm._by_chip)
+assert farm._rows(x, "x") is x
+assert farm._split(x, "x").sharding.spec[0] == "chips"
+out = farm.infer(x)
+assert out.sharding.spec == jax.sharding.PartitionSpec("chips")
+assert np.array_equal(np.asarray(out), np.asarray(one.infer(x)))
+if reconcile == "none":
+    assert float(jnp.abs(out - chip.infer(x)).max()) <= 1e-6
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("dims,chips,reconcile", [
+    ([41, 15, 41], 4, "none"),
+    (hw.PAPER_NETWORKS["mnist_class"], 4, "none"),
+    (hw.PAPER_NETWORKS["mnist_class"], 8, "none"),     # 2 chips a device
+    ([41, 15, 41], 8, "int8"),     # each chip coded, not each device
+])
+def test_meshed_farm_runs_the_compiled_step(subproc, dims, chips,
+                                            reconcile):
+    """A farm on a 4-device mesh runs `train_step` and `infer` through the
+    compiled program, sharded: it equals the one-device compiled farm
+    bitwise (and the serial chip within re-association), its replicas stay
+    in lockstep, it traces once, and stacks and rows stay split."""
+    out = subproc(MESHED_COMPILED.format(dims=list(dims), chips=chips,
+                                         reconcile=reconcile), devices=4)
     assert "OK" in out
 
 

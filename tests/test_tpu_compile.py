@@ -118,3 +118,43 @@ def test_mnist_chip_train_compiles_for_v5e_with_kernel_body(one_chip,
     mem = compiled.memory_analysis()
     # the conductance stacks are donated: updated in place, not copied
     assert mem.alias_size_in_bytes >= 2 * st.g_plus.nbytes
+
+
+def test_meshed_mnist_farm_step_compiles_for_four_v5e_chips(topo, one_chip,
+                                                           monkeypatch):
+    """The farm of four ``mnist_class`` replicas at batch 256, one replica
+    per chip of a 2x2 v5e host: one program with the kernels run per chip,
+    the reconciliation and the error full-scale as all-reduces, the
+    envelope never gathered, and the stacks donated."""
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.sim import ChipFarm
+    monkeypatch.setattr(kernel_ops, "_default_interpret", lambda: False)
+    dims = NETWORKS["mnist_class"]
+    key = jax.random.PRNGKey(0)
+    layers = [xb.init_conductances(jax.random.fold_in(key, i), f, o,
+                                   PAPER_SPEC)
+              for i, (f, o) in enumerate(zip(dims, dims[1:]))]
+    farm = ChipFarm(layers, PAPER_SPEC, n_chips=4)
+    st, gp, _, _ = farm._get_stacks()
+    cfg = csim.chip_config(st, PAPER_SPEC)._replace(use_kernels=True)
+    mesh = Mesh(topo.devices, ("chips",))
+    chips, rows, alike = (NamedSharding(mesh, p) for p in
+                          (P(None, "chips"), P("chips"), P()))
+    idx = {k: _spec(alike, *v.shape, dtype=v.dtype)
+           for k, v in st.index_pytree().items()}
+    compiled = csim.chip_train.lower(
+        _spec(chips, *gp.shape), _spec(chips, *gp.shape),
+        _spec(rows, 256, dims[0]), _spec(rows, 256, dims[-1]), idx, cfg,
+        lr_eff=_spec(alike), reconcile="none", mesh=mesh).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    opcodes = re.findall(r" ([a-z-]+)\(", text)
+    assert opcodes.count("all-reduce") == 2       # psum of dw, pmax
+    assert not {"all-gather", "all-to-all", "collective-permute",
+                "reduce-scatter"} & set(opcodes)
+    # each chip holds and updates in place its replica's envelope
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * gp.nbytes // 4
