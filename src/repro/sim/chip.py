@@ -64,6 +64,13 @@ def compiled_enabled() -> bool:
     return os.environ.get("REPRO_SIM_COMPILED", "1") != "0"
 
 
+def as_rows(x) -> jax.Array:
+    """``x`` as a batch of rows, (M, width).  ``jnp.atleast_2d`` is a
+    program launch even on a 2-D array, so a batch that is already 2-D
+    passes as it is."""
+    return x if np.ndim(x) == 2 else jnp.atleast_2d(x)
+
+
 def _tile_cols(v: jax.Array, r: int, c: int, cols: int) -> jax.Array:
     """(M, fan_out) per-neuron values -> (r*c, M, cols) per-core slabs
     (slice t = i*c + j carries fan-out tile j, same for every fan-in i)."""
@@ -106,6 +113,7 @@ class VirtualChip:
             self.faults = faults
         self.placement = placement
         self._stacks: StageStacks | None = None   # compiled-path envelope
+        self._held: dict = {}         # name -> (value, device copy)
         self.infer_counters = PhaseCounters(
             noc=NocTracker(slot_cycles=placement.cols))
         self.train_counters = PhaseCounters(
@@ -129,6 +137,15 @@ class VirtualChip:
                 or self._stacks.built_version != self.placement.version):
             self._stacks = build_stage_stacks(self.placement)
         return self._stacks
+
+    def _on_device(self, name: str, v):
+        """The numpy scalar ``v`` as the compiled programs take it, placed
+        on the device once for each value: a call that passes ``name``
+        the value it passed last moves nothing to the device."""
+        held = self._held.get(name)
+        if held is None or held[0] != v:
+            held = self._held[name] = (v, jax.device_put(v))
+        return held[1]
 
     @property
     def _cfg(self) -> "csim.ChipConfig":
@@ -227,13 +244,13 @@ class VirtualChip:
         if count:
             counters = self.train_counters if train else self.infer_counters
         if not self._compiled_active():
-            return self._forward(jnp.atleast_2d(x), counters,
+            return self._forward(as_rows(x), counters,
                                  quantize_tail=quantize_tail)
         with spans.phase(spans.PREPARE):
-            x = jnp.atleast_2d(x)
+            x = as_rows(x)
             st = self._get_stacks()
             idx, cfg = st.index_pytree(), self._cfg
-            tail = jnp.asarray(bool(quantize_tail))
+            tail = self._on_device("tail", np.bool_(quantize_tail))
         with spans.phase(spans.DISPATCH):
             acts, dps, h, fcnt = csim.chip_forward(
                 st.g_plus, st.g_minus, x, idx, tail, cfg)
@@ -328,18 +345,13 @@ class VirtualChip:
         if self._compiled_active():
             with spans.phase(spans.PREPARE):
                 st = self._get_stacks()
-                acts_p = jnp.zeros((st.S, M, st.L), jnp.float32)
-                dps_p = jnp.zeros((st.S, M, st.N_pad), jnp.float32)
-                for s in range(st.S):
-                    acts_p = acts_p.at[s, :, 1:st.fan_in[s] + 1].set(acts[s])
-                    dps_p = dps_p.at[s, :, :st.fan_out[s]].set(dps[s])
-                delta_p = jnp.zeros((M, st.N_pad), jnp.float32)
-                delta_p = delta_p.at[:, :delta.shape[1]].set(delta)
                 idx, cfg = st.index_pytree(), self._cfg
+                acts_p, dps_p, delta_p = csim.pad_wave(acts, dps, delta, cfg)
+                lr_eff = self._on_device("lr_eff", np.float32(float(lr) / B))
             with spans.phase(spans.DISPATCH):
                 out = csim.chip_backward(
                     st.g_plus, st.g_minus, acts_p, dps_p, delta_p, idx, cfg,
-                    lr_eff=float(lr) / B)
+                    lr_eff=lr_eff)
             with spans.phase(spans.WRITEBACK):
                 st.g_plus, st.g_minus, delta_prev, bcnt = out
                 st.scatter_back(self.placement, *csim.kept_views(out, cfg))

@@ -549,6 +549,25 @@ def chip_backward(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
             _stage_views(gp2, cfg), _stage_views(gm2, cfg))
 
 
+@partial(jax.jit, static_argnames=("cfg",))
+def pad_wave(acts, dps, delta, cfg: ChipConfig):
+    """A slice's wave as :func:`chip_forward` returned it, padded into the
+    envelope :func:`chip_backward` takes, in one launch: (acts (S, M, L),
+    dps (S, M, N_pad), delta (M, N_pad)).  Each stage's inputs fill lanes
+    [1, fan_in] (bias slot 0 and the lanes beyond stay zero), its dot
+    products lanes [0, fan_out), and the slice's output-side ``delta``
+    (M, width) its first lanes."""
+    _mark("pad_wave", cfg, delta.shape)
+    M = delta.shape[0]
+    acts_p = jnp.zeros((cfg.S, M, cfg.L), jnp.float32)
+    dps_p = jnp.zeros((cfg.S, M, cfg.N_pad), jnp.float32)
+    for s in range(cfg.S):
+        acts_p = acts_p.at[s, :, 1:cfg.fan_in[s] + 1].set(acts[s])
+        dps_p = dps_p.at[s, :, :cfg.fan_out[s]].set(dps[s])
+    delta_p = jnp.zeros((M, cfg.N_pad), jnp.float32)
+    return acts_p, dps_p, delta_p.at[:, :delta.shape[1]].set(delta)
+
+
 # ---------------------------------------------------------------------------
 # Serving beat loop (farm front-end and pipeline front-end)
 # ---------------------------------------------------------------------------

@@ -60,7 +60,7 @@ from repro.kernels import ops as kernel_ops
 from repro.runtime.serve_loop import RequestQueue
 from repro.sim import compiled as csim
 from repro.sim import spans
-from repro.sim.chip import VirtualChip, compiled_enabled
+from repro.sim.chip import VirtualChip, as_rows, compiled_enabled
 from repro.sim.placer import (Placement, StageStacks, build_stage_stacks,
                               fold_subneuron_partials, place_network,
                               stage_dp_from_outputs, sub_placement,
@@ -178,8 +178,7 @@ class ChipPipeline:
         once per batch with a shared error full-scale (the same argument
         that makes the farm equal the serial chip, DESIGN.md §6.2)."""
         with spans.phase(spans.PREPARE):
-            x = jnp.atleast_2d(x)
-            target = jnp.atleast_2d(target)
+            x, target = as_rows(x), as_rows(target)
         M = x.shape[0]
         if M % n_micro:
             raise ValueError(f"batch {M} not divisible by n_micro {n_micro}")
@@ -191,15 +190,14 @@ class ChipPipeline:
             acts, dps, h = chip.forward_wave(h, train=True,
                                              quantize_tail=k < last)
             waves.append((acts, dps))
-        out = h
-        with spans.phase(spans.PREPARE):
-            delta = target - out
+        with spans.phase(spans.WRITEBACK):
+            # the step's result, and the error the last chip takes back
+            err = target - h
+        delta = err
         for k in reversed(range(self.n_chips)):
             acts, dps = waves[k]
             delta = self.chips[k].backward_update(acts, dps, delta, lr,
                                                   global_batch=M)
-        with spans.phase(spans.WRITEBACK):
-            err = target - out
 
         with spans.phase(spans.ACCOUNT):
             for k, chip in enumerate(self.chips):

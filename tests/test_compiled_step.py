@@ -280,6 +280,7 @@ def test_one_compile_per_topology_and_batch():
     assert counts[key_infer] == 1, counts
     assert counts[("chip_forward", cfg, (4, 41))] == 1, counts
     assert counts[("chip_backward", cfg, (4, st.N_pad), None)] == 1, counts
+    assert counts[("pad_wave", cfg, (4, 41))] == 1, counts
     # an lr schedule reuses the SAME executable (lr_eff is traced) ...
     chips[0].train_step(x, tgt, lr=0.37)
     assert csim.trace_counts()[key_train] == 1
@@ -459,6 +460,94 @@ def test_writeback_keeps_a_rebuilt_results_stacks(monkeypatch, kind):
                                               np.asarray(v[s]))
                 seen += 1
     assert seen == 2 * len(RAGGED["dims"][1:])
+
+
+def _eager_envelope(acts, dps, delta, st):
+    """The padded wave as the eager per-stage loop builds it: zeros, then
+    one ``.at[].set`` per stage and operand."""
+    M = delta.shape[0]
+    acts_p = jnp.zeros((st.S, M, st.L), jnp.float32)
+    dps_p = jnp.zeros((st.S, M, st.N_pad), jnp.float32)
+    for s in range(st.S):
+        acts_p = acts_p.at[s, :, 1:st.fan_in[s] + 1].set(acts[s])
+        dps_p = dps_p.at[s, :, :st.fan_out[s]].set(dps[s])
+    delta_p = jnp.zeros((M, st.N_pad), jnp.float32)
+    return acts_p, dps_p, delta_p.at[:, :delta.shape[1]].set(delta)
+
+
+def test_pad_wave_equals_the_eager_envelope_bitwise():
+    """Each slice of a two-chip pipeline with ragged stages pads its wave
+    in one program into the very envelope the eager loop builds: payload
+    lanes, the zero bias slot and every zero lane beyond a stage's
+    widths."""
+    pipe = _build("pipeline")
+    x = _x(RAGGED["dims"], n=4)
+    h = x
+    for k, chip in enumerate(pipe.chips):
+        acts, dps, h = chip.forward_wave(h, count=False,
+                                         quantize_tail=k == 0)
+        delta = _x([h.shape[1]], n=4, seed=5 + k)
+        st = chip._get_stacks()
+        got = csim.pad_wave(acts, dps, delta, chip._cfg)
+        want = _eager_envelope(acts, dps, delta, st)
+        for g, w in zip(got, want):
+            assert g.shape == w.shape and g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        acts_p, dps_p, delta_p = (np.asarray(a) for a in got)
+        assert not np.any(acts_p[:, :, 0])             # the bias slot
+        for s in range(st.S):
+            assert not np.any(acts_p[s, :, st.fan_in[s] + 1:])
+            assert not np.any(dps_p[s, :, st.fan_out[s]:])
+        assert not np.any(delta_p[:, delta.shape[1]:])
+        assert np.any(acts_p) and np.any(dps_p) and np.any(delta_p)
+    # the second slice pads stages of 6 and 1 cores into one envelope
+    assert [c._get_stacks().Ts for c in pipe.chips] == [(12,), (6, 1)]
+
+
+def _prepare_launches(log_dir) -> int:
+    """Program launches (``PjitFunction``) and transfers (``DevicePut``)
+    the host dispatched inside ``repro.prepare`` spans of a profiler
+    trace, an event nested in another counted event counted once."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    n = 0
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for line in plane.lines:
+            evs = [(ev.name, int(ev.start_ns),
+                    int(ev.start_ns) + int(ev.duration_ns))
+                   for ev in line.events]
+            prep = [(a, b) for name, a, b in evs if name == "repro.prepare"]
+            hits = sorted(((a, -b) for name, a, b in evs
+                           if name.startswith(("PjitFunction", "DevicePut"))
+                           and any(p <= a and b <= q for p, q in prep)))
+            end = None
+            for a, neg_b in hits:
+                if end is None or a >= end:      # not inside the last one
+                    n, end = n + 1, -neg_b
+    return n
+
+
+def test_pipeline_train_prepare_launches_one_program_per_chip(tmp_path):
+    """Once its shapes are warm, a pipeline training step builds its
+    operands with at most one program per modelled chip (the padded
+    wave): nothing eager, no transfer of an unchanged scalar."""
+    pipe = _build("pipeline")
+    x = _x(RAGGED["dims"], n=4)
+    tgt = _x(RAGGED["dims"], n=4, seed=3)[:, :RAGGED["dims"][-1]]
+    jax.block_until_ready(pipe.train_step(x, tgt, 0.1))   # compile, place
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    steps = 2
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        for _ in range(steps):
+            jax.block_until_ready(pipe.train_step(x, tgt, 0.1))
+    n = _prepare_launches(tmp_path)
+    assert 0 < n <= steps * pipe.n_chips, n
 
 
 @pytest.mark.parametrize("kind", ["chip", "farm"])
