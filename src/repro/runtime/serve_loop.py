@@ -9,11 +9,11 @@ paper's static routing.
 :class:`RequestQueue` is the shared front-end discipline: a FIFO of
 fixed-shape requests with per-slot refill, used by the LM
 :class:`BatchedServer` pattern here, by the chip farm's pipelined serving
-loop (``repro.sim.cluster.FarmServer``, DESIGN.md §6) where each chip's
-stage-0 slot refills from the queue every pipeline beat, and by the
+loop (``repro.sim.cluster.FarmServer``, DESIGN.md §6) where request ``r``
+enters chip ``r % C``'s stage-0 slot at beat ``r // C``, and by the
 pipeline fabric's front-end (``repro.sim.fabric.PipelineServer``,
-DESIGN.md §7) where the fabric's single stage-0 slot refills per beat and
-a request walks the chip chain at one beat per stage hop.
+DESIGN.md §7) where a request walks the chip chain at one beat per stage
+hop.
 """
 from __future__ import annotations
 
@@ -73,8 +73,7 @@ class RequestQueue:
     @property
     def pending(self) -> tuple:
         """Read-only snapshot of the queued requests (arrival order) —
-        used by the compiled serving loops to decide whether the whole
-        session can run as one jitted beat scan (uniform shapes)."""
+        a serving session checks their shapes before it pops any."""
         return tuple(self._pending)
 
     def complete(self, rid: int, result: Any) -> None:

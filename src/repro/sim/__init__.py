@@ -4,8 +4,9 @@ The analytic side of the repo (`core/mapping.py` allocates cores,
 `core/hw_model.py` prices them) never *runs* a network as the paper's chip.
 This package does: it materializes a :class:`repro.core.mapping.NetworkMap`
 placement as stacked per-core conductance arrays, executes inference and the
-paper's three training phases (fwd/bwd/update, Table II) through batched
-Pallas crossbar kernels, moves neuron outputs between cores through an
+paper's three training phases (fwd/bwd/update, Table II) as compiled
+programs (one ``lax.scan`` over the stages; fused Pallas crossbar kernels
+on a TPU), moves neuron outputs between cores through an
 8-bit-link NoC model with per-link cycle counters, and reports time/energy
 from *measured* counters — cross-validated against `hw_model`'s analytic
 numbers (DESIGN.md "Virtual chip").

@@ -15,13 +15,14 @@ per layer) plus counters; it runs:
                        the same conductances), update (pulse-discretized
                        outer product written into the stacks in place).
 
-Every stage executes as ONE batched Pallas call over its core stack
-(`kernels/ops.crossbar_fwd_stacked` and friends); aggregation sub-stages
-(Fig. 14) run inside their layer's time slot.  Numerics match the
-constrained reference exactly: `infer` == `core.crossbar.mlp_forward` and
-`train_step` == `core.crossbar.paper_backprop_step` (pinned by
-``tests/test_chip_sim.py``), while the counters reproduce `hw_model`'s
-analytic time/energy to <= 1%.
+Every call runs one compiled program (`repro.sim.compiled`) over the
+placement's padded stage stack: the wave is one ``lax.scan`` over the
+stages, and a training step (wave + reversed bwd/update scan) is ONE
+donated program.  Aggregation sub-stages (Fig. 14) run inside their
+layer's time slot.  Numerics match the constrained reference within float
+re-association: `infer` == `core.crossbar.mlp_forward` and `train_step` ==
+`core.crossbar.paper_backprop_step` (pinned by ``tests/test_chip_sim.py``),
+while the counters reproduce `hw_model`'s analytic time/energy to <= 1%.
 
 Counting conventions (shared with the analytic model, pinned by the
 cross-validation contract):
@@ -36,32 +37,20 @@ cross-validation contract):
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import quantization as q
-from repro.core.crossbar import (CORE_COLS, CORE_ROWS, F32, CrossbarSpec,
-                                 hard_sigmoid, hard_sigmoid_deriv)
+from repro.core.crossbar import CORE_COLS, CORE_ROWS, CrossbarSpec
 from repro.core.mapping import map_network
 from repro.core import hw_model as hw
-from repro.kernels import ops as kernel_ops
 from repro.sim import compiled as csim
 from repro.sim import spans
+from repro.sim.faults import inject_faults, reapply
 from repro.sim.noc import NocTracker
-from repro.sim.placer import (Placement, Stage, StageStacks,
-                              build_stage_stacks, place_network,
-                              stage_dot_products, tile_inputs)
+from repro.sim.placer import (Placement, StageStacks, build_stage_stacks,
+                              place_network)
 from repro.sim.report import PhaseCounters, SimReport
-
-
-def compiled_enabled() -> bool:
-    """Whether the compiled whole-step executor is active (DESIGN.md §8).
-    ``REPRO_SIM_COMPILED=0`` falls back to the eager per-stage reference
-    path everywhere (the differential baseline)."""
-    return os.environ.get("REPRO_SIM_COMPILED", "1") != "0"
 
 
 def as_rows(x) -> jax.Array:
@@ -69,15 +58,6 @@ def as_rows(x) -> jax.Array:
     program launch even on a 2-D array, so a batch that is already 2-D
     passes as it is."""
     return x if np.ndim(x) == 2 else jnp.atleast_2d(x)
-
-
-def _tile_cols(v: jax.Array, r: int, c: int, cols: int) -> jax.Array:
-    """(M, fan_out) per-neuron values -> (r*c, M, cols) per-core slabs
-    (slice t = i*c + j carries fan-out tile j, same for every fan-in i)."""
-    M, O = v.shape
-    vp = jnp.pad(v, ((0, 0), (0, c * cols - O)))
-    ct = vp.reshape(M, c, cols).transpose(1, 0, 2)      # (c, M, cols)
-    return jnp.tile(ct, (r, 1, 1))
 
 
 class VirtualChip:
@@ -108,7 +88,6 @@ class VirtualChip:
             placement = place_network(layers, nmap, rows, cols)
         self.faults = None
         if faults is not None and not faults.is_null:
-            from repro.sim.faults import inject_faults
             placement = inject_faults(placement, faults, w_max=spec.w_max)
             self.faults = faults
         self.placement = placement
@@ -123,16 +102,10 @@ class VirtualChip:
     # Compiled whole-step executor (repro.sim.compiled, DESIGN.md §8)
     # ------------------------------------------------------------------
 
-    def _compiled_active(self) -> bool:
-        """Compiled path applies unless disabled or the chip owns faults
-        (the stuck-mask re-assert mutates stacks mid-step — that path
-        stays on the eager reference)."""
-        return compiled_enabled() and self.faults is None
-
     def _get_stacks(self) -> StageStacks:
         """The padded stage stack, rebuilt whenever the placement's
         conductances were written outside the compiled step (version
-        bump: eager updates, fault injection, farm scatter)."""
+        bump: fault injection or re-assert, farm scatter)."""
         if (self._stacks is None
                 or self._stacks.built_version != self.placement.version):
             self._stacks = build_stage_stacks(self.placement)
@@ -146,6 +119,19 @@ class VirtualChip:
         if held is None or held[0] != v:
             held = self._held[name] = (v, jax.device_put(v))
         return held[1]
+
+    def _keep(self, st: StageStacks, out, cfg: "csim.ChipConfig") -> None:
+        """Keep training result ``out``: its stacks become the envelope the
+        next step donates, and the placement's stages take the per-stage
+        views (`compiled.kept_views`).  A chip that owns faults then
+        re-asserts its stuck masks — pulse updates cannot move a stuck
+        device — which replaces the placement with a newer version, so
+        the next call rebuilds the envelope from the masked stacks."""
+        st.g_plus, st.g_minus = out[0], out[1]
+        st.scatter_back(self.placement, *csim.kept_views(out, cfg))
+        if self.faults is not None:
+            self.placement = reapply(self.placement, self.faults,
+                                     w_max=self.spec.w_max)
 
     @property
     def _cfg(self) -> "csim.ChipConfig":
@@ -181,51 +167,6 @@ class VirtualChip:
             counters.slots["update"] += u_slots
             counters.core_steps["update"] += u_steps
 
-    # ------------------------------------------------------------------
-    # Stage execution (one batched Pallas call per stage)
-    # ------------------------------------------------------------------
-
-    def _stage_dp(self, st: Stage, h: jax.Array) -> jax.Array:
-        """Run one stage's core stack on a (M, fan_in) input wave; returns
-        the exact-aggregated (M, fan_out) dot products.  The tile /
-        Fig.-14 aggregation discipline lives in `placer.stage_dot_products`
-        (shared with the farm)."""
-        return stage_dot_products(st, h, st.g_plus, st.g_minus,
-                                  kernel_ops.crossbar_fwd_stacked)
-
-    def _count_stage(self, counters: PhaseCounters, st: Stage,
-                     samples: int) -> None:
-        """Measured fwd accounting for one stage execution: one time slot
-        on the stacks' core count, plus the stage's NoC egress."""
-        counters.record_phase("fwd", st.n_cores, samples)
-        links = st.g_plus.shape[0]           # one outbound link per core
-        counters.noc.record(st.index, st.lmap.routed_outputs, links,
-                            samples)
-
-    def _forward(self, x: jax.Array, counters: PhaseCounters | None, *,
-                 quantize_tail: bool = False
-                 ) -> tuple[list[jax.Array], list[jax.Array], jax.Array]:
-        """Wave through all stages; returns (per-stage inputs, DPs, output
-        activation) with the reference path's transport semantics: the
-        network input is DAC-driven (no ADC), inter-stage activations are
-        3-bit quantized, and the last stage's output leaves raw for the
-        training unit — unless ``quantize_tail`` is set, in which case the
-        tail activation is ADC-quantized too (this chip is a mid-pipeline
-        slice and its output crosses an inter-chip link, DESIGN.md §7)."""
-        acts, dps = [], []
-        h = x
-        last = len(self.placement.stages) - 1
-        for si, st in enumerate(self.placement.stages):
-            acts.append(h)
-            dp = self._stage_dp(st, h)
-            dps.append(dp)
-            if counters is not None:
-                self._count_stage(counters, st, x.shape[0])
-            h = hard_sigmoid(dp)
-            if (si < last or quantize_tail) and self.spec.transport_quant:
-                h = q.adc_quantize_ste(h, self.spec.adc_bits)
-        return acts, dps, h
-
     @spans.entry("chip.forward_wave")
     def forward_wave(self, x: jax.Array, *, count: bool = True,
                      train: bool = False, quantize_tail: bool = False
@@ -243,9 +184,6 @@ class VirtualChip:
         counters = None
         if count:
             counters = self.train_counters if train else self.infer_counters
-        if not self._compiled_active():
-            return self._forward(as_rows(x), counters,
-                                 quantize_tail=quantize_tail)
         with spans.phase(spans.PREPARE):
             x = as_rows(x)
             st = self._get_stacks()
@@ -266,19 +204,13 @@ class VirtualChip:
     def infer(self, x: jax.Array, *, count: bool = True) -> jax.Array:
         """One recognition wave (serialized-latency semantics)."""
         counters = self.infer_counters if count else None
-        if self._compiled_active():
-            with spans.phase(spans.PREPARE):
-                x = jnp.atleast_2d(x)
-                st = self._get_stacks()
-                idx, cfg = st.index_pytree(), self._cfg
-            with spans.phase(spans.DISPATCH):
-                out, fcnt = csim.chip_infer(st.g_plus, st.g_minus, x, idx,
-                                            cfg)
-            self._apply_fwd_counters(counters, fcnt, x.shape[0])
-        else:
+        with spans.phase(spans.PREPARE):
             x = jnp.atleast_2d(x)
-            _, dps, _ = self._forward(x, counters)
-            out = hard_sigmoid(dps[-1])
+            st = self._get_stacks()
+            idx, cfg = st.index_pytree(), self._cfg
+        with spans.phase(spans.DISPATCH):
+            out, fcnt = csim.chip_infer(st.g_plus, st.g_minus, x, idx, cfg)
+        self._apply_fwd_counters(counters, fcnt, x.shape[0])
         if count:
             with spans.phase(spans.ACCOUNT):
                 M = x.shape[0]
@@ -337,70 +269,23 @@ class VirtualChip:
         previous chip).  ``global_batch`` is the learning-rate batch
         normalizer, the FULL step batch when this chip is a pipeline slice
         (defaults to ``delta``'s batch)."""
-        spec = self.spec
         M = delta.shape[0]
         B = M if global_batch is None else global_batch
         c = counters if counters is not None else self.train_counters
-
-        if self._compiled_active():
-            with spans.phase(spans.PREPARE):
-                st = self._get_stacks()
-                idx, cfg = st.index_pytree(), self._cfg
-                acts_p, dps_p, delta_p = csim.pad_wave(acts, dps, delta, cfg)
-                lr_eff = self._on_device("lr_eff", np.float32(float(lr) / B))
-            with spans.phase(spans.DISPATCH):
-                out = csim.chip_backward(
-                    st.g_plus, st.g_minus, acts_p, dps_p, delta_p, idx, cfg,
-                    lr_eff=lr_eff)
-            with spans.phase(spans.WRITEBACK):
-                st.g_plus, st.g_minus, delta_prev, bcnt = out
-                st.scatter_back(self.placement, *csim.kept_views(out, cfg))
-            self._apply_bwd_counters(c, bcnt)
-            return delta_prev
-
-        for si in reversed(range(len(self.placement.stages))):
-            st = self.placement.stages[si]
-            r, ct = st.row_tiles, st.col_tiles
-            if spec.error_quant:
-                # III.F step 1: errors ride the links as 8-bit
-                # sign-magnitude codes.
-                delta = q.error_quantize(delta, spec.err_bits).dequantize()
-            local = delta * hard_sigmoid_deriv(dps[si])
-
-            # -- backward phase: the error drives the SAME conductance
-            # stacks transposed (Eq. 7 / Fig. 9), one batched call.
-            ds = _tile_cols(local, r, ct, st.cols)
-            dxs = kernel_ops.crossbar_bwd_stacked(ds, st.g_plus, st.g_minus)
-            dx = (dxs.reshape(r, ct, M, st.rows).sum(axis=1)
-                     .transpose(1, 0, 2).reshape(M, r * st.rows))
-            delta_prev = dx[:, 1:st.lmap.fan_in + 1]   # strip bias line
-            c.record_phase("bwd", st.n_cores, M)
-
-            # -- update phase: per-core outer product + pulse
-            # discretization + clipping, written into the stacks.
-            xs = tile_inputs(acts[si], r, ct, st.rows)
-            if spec.update_quant:
-                gp, gm = kernel_ops.pulse_update_stacked(
-                    st.g_plus, st.g_minus, xs, ds, lr=lr / B,
-                    max_dw=spec.max_update, levels=spec.update_levels,
-                    w_max=spec.w_max)
-            else:
-                dw = 2.0 * (lr / B) * jnp.einsum("tmk,tmn->tkn", xs, ds,
-                                                 precision=F32)
-                gp = jnp.clip(st.g_plus + 0.5 * dw, 0.0, spec.w_max)
-                gm = jnp.clip(st.g_minus - 0.5 * dw, 0.0, spec.w_max)
-            self.placement.set_stage_stacks(si, gp, gm)
-            c.record_phase("update", st.n_cores, M)
-
-            delta = delta_prev
-
-        if self.faults is not None:
-            # pulse updates cannot move a stuck device: re-assert the
-            # masks so training works around, not through, broken cells.
-            from repro.sim.faults import reapply
-            self.placement = reapply(self.placement, self.faults,
-                                     w_max=self.spec.w_max)
-        return delta
+        with spans.phase(spans.PREPARE):
+            st = self._get_stacks()
+            idx, cfg = st.index_pytree(), self._cfg
+            acts_p, dps_p, delta_p = csim.pad_wave(acts, dps, delta, cfg)
+            lr_eff = self._on_device("lr_eff", np.float32(float(lr) / B))
+        with spans.phase(spans.DISPATCH):
+            out = csim.chip_backward(
+                st.g_plus, st.g_minus, acts_p, dps_p, delta_p, idx, cfg,
+                lr_eff=lr_eff)
+        with spans.phase(spans.WRITEBACK):
+            delta_prev, bcnt = out[2:]
+            self._keep(st, out, cfg)
+        self._apply_bwd_counters(c, bcnt)
+        return delta_prev
 
     @spans.entry("chip.train_step")
     def train_step(self, x: jax.Array, target: jax.Array,
@@ -410,34 +295,23 @@ class VirtualChip:
         `core.crossbar.paper_backprop_step` exactly under equal specs.
         Returns the output error (target - prediction)."""
         c = self.train_counters
-        if self._compiled_active():
-            # the whole step — wave + reversed bwd/update scan — is ONE
-            # donated XLA program; the conductance stacks update in place
-            # and the counters come back in one transfer (DESIGN.md §8).
-            with spans.phase(spans.PREPARE):
-                x = jnp.atleast_2d(x)
-                target = jnp.atleast_2d(target)
-                M = x.shape[0]
-                st = self._get_stacks()
-                idx, cfg = st.index_pytree(), self._cfg
-            with spans.phase(spans.DISPATCH):
-                out = csim.chip_train(
-                    st.g_plus, st.g_minus, x, target, idx, cfg,
-                    lr_eff=float(lr) / M)
-            with spans.phase(spans.WRITEBACK):
-                st.g_plus, st.g_minus, err, fcnt, bcnt = out
-                st.scatter_back(self.placement, *csim.kept_views(out, cfg))
-            self._apply_fwd_counters(c, fcnt, M)
-            self._apply_bwd_counters(c, bcnt)
-        else:
+        # the whole step — wave + reversed bwd/update scan — is ONE donated
+        # XLA program; the conductance stacks update in place and the
+        # counters come back in one transfer (DESIGN.md §8).
+        with spans.phase(spans.PREPARE):
             x = jnp.atleast_2d(x)
             target = jnp.atleast_2d(target)
             M = x.shape[0]
-            acts, dps, _ = self._forward(x, c)
-            out = hard_sigmoid(dps[-1])
-            self.backward_update(acts, dps, target - out, lr, counters=c)
-            err = target - out
-
+            st = self._get_stacks()
+            idx, cfg = st.index_pytree(), self._cfg
+        with spans.phase(spans.DISPATCH):
+            out = csim.chip_train(st.g_plus, st.g_minus, x, target, idx, cfg,
+                                  lr_eff=float(lr) / M)
+        with spans.phase(spans.WRITEBACK):
+            err, fcnt, bcnt = out[2:]
+            self._keep(st, out, cfg)
+        self._apply_fwd_counters(c, fcnt, M)
+        self._apply_bwd_counters(c, bcnt)
         with spans.phase(spans.ACCOUNT):
             c.samples += M
             c.record_io(2 * self.placement.dims[0] * self.input_bits

@@ -5,13 +5,13 @@ the farm scales it out the way the ROADMAP's serving story requires:
 
   * ``ChipFarm`` — N data-parallel chip replicas.  Every stage's stacked
     conductances carry a leading *chip* axis ``(C, T, rows, cols)``, and
-    every stage of every chip executes as ONE chip-axis stacked Pallas call
-    (`kernels/ops.crossbar_*_stacked` with 4-D operands) — the farm is a
-    single fused dispatch per phase, never a Python loop over chips.
+    a whole farm step runs as ONE compiled program
+    (`compiled.chip_train`/`chip_infer`) whose chip axis is an array
+    axis — never a Python loop over chips.
 
   * data-parallel training — each chip runs the paper's fwd/bwd phases on
-    its batch shard, computes its LOCAL batch-summed outer product
-    (`crossbar_dw_stacked`), and the host link reconciles:
+    its batch shard, computes its LOCAL batch-summed outer product, and
+    the host link reconciles (inside the same program):
     ``dist.collectives.farm_reduce_sum`` sums the contributions, the pulse
     discretization (III.F step 3) is applied ONCE to the sum, and every
     replica writes the same pulses.  Two consequences, both pinned by
@@ -24,10 +24,10 @@ the farm scales it out the way the ROADMAP's serving story requires:
         view), and (c) summed local outer products == the global one.
 
   * ``FarmServer`` — the batched serving front-end: a
-    `runtime.serve_loop.RequestQueue` with per-slot refill feeds each
-    chip's stage-0 slot every pipeline beat; all stages of all chips
-    evaluate in one chip-axis stacked call per beat (plus one aggregation
-    call when fan-in-split stages exist), and each beat retires one
+    `runtime.serve_loop.RequestQueue` drained as one compiled scan over
+    pipeline beats (`compiled.serve_scan`): request ``r`` enters chip
+    ``r % C``'s stage-0 slot at beat ``r // C``, all stages of all chips
+    evaluate in one stacked dispatch per beat, and each beat retires one
     sample per chip at steady state — Table IV's 0.77 us beat, times N.
 
   * accounting — per-chip `PhaseCounters` (identical conventions to the
@@ -39,16 +39,15 @@ the farm scales it out the way the ROADMAP's serving story requires:
 With a JAX device mesh (``mesh=`` with a ``"chips"`` axis), the farm is
 sharded: every stage's stacks, and the compiled step's padded envelope
 ``(S, C, T_max, rows, cols)`` on its axis 1, are split over the devices by
-chip, and the global batch by rows in replica order.  ``train_step`` and
-``infer`` run the same compiled program as one device, under
+chip, and the global batch by rows in replica order.  ``train_step``,
+``infer`` and serving run the same compiled programs as one device, under
 ``shard_map``: each device runs its replicas' forward wave, backward scan
-and local outer products, and the reconciliation (`farm_reduce_sum`) and
-the error full-scale (`farm_max`) are collectives over ``"chips"`` inside
-that one donated program.  No replica's stacks and no input row leave
-their device outside the collectives.  Serving on a mesh, and the eager
-reference path (``REPRO_SIM_COMPILED=0``), dispatch each stacked call
-under ``shard_map`` from Python.  Without a mesh the same code runs
-single-device (the chip axis is just an array axis).
+and local outer products (or its chips' serving lanes), and the
+reconciliation (`farm_reduce_sum`) and the error full-scale (`farm_max`)
+are collectives over ``"chips"`` inside that one donated program.  No
+replica's stacks and no input row leave their device outside the
+collectives.  Without a mesh the same code runs single-device (the chip
+axis is just an array axis).
 """
 from __future__ import annotations
 
@@ -61,20 +60,15 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import hw_model as hw
-from repro.core import quantization as q
-from repro.core.crossbar import (CORE_COLS, CORE_ROWS, CrossbarSpec,
-                                 hard_sigmoid, hard_sigmoid_deriv)
+from repro.core.crossbar import CORE_COLS, CORE_ROWS, CrossbarSpec
 from repro.core.mapping import map_network
-from repro.kernels import ops as kernel_ops
 from repro.runtime.serve_loop import RequestQueue
 from repro.sim import compiled as csim
 from repro.sim import spans
-from repro.sim.chip import VirtualChip, _tile_cols, compiled_enabled
+from repro.sim.chip import VirtualChip
 from repro.sim.noc import NocTracker
 from repro.sim.placer import (Placement, StageStacks, build_stage_stacks,
-                              fold_subneuron_partials, place_network,
-                              stage_dot_products, stage_dp_from_outputs,
-                              tile_inputs)
+                              place_network)
 from repro.sim.report import (FarmReport, HostLinkTracker, PhaseCounters,
                               SimReport)
 
@@ -89,8 +83,9 @@ def make_farm_mesh(n_chips: int):
                  if n_chips % d == 0), 1)
     if span == 1:
         return None
-    # Auto axes: the eager chip-axis ops between shard_mapped dispatches
-    # run outside any mesh context, which Explicit axes would reject
+    # Auto axes: the farm's host-side glue between its programs (envelope
+    # builds, batch placement, serving's lane view) runs outside any mesh
+    # context, which Explicit axes would reject
     return jax.make_mesh((span,), (csim.FARM_AXIS,),
                          axis_types=(jax.sharding.AxisType.Auto,))
 
@@ -172,12 +167,11 @@ class ChipFarm:
     # ------------------------------------------------------------------
 
     def _compiled_active(self) -> bool:
-        """Whether ``train_step`` and ``infer`` run the compiled whole-step
-        program: on one device the chip axis is an array axis; on a mesh
-        each device runs its replicas' slice of the same program under
-        ``shard_map`` (`compiled.chip_train`), with the reconciliation as
-        collectives over ``"chips"`` inside it."""
-        return compiled_enabled()
+        """Always True: the compiled programs are the farm's only way to
+        run, on one device or on a mesh.  Kept because the benchmark's
+        farm driver (``bench/drivers/farm.py``) asks before it times a
+        meshed farm; drop it with that check."""
+        return True
 
     def _get_stacks(self):
         """(layout, gp (S, C, T_max, rows, cols), gm, index maps) — padded
@@ -234,68 +228,6 @@ class ChipFarm:
                     c.slots["update"] += b[2]
                     c.core_steps["update"] += b[3]
 
-    # ------------------------------------------------------------------
-    # Chip-axis stacked dispatch (shard_mapped when a mesh is present)
-    # ------------------------------------------------------------------
-
-    def _shard(self, fn, n_in: int):
-        if self.mesh is None:
-            return fn
-        from jax.sharding import PartitionSpec as P
-        return jax.shard_map(fn, mesh=self.mesh,
-                             in_specs=(P("chips"),) * n_in,
-                             out_specs=P("chips"), check_vma=False)
-
-    def _run_fwd(self, xs, gp, gm):
-        return self._shard(
-            lambda a, b, c: kernel_ops.crossbar_fwd_stacked(a, b, c), 3)(
-            xs, gp, gm)
-
-    def _run_bwd(self, dys, gp, gm):
-        return self._shard(
-            lambda a, b, c: kernel_ops.crossbar_bwd_stacked(a, b, c), 3)(
-            dys, gp, gm)
-
-    def _run_dw(self, xs, ds):
-        return self._shard(
-            lambda a, b: kernel_ops.crossbar_dw_stacked(a, b), 2)(xs, ds)
-
-    # ------------------------------------------------------------------
-    # Stage execution with a chip axis
-    # ------------------------------------------------------------------
-
-    def _stage_dp(self, si: int, h: jax.Array) -> jax.Array:
-        """(C, Mc, fan_in) input wave -> (C, Mc, fan_out) dot products;
-        the same `placer.stage_dot_products` the serial chip runs, with
-        the chip-axis stacks and the (possibly shard_mapped) dispatch."""
-        st = self.placement.stages[si]
-        return stage_dot_products(st, h, self._gp[si], self._gm[si],
-                                  self._run_fwd)
-
-    def _count_stage(self, counters: list[PhaseCounters], st,
-                     samples: int) -> None:
-        links = st.g_plus.shape[0]
-        for c in counters:
-            c.record_phase("fwd", st.n_cores, samples)
-            c.noc.record(st.index, st.lmap.routed_outputs, links, samples)
-
-    def _forward(self, xb: jax.Array, counters: list[PhaseCounters] | None
-                 ) -> tuple[list[jax.Array], list[jax.Array]]:
-        """Chip-axis wave with the reference transport semantics."""
-        acts, dps = [], []
-        h = xb
-        last = len(self.placement.stages) - 1
-        for si, st in enumerate(self.placement.stages):
-            acts.append(h)
-            dp = self._stage_dp(si, h)
-            dps.append(dp)
-            if counters is not None:
-                self._count_stage(counters, st, xb.shape[1])
-            h = hard_sigmoid(dp)
-            if si < last and self.spec.transport_quant:
-                h = q.adc_quantize_ste(h, self.spec.adc_bits)
-        return acts, dps
-
     def _rows(self, x: jax.Array, what: str) -> jax.Array:
         """The global batch as a meshed program takes it: rows in replica
         order, split over the devices (a batch that arrives so split is
@@ -327,11 +259,6 @@ class ChipFarm:
                 np.float32(v), NamedSharding(self.mesh, P())))
         return self._held_scalar[1]
 
-    def _meshed(self) -> bool:
-        """The compiled program runs on the mesh and takes the batch as
-        rows (`_rows`), not chip-stacked (`_split`)."""
-        return self.mesh is not None and self._compiled_active()
-
     # ------------------------------------------------------------------
     # Inference (wave semantics; serving goes through FarmServer)
     # ------------------------------------------------------------------
@@ -341,26 +268,17 @@ class ChipFarm:
         """Data-parallel recognition wave: the global batch splits over
         chips, each replica computes its shard; rows come back in input
         order and equal `VirtualChip.infer` on the unsharded batch."""
-        counters = self.chip_infer if count else None
-        if self._compiled_active():
-            with spans.phase(spans.PREPARE):
-                xb = (self._rows if self._meshed() else self._split)(
-                    x, "infer")
-                Mc = (xb.shape[0] // self.n_chips if xb.ndim == 2
-                      else xb.shape[1])
-                st, gp, gm, idx = self._get_stacks()
-                cfg = csim.chip_config(st, self.spec)
-            with spans.phase(spans.DISPATCH):
-                out, fcnt = csim.chip_infer(gp, gm, xb, idx, cfg,
-                                            mesh=self.mesh)
-            if count:
-                self._apply_phase_counters(counters, fcnt, None, Mc)
-        else:
-            xb = self._split(x, "infer")
-            Mc = xb.shape[1]
-            _, dps = self._forward(xb, counters)
-            out = hard_sigmoid(dps[-1])
+        with spans.phase(spans.PREPARE):
+            xb = (self._split if self.mesh is None else self._rows)(
+                x, "infer")
+            Mc = (xb.shape[0] // self.n_chips if xb.ndim == 2
+                  else xb.shape[1])
+            st, gp, gm, idx = self._get_stacks()
+            cfg = csim.chip_config(st, self.spec)
+        with spans.phase(spans.DISPATCH):
+            out, fcnt = csim.chip_infer(gp, gm, xb, idx, cfg, mesh=self.mesh)
         if count:
+            self._apply_phase_counters(self.chip_infer, fcnt, None, Mc)
             with spans.phase(spans.ACCOUNT):
                 bits = (self.placement.dims[0] * self.input_bits
                         + self.placement.dims[-1] * hw.ADC_BITS_OUT)
@@ -383,98 +301,37 @@ class ChipFarm:
         wire format the link accounting already meters (bounded deviation
         from the serial chip); mode "none" idealizes an exact f32 sum over
         that same metered traffic.  Returns the (global) output error."""
-        from repro.dist.collectives import farm_reduce_sum
-
+        # the whole farm step — chip-axis wave, reversed bwd scan,
+        # farm_reduce_sum reconciliation INSIDE the trace, pulses broadcast
+        # to every replica — is ONE donated XLA program, on a mesh split
+        # over its devices by chip.
         with spans.phase(spans.PREPARE):
-            batch = self._rows if self._meshed() else self._split
+            batch = self._split if self.mesh is None else self._rows
             xb, tb = batch(x, "train"), batch(target, "target")
-        spec = self.spec
-        C = self.n_chips
-        M = xb.shape[0] if xb.ndim == 2 else C * xb.shape[1]
-        Mc = M // C
-
-        if self._compiled_active():
-            # the whole farm step — chip-axis wave, reversed bwd scan,
-            # farm_reduce_sum reconciliation INSIDE the trace, pulses
-            # broadcast to every replica — is ONE donated XLA program,
-            # on a mesh split over its devices by chip.
-            with spans.phase(spans.PREPARE):
-                st, gp, gm, idx = self._get_stacks()
-                cfg = csim.chip_config(st, self.spec)
-                lr_eff = self._scalar(float(lr) / M)
-            with spans.phase(spans.DISPATCH):
-                out = csim.chip_train(
-                    gp, gm, xb, tb, idx, cfg, lr_eff=lr_eff,
-                    reconcile=reconcile, mesh=self.mesh)
-            with spans.phase(spans.WRITEBACK):
-                gp2, gm2, err, fcnt, bcnt = out
-                self._scatter_back(gp2, gm2, *csim.kept_views(out, cfg))
-                err = err.reshape(M, -1)
-            self._apply_phase_counters(self.chip_train, fcnt, bcnt, Mc)
-            with spans.phase(spans.ACCOUNT):
-                bits = (2 * self.placement.dims[0] * self.input_bits
-                        + self.placement.dims[-1] * hw.ADC_BITS_OUT)
-                for c in self.chip_train:
-                    c.samples += Mc
-                    c.record_io(bits, Mc)
-                self.train_link.record_samples(bits, M)
-                self.train_link.record_reconcile(C * self._reconcile_bits())
-                self.train_steps += 1
-            return err
-
-        acts, dps = self._forward(xb, self.chip_train)
-        out = hard_sigmoid(dps[-1])
-        delta = tb - out                                  # (C, Mc, O)
-
-        for si in reversed(range(len(self.placement.stages))):
-            st = self.placement.stages[si]
-            r, ct = st.row_tiles, st.col_tiles
-            if spec.error_quant:
-                # shared full-scale across the farm: quantizing the global
-                # tensor IS max-abs over every chip's shard (a farm_max
-                # collective in the distributed view) — required for the
-                # replicas to discretize on the same grid as the serial
-                # chip (III.F step 1).
-                flat = delta.reshape(M, -1)
-                delta = (q.error_quantize(flat, spec.err_bits).dequantize()
-                         .reshape(C, Mc, -1))
-            local = delta * hard_sigmoid_deriv(dps[si])
-
-            ds = jax.vmap(lambda l: _tile_cols(l, r, ct, st.cols))(local)
-            dxs = self._run_bwd(ds, self._gp[si], self._gm[si])
-            dx = (dxs.reshape(C, r, ct, Mc, st.rows).sum(axis=2)
-                     .transpose(0, 2, 1, 3).reshape(C, Mc, r * st.rows))
-            delta_prev = dx[..., 1:st.lmap.fan_in + 1]
+            C = self.n_chips
+            M = xb.shape[0] if xb.ndim == 2 else C * xb.shape[1]
+            Mc = M // C
+            st, gp, gm, idx = self._get_stacks()
+            cfg = csim.chip_config(st, self.spec)
+            lr_eff = self._scalar(float(lr) / M)
+        with spans.phase(spans.DISPATCH):
+            out = csim.chip_train(gp, gm, xb, tb, idx, cfg, lr_eff=lr_eff,
+                                  reconcile=reconcile, mesh=self.mesh)
+        with spans.phase(spans.WRITEBACK):
+            gp2, gm2, err, fcnt, bcnt = out
+            self._scatter_back(gp2, gm2, *csim.kept_views(out, cfg))
+            err = err.reshape(M, -1)
+        self._apply_phase_counters(self.chip_train, fcnt, bcnt, Mc)
+        with spans.phase(spans.ACCOUNT):
+            bits = (2 * self.placement.dims[0] * self.input_bits
+                    + self.placement.dims[-1] * hw.ADC_BITS_OUT)
             for c in self.chip_train:
-                c.record_phase("bwd", st.n_cores, Mc)
-
-            # update: LOCAL outer products (one farm-wide dispatch), then
-            # the host reconciles and every replica pulses identically.
-            xs = jax.vmap(lambda a: tile_inputs(a, r, ct, st.rows))(acts[si])
-            dw_local = self._run_dw(xs, ds)               # (C, T, rows, cols)
-            dw = 2.0 * (lr / M) * farm_reduce_sum(dw_local, mode=reconcile)
-            if spec.update_quant:
-                dw = q.pulse_discretize(dw, spec.max_update,
-                                        spec.update_levels, None)
-            self._gp[si] = jnp.clip(self._gp[si] + 0.5 * dw[None],
-                                    0.0, spec.w_max)
-            self._gm[si] = jnp.clip(self._gm[si] - 0.5 * dw[None],
-                                    0.0, spec.w_max)
-            for c in self.chip_train:
-                c.record_phase("update", st.n_cores, Mc)
-
-            delta = delta_prev
-
-        bits = (2 * self.placement.dims[0] * self.input_bits
-                + self.placement.dims[-1] * hw.ADC_BITS_OUT)
-        for c in self.chip_train:
-            c.samples += Mc
-            c.record_io(bits, Mc)
-        self.train_link.record_samples(bits, M)
-        self.train_link.record_reconcile(C * self._reconcile_bits())
-        self.train_steps += 1
-        self.version += 1
-        return (tb - out).reshape(M, -1)
+                c.samples += Mc
+                c.record_io(bits, Mc)
+            self.train_link.record_samples(bits, M)
+            self.train_link.record_reconcile(C * self._reconcile_bits())
+            self.train_steps += 1
+        return err
 
     def _reconcile_bits(self) -> int:
         """Host-link bits one chip's update reconciliation moves per step:
@@ -650,194 +507,48 @@ def build_farm(app: str, n_chips: int, *, seed: int = 0,
 
 
 class FarmServer:
-    """Pipelined serving front-end: one chip-axis stacked call per beat.
+    """Pipelined serving front-end: the whole session is one compiled
+    scan over beats (`compiled.serve_scan`, DESIGN.md §6.3).
 
-    Wavefront execution (Fig. 2 at farm scale): sample ``k`` occupies
-    stage ``s`` of its chip at beat ``enter_k + s``; every beat the server
-    assembles the (C, sumT, m, rows) input slab of ALL stages of ALL
-    chips, runs ONE `crossbar_fwd_stacked` dispatch (plus one aggregation
-    dispatch when fan-in-split stages exist), advances the wavefront, and
-    refills each chip's stage-0 slot from the request queue.  Numerics are
-    identical to the wave path — stages are sample-independent — so served
-    outputs equal `mlp_forward` exactly; what the beat loop adds is the
-    *time* structure the farm throughput claim is made from.
+    Wavefront schedule (Fig. 2 at farm scale): request ``r`` enters chip
+    ``r % C``'s stage-0 slot at beat ``r // C`` and occupies stage ``s``
+    at beat ``r // C + s``; every beat evaluates all stages of all chips
+    in one stacked dispatch and each beat retires one request per chip at
+    steady state.  Numerics are the wave path's — stages are
+    sample-independent — so served outputs equal `mlp_forward`; what the
+    schedule adds is the *time* structure the farm throughput claim is
+    made from, billed host-side in closed form.  On a mesh each device
+    runs its own chips' lanes.  A server serves the conductance version
+    it was built at and one request microbatch over its lifetime.
     """
 
     def __init__(self, farm: ChipFarm):
         self.farm = farm
         self._version = farm.version     # conductance snapshot guard
-        pl = farm.placement
-        self.stages = pl.stages
+        self.stages = farm.placement.stages
         self.S = len(self.stages)
         self.C = farm.n_chips
-        self.rows = pl.rows
-        # chip-major stacks: chip c's cores for all stages, concatenated
-        self._off = []
-        off = 0
-        for st in self.stages:
-            self._off.append(off)
-            off += st.g_plus.shape[0]
-        self.sumT = off
-        self._stack_p = jnp.concatenate(farm._gp, axis=1)  # (C, sumT, R, cols)
-        self._stack_m = jnp.concatenate(farm._gm, axis=1)
-        # aggregation stacks (fan-in-split stages), padded to a common
-        # input-line count
-        self._agg_idx = [si for si, st in enumerate(self.stages)
-                         if st.row_tiles > 1]
-        if self._agg_idx:
-            self._agg_rows = max(self.stages[si].agg_plus.shape[1]
-                                 for si in self._agg_idx)
-            self._agg_off = []
-            parts_p, parts_m = [], []
-            aoff = 0
-            for si in self._agg_idx:
-                st = self.stages[si]
-                self._agg_off.append(aoff)
-                aoff += st.agg_plus.shape[0]
-                pad = self._agg_rows - st.agg_plus.shape[1]
-                ap = jnp.pad(st.agg_plus, ((0, 0), (0, pad), (0, 0)))
-                am = jnp.pad(st.agg_minus, ((0, 0), (0, pad), (0, 0)))
-                parts_p.append(jnp.broadcast_to(ap, (self.C,) + ap.shape))
-                parts_m.append(jnp.broadcast_to(am, (self.C,) + am.shape))
-            self._agg_p = jnp.concatenate(parts_p, axis=1)
-            self._agg_m = jnp.concatenate(parts_m, axis=1)
-        # wavefront: pipe[c][s] = (rid, input activation) or None
-        self.pipe: list[list] = [[None] * self.S for _ in range(self.C)]
         self._slot_m: int | None = None   # uniform request batch size
 
-    # -- one pipeline beat ------------------------------------------------
-
-    def step(self, queue: RequestQueue) -> int:
-        """Advance the farm one beat; returns samples retired."""
+    def run(self, queue: RequestQueue) -> dict:
+        """Drain the queue in one session; returns serving stats.  The
+        counters replay the static schedule host-side: lane ``c`` serves
+        ``ceil((Q - c) / C)`` requests."""
         farm = self.farm
-        if farm.version != self._version:
-            raise RuntimeError(
-                "farm conductances changed since this FarmServer was "
-                "built (a train_step ran); construct a fresh server — "
-                "the serving stacks are a snapshot")
-        spec = farm.spec
-        for c in range(self.C):
-            if self.pipe[c][0] is None:
-                req = queue.pop()
-                if req is not None:
-                    x = jnp.atleast_2d(jnp.asarray(req.x))
-                    # the beat slab needs one static shape: all requests
-                    # of a serving session must share their microbatch
-                    if self._slot_m is None:
-                        self._slot_m = x.shape[0]
-                    elif x.shape[0] != self._slot_m:
-                        raise ValueError(
-                            f"request {req.rid} has microbatch "
-                            f"{x.shape[0]}, session uses {self._slot_m}; "
-                            f"serve uniform request shapes")
-                    self.pipe[c][0] = (req.rid, x)
-        m = next((h.shape[0] for lane in self.pipe
-                  for slot in lane if slot is not None
-                  for h in (slot[1],)), None)
-        if m is None:
-            return 0
-
-        # assemble the farm-wide input slab (idle slots drive zeros; their
-        # outputs are discarded and their stages not billed)
-        slabs = []
-        for c in range(self.C):
-            parts = []
-            for s, st in enumerate(self.stages):
-                if self.pipe[c][s] is not None:
-                    parts.append(tile_inputs(self.pipe[c][s][1],
-                                             st.row_tiles, st.col_tiles,
-                                             st.rows))
-                else:
-                    parts.append(jnp.zeros(
-                        (st.g_plus.shape[0], m, st.rows)))
-            slabs.append(jnp.concatenate(parts, axis=0))
-        xs = jnp.stack(slabs)                       # (C, sumT, m, rows)
-        ys = farm._run_fwd(xs, self._stack_p, self._stack_m)
-
-        # aggregation dispatch for fan-in-split stages (same time slot);
-        # input-line folding shared with the wave paths via
-        # `placer.fold_subneuron_partials`
-        agg_out = None
-        if self._agg_idx:
-            aparts = []
-            for si in self._agg_idx:
-                st = self.stages[si]
-                o = self._off[si]
-                u = fold_subneuron_partials(
-                    ys[:, o:o + st.row_tiles * st.col_tiles], st)
-                aparts.append(jnp.pad(
-                    u, ((0, 0), (0, 0), (0, 0),
-                        (0, self._agg_rows - u.shape[-1]))))
-            agg_in = jnp.concatenate(aparts, axis=1)
-            agg_out = farm._run_fwd(agg_in, self._agg_p, self._agg_m)
-
-        # per-stage dot products -> outputs, advance the wavefront
-        new_pipe: list[list] = [[None] * self.S for _ in range(self.C)]
-        retired = 0
-        retired_requests = 0
-        for s, st in enumerate(self.stages):
-            r, ct = st.row_tiles, st.col_tiles
-            o = self._off[s]
-            agg_slice = None
-            if r > 1:
-                ao = self._agg_off[self._agg_idx.index(s)]
-                agg_slice = agg_out[:, ao:ao + ct]  # (C, ct, m, cols)
-            dp = stage_dp_from_outputs(ys[:, o:o + r * ct], st, agg_slice)
-            for c in range(self.C):
-                if self.pipe[c][s] is None:
-                    continue
-                rid, _ = self.pipe[c][s]
-                farm._count_stage([farm.chip_infer[c]], st, m)
-                h = hard_sigmoid(dp[c])
-                if s < self.S - 1:
-                    if spec.transport_quant:
-                        h = q.adc_quantize_ste(h, spec.adc_bits)
-                    new_pipe[c][s + 1] = (rid, h)
-                else:
-                    queue.complete(rid, h)
-                    retired += m
-                    retired_requests += 1
-                    bits = (farm.placement.dims[0] * farm.input_bits
-                            + farm.placement.dims[-1] * hw.ADC_BITS_OUT)
-                    farm.serve_link.record_samples(bits, m)
-                    farm.chip_infer[c].samples += m
-                    farm.chip_infer[c].record_io(bits, m)
-        if retired_requests == self.C:      # every slot retired: capacity
-            farm.serve_full_beats += 1
-            farm.serve_full_samples += retired
-            farm.serve_full_requests += retired_requests
-        self.pipe = new_pipe
-        farm.serve_beats += 1
-        return retired
-
-    def _run_compiled(self, queue: RequestQueue) -> dict:
-        """The whole serving session as ONE jitted scan over beats
-        (DESIGN.md §8): the wavefront schedule of `step` is static —
-        request ``r`` enters chip ``r % C`` at beat ``r // C`` — so the
-        beat loop compiles once and the queue is drained in a single
-        device program.  Counters replay the same static schedule
-        host-side (identical totals to the eager loop)."""
-        farm = self.farm
-        if farm.version != self._version:
-            raise RuntimeError(
-                "farm conductances changed since this FarmServer was "
-                "built (a train_step ran); construct a fresh server — "
-                "the serving stacks are a snapshot")
-        farm.serve_sessions += 1
         C, S = self.C, self.S
         with spans.phase(spans.PREPARE):
+            csim.check_fresh("FarmServer", self._version, farm.version)
             st, gp, gm, _ = farm._get_stacks()
             gp_cat = jnp.moveaxis(gp, 0, 1).reshape(C, S * st.T_max,
                                                     st.rows, st.cols)
             gm_cat = jnp.moveaxis(gm, 0, 1).reshape(C, S * st.T_max,
                                                     st.rows, st.cols)
         Q, m, q_max, n_beats = csim.run_serve_session(
-            queue, st, gp_cat, gm_cat, farm.spec, C)
+            queue, st, gp_cat, gm_cat, farm.spec, C, self._slot_m,
+            mesh=farm.mesh)
         self._slot_m = m
-
-        # counters: the eager loop's per-beat billing, aggregated over the
-        # static schedule (lane c serves ceil((Q - c) / C) requests)
         with spans.phase(spans.ACCOUNT):
+            farm.serve_sessions += 1
             bits = (farm.placement.dims[0] * farm.input_bits
                     + farm.placement.dims[-1] * hw.ADC_BITS_OUT)
             for c in range(C):
@@ -864,42 +575,6 @@ class FarmServer:
             "beat_us": beat_us,
             "makespan_us": n_beats * beat_us,
             "samples_per_s": Q * m / (q_max * beat_us) * 1e6,
-            "occupancy": Q * self.S / max(self.S * self.C * n_beats, 1),
-        }
-
-    def run(self, queue: RequestQueue, *, max_beats: int | None = None
-            ) -> dict:
-        """Drain the queue; returns serving stats.
-
-        With the compiled executor active, a fresh server draining a
-        uniform-shape queue runs the whole session as one jitted beat
-        scan; step-wise use (partially drained pipes, beat limits, ragged
-        shapes) and a farm on a mesh stay on the eager per-beat path."""
-        with spans.phase(spans.PREPARE):
-            compiled = (self.farm._compiled_active()
-                        and self.farm.mesh is None and max_beats is None
-                        and csim.serve_session_applicable(
-                            queue, all(s is None for lane in self.pipe
-                                       for s in lane), self._slot_m))
-        if compiled:
-            return self._run_compiled(queue)
-        beats = retired = 0
-        limit = max_beats if max_beats is not None else 10_000_000
-        self.farm.serve_sessions += 1
-        done_before = queue.completed
-        while not queue.drained and beats < limit:
-            retired += self.step(queue)
-            beats += 1
-        beat_us = self.farm.beat_us
-        steady = max(beats - (self.S - 1), 1)
-        requests = queue.completed - done_before
-        return {
-            "beats": beats,
-            "retired": retired,
-            "beat_us": beat_us,
-            "makespan_us": beats * beat_us,
-            "samples_per_s": retired / (steady * beat_us) * 1e6,
             # fraction of (chip, stage) slots occupied over the session
-            "occupancy": requests * self.S / max(
-                self.S * self.C * beats, 1),
+            "occupancy": Q * S / max(S * C * n_beats, 1),
         }

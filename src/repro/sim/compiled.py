@@ -1,12 +1,12 @@
-"""Compiled whole-step execution for the virtual chip (DESIGN.md §8).
+"""Compiled whole-step execution: the only way the simulator runs
+(DESIGN.md §8).
 
-The eager simulator drives every stage from Python — one kernel dispatch,
-one host sync per stage per phase.  The paper's chip has no host in the
-loop at all: the whole network step is a fixed schedule burned into
-hardware.  This module is that schedule for the *simulator*: each hot loop
-(recognition wave, training step, farm step, serving beat loop) is ONE
-jitted XLA program whose stage loop is a ``lax.scan`` over the padded
-ragged stage stack (`repro.sim.placer.StageStacks`), with
+The paper's chip has no host in the loop: the whole network step is a
+fixed schedule burned into hardware.  This module is that schedule for
+the *simulator*: each hot loop (recognition wave, training step, farm
+step, serving beat loop) is ONE jitted XLA program whose stage loop is a
+``lax.scan`` over the padded ragged stage stack
+(`repro.sim.placer.StageStacks`), with
 
   * conductance stacks DONATED — training updates the buffers in place,
     no per-step copy of the network's weights;
@@ -21,10 +21,12 @@ ragged stage stack (`repro.sim.placer.StageStacks`), with
 
 Every program takes an optional leading *chip* axis: the serial chip is
 the ``C == 1`` special case of the farm, so both execute the same traced
-code and cannot drift apart.  Numerics match the eager reference path
-within float re-association (all existing equivalence pins hold), and the
-padded layout is BITWISE padding-invariant (see `StageStacks`), which is
-what keeps the pipeline fabric's slice-vs-serial pins exact.
+code and cannot drift apart.  Numerics match the plain reference
+(`core.crossbar.mlp_forward` / `paper_backprop_step`) within float
+re-association, and the padded layout is BITWISE padding-invariant (see
+`StageStacks`), which is what keeps the pipeline fabric's slice-vs-serial
+pins exact.  Given a farm's ``mesh``, a program runs the same body under
+``shard_map``, each device on its own replicas.
 
 Compilation is memoized by ``jax.jit`` on (static config, operand shapes):
 two chips with the same topology and batch share one executable.  The
@@ -40,6 +42,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import quantization as q
@@ -60,8 +63,7 @@ def kernel_body_enabled() -> bool:
     the bodies use the bitwise-reference jnp math: on CPU the kernels only
     exist in *interpret mode*, whose per-call emulation overhead is the
     very dispatch tax the compiled step removes (~10-35x a plain XLA
-    contraction, growing with the core stack) — the kernels remain the
-    eager path and the differential reference either way.
+    contraction, growing with the core stack).
     ``REPRO_SIM_FORCE_KERNELS=1`` or ``=0`` overrides the backend's choice:
     tests run the kernel-in-scan path on CPU, and ``chip_smoke.py`` times
     the jnp body against the kernel body on the chip.  The flag is
@@ -572,22 +574,11 @@ def pad_wave(acts, dps, delta, cfg: ChipConfig):
 # Serving beat loop (farm front-end and pipeline front-end)
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("cfg", "n_beats"))
-def serve_scan(gp_cat, gm_cat, requests, idx, cfg: ChipConfig,
-               n_beats: int):
-    """The pipelined serving loop as ONE scan over beats (DESIGN.md §8).
-
-    ``requests`` is (Qp, m, fan_in) with Qp a multiple of the chip count;
-    request ``r`` enters chip ``r % C`` at beat ``r // C`` and retires
-    ``S - 1`` beats later — the static schedule of the eager
-    `FarmServer`/`PipelineServer` wavefront.  Every beat, ALL stages of
-    ALL chips evaluate in one stacked kernel dispatch over the
-    concatenated (C, S*T_max) core stacks; idle/padding slots drive zeros
-    whose outputs are never read back (their retire rows are overwritten
-    by real retirements or sliced away by the caller).  Returns the
-    (Qp, m, out_dim) outputs in request order.
-    """
-    _mark("serve_scan", cfg, requests.shape, gp_cat.shape)
+def _serve_beats(gp_cat, gm_cat, requests, idx, cfg: ChipConfig,
+                 n_beats: int):
+    """The beat scan of :func:`serve_scan` on ``C`` lanes (C = the stacks'
+    chip axis): request row ``b * C + c`` enters lane ``c`` at beat
+    ``b``."""
     C = gp_cat.shape[0]
     Qp, m, D = requests.shape
     S, T_max, cols = cfg.S, cfg.T_max, cfg.cols
@@ -637,33 +628,88 @@ def serve_scan(gp_cat, gm_cat, requests, idx, cfg: ChipConfig,
     return out_buf
 
 
-def serve_session_applicable(queue, slots_empty: bool,
-                             slot_m: int | None = None) -> bool:
-    """Whether a serving session can run as one compiled beat scan: a
-    fresh (empty-pipe) server draining a queue of uniform-shape requests
-    that also match the server's established request microbatch
-    (``slot_m``).  Anything else — step-wise use, beat limits, ragged
-    shapes, a cross-session microbatch change — stays on the eager path,
-    which enforces the uniform-shape contract with the same errors either
-    way."""
-    if not slots_empty or not queue.pending:
-        return False
-    shapes = {tuple(jnp.atleast_2d(jnp.asarray(r.x)).shape)
-              for r in queue.pending}
-    if len(shapes) != 1:
-        return False
-    return slot_m is None or next(iter(shapes))[0] == slot_m
+@partial(jax.jit, static_argnames=("cfg", "n_beats", "mesh"))
+def serve_scan(gp_cat, gm_cat, requests, idx, cfg: ChipConfig,
+               n_beats: int, mesh=None):
+    """The pipelined serving loop as ONE scan over beats (DESIGN.md §8).
+
+    ``requests`` is (Qp, m, fan_in) with Qp a multiple of the chip count;
+    request ``r`` enters chip ``r % C`` at beat ``r // C`` and retires
+    ``S - 1`` beats later — the static schedule of the serving wavefront.
+    Every beat, ALL stages of ALL chips evaluate in one stacked dispatch
+    over the concatenated (C, S*T_max) core stacks; idle/padding slots
+    drive zeros whose outputs are never read back (their retire rows are
+    overwritten by real retirements or sliced away by the caller).
+    Returns the (Qp, m, out_dim) outputs in request order.
+
+    With the farm's ``mesh`` each device runs the same beat scan under
+    ``shard_map`` on its own chips' lanes: the requests, viewed as
+    (Qp / C, C, m, fan_in), split on the lane axis, and the outputs come
+    back through the inverse view.
+    """
+    if mesh is None:
+        _mark("serve_scan", cfg, requests.shape, gp_cat.shape)
+        return _serve_beats(gp_cat, gm_cat, requests, idx, cfg, n_beats)
+    _mark("serve_scan", cfg, requests.shape, gp_cat.shape,
+          tuple(mesh.shape.items()))
+    C = gp_cat.shape[0]
+    Qp, m, D = requests.shape
+
+    def body(gp, gm, lanes, idx):
+        q_pad, c = lanes.shape[:2]
+        out = _serve_beats(gp, gm, lanes.reshape(q_pad * c, m, D), idx,
+                           cfg, n_beats)
+        return out.reshape(q_pad, c, m, cfg.out_dim)
+
+    lanes = P(None, FARM_AXIS)
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(_ROWS, _ROWS, lanes, _ALL),
+                        out_specs=lanes, check_vma=False)(
+        gp_cat, gm_cat, requests.reshape(Qp // C, C, m, D), idx)
+    return out.reshape(Qp, m, cfg.out_dim)
 
 
-def run_serve_session(queue, stacks, gp_cat, gm_cat, spec,
-                      n_lanes: int) -> tuple[int, int, int, int]:
+def _microbatch(x) -> int:
+    """Rows of request input ``x`` as the beat slab takes it (a 1-D
+    request is one row), read from its host-side shape."""
+    shape = np.shape(x)
+    return 1 if len(shape) < 2 else shape[0]
+
+
+def check_fresh(server: str, built: int, now: int) -> None:
+    """The stale-snapshot guard of both servers: a server serves the
+    conductances of the version it was built at (``built``); once a
+    train_step moved its owner to ``now`` it refuses to run."""
+    if now != built:
+        raise RuntimeError(
+            f"conductances changed since this {server} was built (a "
+            f"train_step ran); construct a fresh server — a server serves "
+            f"the conductances it was built on")
+
+
+def run_serve_session(queue, stacks, gp_cat, gm_cat, spec, n_lanes: int,
+                      slot_m: int | None = None, mesh=None
+                      ) -> tuple[int, int, int, int]:
     """Drain ``queue`` through :func:`serve_scan` (the shared front-end
     driver of `FarmServer` and `PipelineServer`): request ``r`` enters
-    lane ``r % n_lanes`` at beat ``r // n_lanes`` — the eager wavefront's
-    static schedule.  Completes every request in order and returns
+    lane ``r % n_lanes`` at beat ``r // n_lanes``.  Every request must
+    share one microbatch, and the server's earlier sessions' ``slot_m``
+    when given; both are checked from host shapes before anything is
+    popped or launched.  Completes every request in order and returns
     (requests, microbatch m, q_max, beats); the callers replay their own
     counter/link billing from the same schedule."""
     with spans.phase(spans.PREPARE):
+        if not queue.pending:
+            raise ValueError("a serving session needs at least one request")
+        m = slot_m
+        for r in queue.pending:
+            rows = _microbatch(r.x)
+            if m is None:
+                m = rows
+            elif rows != m:
+                raise ValueError(
+                    f"request {r.rid} has microbatch {rows}, session uses "
+                    f"{m}; serve uniform request shapes")
         reqs = []
         while True:
             r = queue.pop()
@@ -671,7 +717,7 @@ def run_serve_session(queue, stacks, gp_cat, gm_cat, spec,
                 break
             reqs.append(r)
         xs = [jnp.atleast_2d(jnp.asarray(r.x)) for r in reqs]
-        Q, (m, D) = len(reqs), xs[0].shape
+        Q, D = len(reqs), xs[0].shape[1]
         q_max = -(-Q // n_lanes)
         # bucket the lane depth to a power of two so varying queue lengths
         # share compiled executables (the scan's shapes are static in Qp
@@ -679,15 +725,15 @@ def run_serve_session(queue, stacks, gp_cat, gm_cat, spec,
         # the final padded block, whose never-retired junk lands —
         # clamped — only in rows >= q_max*n_lanes >= Q, all sliced away
         # below; the REAL schedule (and therefore the billing the callers
-        # replay) is unchanged, so the returned q_max/beats stay the eager
-        # loop's.
+        # replay) is unchanged, so the returned q_max/beats are the
+        # schedule's own.
         q_pad = 1 << (q_max - 1).bit_length()
         Qp = q_pad * n_lanes
         x_arr = jnp.zeros((Qp, m, D), jnp.float32).at[:Q].set(jnp.stack(xs))
         idx, cfg = stacks.index_pytree(), chip_config(stacks, spec)
     with spans.phase(spans.DISPATCH):
         out = serve_scan(gp_cat, gm_cat, x_arr, idx, cfg,
-                         stacks.S - 1 + q_pad)
+                         stacks.S - 1 + q_pad, mesh=mesh)
     with spans.phase(spans.WRITEBACK):
         for i, r in enumerate(reqs):
             queue.complete(r.rid, out[i])

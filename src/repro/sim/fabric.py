@@ -5,7 +5,7 @@ network whose placed core count exceeds one chip's budget cannot run at
 all.  This module is the other scaling axis (DESIGN.md §7): the mapper's
 stage list is partitioned into contiguous per-chip groups
 (`core.mapping.split_network`), each group executes on its own virtual
-chip exactly as before (one fused stacked Pallas call per stage), and the
+chip exactly as before (one compiled program per phase), and the
 two values that cross a chip boundary obey the NoC's
 quantize-at-the-boundary rule, lifted to a modeled inter-chip link:
 
@@ -32,11 +32,11 @@ and accounting:
     priced by `hw_model.schedule_1f1b` from the measured slice times and
     cross-validated against `hw_model.pipeline_cost`;
   * `PipelineServer` — drains a `runtime.serve_loop.RequestQueue` through
-    the chip pipeline at one beat per stage hop: per beat each chip runs
-    ONE fused stacked call over its slice (idle slots drive zeros), a
-    boundary hop rides inside the static routing slot (flagged by
-    ``link_utilization`` when it would not fit), and one sample retires
-    per beat at steady state — the Table IV beat survives the split;
+    the chip pipeline at one beat per stage hop, as one compiled scan over
+    beats (`compiled.serve_scan`): a boundary hop rides inside the static
+    routing slot (flagged by ``link_utilization`` when it would not fit),
+    and one sample retires per beat at steady state — the Table IV beat
+    survives the split;
   * `PipelineFarm` — the composition point with the data-parallel farm: N
     lockstep replicas of a K-chip pipeline ("farm of pipelines").  The
     replica axis delegates to `ChipFarm` (reconciled pulse updates, host
@@ -52,19 +52,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import hw_model as hw
-from repro.core import quantization as q
-from repro.core.crossbar import (CORE_COLS, CORE_ROWS, CrossbarSpec,
-                                 hard_sigmoid)
+from repro.core.crossbar import CORE_COLS, CORE_ROWS, CrossbarSpec
 from repro.core.mapping import map_network, split_network
-from repro.kernels import ops as kernel_ops
 from repro.runtime.serve_loop import RequestQueue
 from repro.sim import compiled as csim
 from repro.sim import spans
-from repro.sim.chip import VirtualChip, as_rows, compiled_enabled
+from repro.sim.chip import VirtualChip, as_rows
 from repro.sim.placer import (Placement, StageStacks, build_stage_stacks,
-                              fold_subneuron_partials, place_network,
-                              stage_dp_from_outputs, sub_placement,
-                              tile_inputs)
+                              place_network, sub_placement)
 from repro.sim.report import InterChipLinkTracker, PipelineReport
 
 
@@ -369,16 +364,18 @@ def build_pipeline(app: str, *, max_cores_per_chip: int | None = None,
 class PipelineServer:
     """Pipelined serving front-end over the chip fabric.
 
-    Wavefront execution at one beat per stage hop: a request occupies one
-    global stage per beat; per beat each chip assembles the input slab of
-    its OWN stage slice (idle slots drive zeros, their outputs discarded
-    and unbilled) and runs ONE fused stacked Pallas call (plus one
-    aggregation call when its slice has fan-in-split stages).  A sample
-    crossing a chip boundary is metered on the inter-chip link; the hop
-    rides inside the beat's static routing slot, so the Table IV beat —
-    and therefore the one-sample-per-beat steady state — survives the
-    split.  Numerics equal the wave path exactly (stages are
-    sample-independent), so served outputs equal `mlp_forward`."""
+    The session is one compiled scan over beats (`compiled.serve_scan`):
+    the fabric is the single-lane (C == 1) case of the farm's beat scan
+    over the FULL placement's stage stacks, a request occupying one
+    global stage per beat.  The boundary quantize rule is the scan's
+    ordinary inter-stage ADC; a sample crossing a chip boundary is
+    metered on the inter-chip link, replayed host-side from the static
+    owner map.  The hop rides inside the beat's static routing slot, so
+    the Table IV beat — and therefore the one-sample-per-beat steady state
+    — survives the split.  Numerics equal the wave path (stages are
+    sample-independent), so served outputs equal `mlp_forward`.  A server
+    serves the conductance version it was built at and one request
+    microbatch over its lifetime."""
 
     def __init__(self, pipe: ChipPipeline):
         self.pipe = pipe
@@ -387,174 +384,22 @@ class PipelineServer:
         self.S = len(self.stages)
         # global stage index -> owning chip
         self.owner = [k for k, g in enumerate(pipe.groups) for _ in g]
-        # per-chip concatenated core stacks (snapshot)
-        self._off: list[int] = []
-        self._stack_p, self._stack_m = [], []
-        self._agg: list[dict] = []
-        for k, g in enumerate(pipe.groups):
-            offs, parts_p, parts_m = {}, [], []
-            off = 0
-            for s in g:
-                st = self.stages[s]
-                offs[s] = off
-                off += st.g_plus.shape[0]
-                parts_p.append(st.g_plus)
-                parts_m.append(st.g_minus)
-            self._off.append(offs)
-            self._stack_p.append(jnp.concatenate(parts_p, axis=0))
-            self._stack_m.append(jnp.concatenate(parts_m, axis=0))
-            agg_idx = [s for s in g if self.stages[s].row_tiles > 1]
-            agg = {"idx": agg_idx}
-            if agg_idx:
-                agg["rows"] = max(self.stages[s].agg_plus.shape[1]
-                                  for s in agg_idx)
-                agg["off"], ap, am = {}, [], []
-                aoff = 0
-                for s in agg_idx:
-                    st = self.stages[s]
-                    agg["off"][s] = aoff
-                    aoff += st.agg_plus.shape[0]
-                    pad = agg["rows"] - st.agg_plus.shape[1]
-                    ap.append(jnp.pad(st.agg_plus,
-                                      ((0, 0), (0, pad), (0, 0))))
-                    am.append(jnp.pad(st.agg_minus,
-                                      ((0, 0), (0, pad), (0, 0))))
-                agg["p"] = jnp.concatenate(ap, axis=0)
-                agg["m"] = jnp.concatenate(am, axis=0)
-            self._agg.append(agg)
-        self.slots: list = [None] * self.S     # (rid, input activation)
         self._slot_m: int | None = None
 
-    def step(self, queue: RequestQueue) -> int:
-        """Advance the fabric one beat; returns samples retired."""
+    def run(self, queue: RequestQueue) -> dict:
+        """Drain the queue in one session; returns serving stats.  The
+        counters replay the static schedule host-side: every request
+        visits every stage once."""
         pipe = self.pipe
-        if pipe.version != self._version:
-            raise RuntimeError(
-                "pipeline conductances changed since this PipelineServer "
-                "was built (a train_step ran); construct a fresh server — "
-                "the serving stacks are a snapshot")
-        spec = pipe.spec
-        if self.slots[0] is None:
-            req = queue.pop()
-            if req is not None:
-                x = jnp.atleast_2d(jnp.asarray(req.x))
-                if self._slot_m is None:
-                    self._slot_m = x.shape[0]
-                elif x.shape[0] != self._slot_m:
-                    raise ValueError(
-                        f"request {req.rid} has microbatch {x.shape[0]}, "
-                        f"session uses {self._slot_m}; serve uniform "
-                        f"request shapes")
-                self.slots[0] = (req.rid, x)
-        m = next((h.shape[0] for slot in self.slots if slot is not None
-                  for h in (slot[1],)), None)
-        if m is None:
-            return 0
-
-        # one fused call per chip over its stage slice (+ one aggregation
-        # call when the slice has fan-in-split stages)
-        dp_by_stage: dict[int, jax.Array] = {}
-        for k, g in enumerate(pipe.groups):
-            if not any(self.slots[s] is not None for s in g):
-                continue
-            parts = []
-            for s in g:
-                st = self.stages[s]
-                if self.slots[s] is not None:
-                    parts.append(tile_inputs(self.slots[s][1], st.row_tiles,
-                                             st.col_tiles, st.rows))
-                else:
-                    parts.append(jnp.zeros(
-                        (st.g_plus.shape[0], m, st.rows)))
-            xs = jnp.concatenate(parts, axis=0)
-            ys = kernel_ops.crossbar_fwd_stacked(xs, self._stack_p[k],
-                                                 self._stack_m[k])
-            agg = self._agg[k]
-            agg_out = None
-            if agg["idx"]:
-                aparts = []
-                for s in agg["idx"]:
-                    st = self.stages[s]
-                    o = self._off[k][s]
-                    u = fold_subneuron_partials(
-                        ys[None, o:o + st.row_tiles * st.col_tiles], st)[0]
-                    aparts.append(jnp.pad(
-                        u, ((0, 0), (0, 0), (0, agg["rows"] - u.shape[-1]))))
-                agg_out = kernel_ops.crossbar_fwd_stacked(
-                    jnp.concatenate(aparts, axis=0), agg["p"], agg["m"])
-            for s in g:
-                if self.slots[s] is None:
-                    continue
-                st = self.stages[s]
-                o = self._off[k][s]
-                agg_slice = None
-                if st.row_tiles > 1:
-                    ao = agg["off"][s]
-                    agg_slice = agg_out[None, ao:ao + st.col_tiles]
-                dp_by_stage[s] = stage_dp_from_outputs(
-                    ys[None, o:o + st.row_tiles * st.col_tiles], st,
-                    agg_slice)[0]
-
-        # advance the wavefront, metering boundary hops
-        new_slots: list = [None] * self.S
-        retired = retired_requests = 0
-        for s, st in enumerate(self.stages):
-            if self.slots[s] is None:
-                continue
-            rid, _ = self.slots[s]
-            k = self.owner[s]
-            chip = pipe.chips[k]
-            chip._count_stage(chip.infer_counters, st, m)
-            h = hard_sigmoid(dp_by_stage[s])
-            if s < self.S - 1:
-                if spec.transport_quant:
-                    h = q.adc_quantize_ste(h, spec.adc_bits)
-                if self.owner[s + 1] != k:
-                    pipe.link.record_fwd(
-                        k, pipe.boundary_dims[k] * hw.ADC_BITS_OUT, m)
-                new_slots[s + 1] = (rid, h)
-            else:
-                queue.complete(rid, h)
-                retired += m
-                retired_requests += 1
-                pipe.chips[0].infer_counters.record_io(
-                    pipe.placement.dims[0] * pipe.input_bits, m)
-                chip.infer_counters.record_io(
-                    pipe.placement.dims[-1] * hw.ADC_BITS_OUT, m)
-                for c in pipe.chips:
-                    c.infer_counters.samples += m
-        if retired_requests:
-            pipe.serve_full_beats += 1
-        self.slots = new_slots
-        pipe.serve_beats += 1
-        pipe.serve_samples += retired
-        return retired
-
-    def _run_compiled(self, queue: RequestQueue) -> dict:
-        """The serving session as ONE jitted scan over beats: the fabric
-        is the single-lane (C == 1) case of the farm's beat scan over the
-        FULL placement's stage stacks — per-stage numerics are per-core
-        independent, so one fused dispatch over every stage equals the
-        eager per-chip dispatches bitwise.  The boundary quantize rule is
-        the scan's ordinary inter-stage ADC (traced); boundary link
-        metering replays the static owner map host-side."""
-        pipe = self.pipe
-        if pipe.version != self._version:
-            raise RuntimeError(
-                "pipeline conductances changed since this PipelineServer "
-                "was built (a train_step ran); construct a fresh server — "
-                "the serving stacks are a snapshot")
         S = self.S
         with spans.phase(spans.PREPARE):
+            csim.check_fresh("PipelineServer", self._version, pipe.version)
             st = pipe._get_serve_stacks()
             gp_cat = st.g_plus.reshape(1, S * st.T_max, st.rows, st.cols)
             gm_cat = st.g_minus.reshape(1, S * st.T_max, st.rows, st.cols)
         Q, m, _, n_beats = csim.run_serve_session(
-            queue, st, gp_cat, gm_cat, pipe.spec, 1)
+            queue, st, gp_cat, gm_cat, pipe.spec, 1, self._slot_m)
         self._slot_m = m
-
-        # counters: the eager loop's per-beat billing aggregated over the
-        # static schedule (every request visits every stage once)
         with spans.phase(spans.ACCOUNT):
             n = Q * m
             for s, stg in enumerate(self.stages):
@@ -585,43 +430,8 @@ class PipelineServer:
             "makespan_us": n_beats * beat_us,
             "latency_us": pipe.serve_latency_us,
             "samples_per_s": n / (Q * beat_us) * 1e6,
-            "occupancy": Q * self.S / max(self.S * n_beats, 1),
-        }
-
-    def run(self, queue: RequestQueue, *, max_beats: int | None = None
-            ) -> dict:
-        """Drain the queue; returns serving stats.
-
-        With the compiled executor active, a fresh server draining a
-        uniform-shape queue runs the whole session as one jitted beat
-        scan; step-wise use stays on the eager per-beat path."""
-        with spans.phase(spans.PREPARE):
-            compiled = (compiled_enabled() and max_beats is None
-                        and csim.serve_session_applicable(
-                            queue, all(s is None for s in self.slots),
-                            self._slot_m))
-        if compiled:
-            return self._run_compiled(queue)
-        beats = retired = 0
-        limit = max_beats if max_beats is not None else 10_000_000
-        done_before = queue.completed
-        while not queue.drained and beats < limit:
-            retired += self.step(queue)
-            beats += 1
-        if self._slot_m is not None:
-            self.pipe.serve_slot_m = self._slot_m
-        beat_us = self.pipe.beat_us
-        steady = max(beats - (self.S - 1), 1)
-        requests = queue.completed - done_before
-        return {
-            "beats": beats,
-            "retired": retired,
-            "beat_us": beat_us,
-            "makespan_us": beats * beat_us,
-            "latency_us": self.pipe.serve_latency_us,
-            "samples_per_s": retired / (steady * beat_us) * 1e6,
             # fraction of stage slots occupied over the session
-            "occupancy": requests * self.S / max(self.S * beats, 1),
+            "occupancy": Q * S / max(S * n_beats, 1),
         }
 
 

@@ -37,7 +37,10 @@ def _overlay(placement: Placement, faults: MemristorFaults, w_max: float,
                                 variation=variation),
             g_minus=faults.apply(st.g_minus, salt=sm, w_max=w_max,
                                  variation=variation)))
-    return dataclasses.replace(placement, stages=stages)
+    # a conductance write: the new version invalidates any envelope built
+    # from the old placement
+    return dataclasses.replace(placement, stages=stages,
+                               version=placement.version + 1)
 
 
 def inject_faults(placement: Placement, faults: MemristorFaults,

@@ -5,17 +5,18 @@ Each network layer becomes one pipeline *stage* (DESIGN.md "Virtual chip"):
   * the layer's ``row_tiles x col_tiles`` core grid (section V.B) is stored
     as ONE stacked array ``(T, rows, cols)`` with ``T = row_tiles*col_tiles``
     — slice ``t = i*col_tiles + j`` is the physical core holding fan-in tile
-    ``i`` of fan-out tile ``j``.  The whole stage executes as a single
-    batched Pallas call (`kernels/ops.crossbar_fwd_stacked`), never a Python
-    loop over cores;
+    ``i`` of fan-out tile ``j``.  The compiled programs
+    (``repro.sim.compiled``) run every stage of the padded `StageStacks`
+    envelope in one ``lax.scan``, never a Python loop over cores;
   * row 0 of the first fan-in tile is the provisioned bias row (Fig. 8).
     The repo's crossbar layers have no bias term, so its conductances start
     at zero and its input line is driven to 0 — the row occupies hardware
     (mapping counts it) but contributes nothing numerically;
   * layers split over fan-in get a Fig.-14 aggregation stage: ``col_tiles``
     cores whose unit-conductance block pattern sums the ``row_tiles``
-    sub-neuron partials per neuron.  It too executes as one stacked call.
-    The sim implements *exact aggregation* (``split_activation=False``):
+    sub-neuron partials per neuron.  Its cores are billed (`Stage.n_cores`);
+    the compiled scan computes the same unit-weight sum as an exact gather
+    sum.  The sim implements *exact aggregation* (``split_activation=False``):
     partials cross the NoC at full precision and the activation is applied
     once after aggregation, which is what `crossbar_apply` computes.  Known
     idealization, shared with the mapper: an aggregation core serving
@@ -28,8 +29,8 @@ Each network layer becomes one pipeline *stage* (DESIGN.md "Virtual chip"):
     both sides to count the same chip — so the sim executes what the
     mapper prices.
 
-The placement is mutable state: the virtual chip's update phase writes new
-conductance stacks back (`Placement.set_stage_stacks`), and
+The placement is mutable state: the compiled training programs hand each
+stage its updated stacks (`StageStacks.scatter_back`), and
 `Placement.extract_params` slices the stacks back into the per-layer
 ``{"g_plus", "g_minus"}`` dicts the rest of the repo consumes.
 """
@@ -93,14 +94,6 @@ class Placement:
         (the per-stage stacks still execute independently in time)."""
         return self.nmap.cores
 
-    def set_stage_stacks(self, index: int, g_plus: jax.Array,
-                         g_minus: jax.Array) -> None:
-        """Write updated conductance stacks back into stage ``index`` (the
-        virtual chip's update phase mutates the placement in place)."""
-        self.stages[index].g_plus = g_plus
-        self.stages[index].g_minus = g_minus
-        self.version += 1
-
     def extract_params(self) -> list[dict[str, jax.Array]]:
         """Stacks -> per-layer {"g_plus", "g_minus"} dicts (inverse of
         place_network's tiling, bias row and padding stripped)."""
@@ -133,88 +126,6 @@ def _pad_layer(g: jax.Array, r: int, c: int, rows: int,
     F, O = g.shape
     out = jnp.zeros((r * rows, c * cols), g.dtype)
     return out.at[1:F + 1, :O].set(g)
-
-
-def tile_inputs(x: jax.Array, r: int, c: int, rows: int,
-                bias_value: float = 0.0) -> jax.Array:
-    """(M, fan_in) activations -> (r*c, M, rows) per-core input slabs.
-
-    Core ``i*c + j`` receives fan-in tile ``i`` (all cores of one fan-in
-    tile see the same rows — the routing network fans a neuron output to
-    every consuming core).  Row 0 of tile 0 is the bias line, driven at
-    ``bias_value`` (0: the repo's layers are bias-free; the row is
-    provisioned but silent)."""
-    M, F = x.shape
-    xb = jnp.concatenate(
-        [jnp.full((M, 1), bias_value, x.dtype), x,
-         jnp.zeros((M, r * rows - F - 1), x.dtype)], axis=1)
-    xt = xb.reshape(M, r, rows).transpose(1, 0, 2)      # (r, M, rows)
-    return jnp.repeat(xt, c, axis=0)                    # (r*c, M, rows)
-
-
-def fold_subneuron_partials(ys: jax.Array, st: Stage) -> jax.Array:
-    """(C, r*c, M, cols) main-grid outputs of a fan-in-split stage ->
-    (C, c, M, r*cols) aggregation-core input lines (Fig. 14: partial ``i``
-    of neuron ``n`` drives line ``i*cols + n``)."""
-    C, M = ys.shape[0], ys.shape[2]
-    r, c = st.row_tiles, st.col_tiles
-    return (ys.reshape(C, r, c, M, st.cols).transpose(0, 2, 3, 1, 4)
-              .reshape(C, c, M, r * st.cols))
-
-
-def stage_dp_from_outputs(ys: jax.Array, st: Stage,
-                          agg_out: jax.Array | None = None) -> jax.Array:
-    """Core outputs -> (C, M, fan_out) stage dot products.
-
-    ``ys`` is the (C, r*c, M, cols) main-grid output; fan-in-split stages
-    pass the (C, c, M, cols) aggregation output instead of summing."""
-    C, M = ys.shape[0], ys.shape[2]
-    r, c = st.row_tiles, st.col_tiles
-    if st.row_tiles > 1:
-        dp = agg_out.transpose(0, 2, 1, 3).reshape(C, M, c * st.cols)
-    else:
-        dp = (ys.reshape(C, r, c, M, st.cols).sum(axis=1)
-                .transpose(0, 2, 1, 3).reshape(C, M, c * st.cols))
-    return dp[..., :st.lmap.fan_out]
-
-
-def stage_dot_products(st: Stage, h: jax.Array, g_plus: jax.Array,
-                       g_minus: jax.Array, run_fwd) -> jax.Array:
-    """One stage's exact-aggregated dot products — with the two reshape
-    helpers above, the single owner of the tile/aggregate discipline,
-    shared by the serial chip, the farm wave paths, and (helpers only)
-    the farm serving beat, so their numerics cannot drift apart.
-
-    ``h`` is ``(M, fan_in)`` or chip-stacked ``(C, Mc, fan_in)``;
-    ``g±`` match (``(T, rows, cols)`` / ``(C, T, rows, cols)``).
-    ``run_fwd(xs, gp, gm)`` is the stacked forward dispatch (the farm
-    passes its shard_mapped variant).  Fan-in-split stages run the
-    Fig.-14 aggregation as a second dispatch in the same time slot."""
-    chipped = h.ndim == 3
-    if not chipped:
-        h, g_plus, g_minus = h[None], g_plus[None], g_minus[None]
-    r, c = st.row_tiles, st.col_tiles
-    C = h.shape[0]
-    xs = jax.vmap(lambda hh: tile_inputs(hh, r, c, st.rows))(h)
-    ys = run_fwd(xs, g_plus, g_minus)
-    agg_out = None
-    if r > 1:
-        # sub-neuron partials cross the NoC to the aggregation cores,
-        # which sum them through unit conductances.
-        u = fold_subneuron_partials(ys, st)
-        agg_p = jnp.broadcast_to(st.agg_plus, (C,) + st.agg_plus.shape)
-        agg_m = jnp.broadcast_to(st.agg_minus, (C,) + st.agg_minus.shape)
-        agg_out = run_fwd(u, agg_p, agg_m)
-    dp = stage_dp_from_outputs(ys, st, agg_out)
-    return dp if chipped else dp[0]
-
-
-def untile_outputs(ys: jax.Array, r: int, c: int, fan_out: int) -> jax.Array:
-    """(r*c, M, cols) per-core partial DPs -> (M, fan_out) exact-aggregated
-    dot products (sum over fan-in tiles, concat over fan-out tiles)."""
-    T, M, cols = ys.shape
-    part = ys.reshape(r, c, M, cols).sum(axis=0)        # (c, M, cols)
-    return part.transpose(1, 0, 2).reshape(M, c * cols)[:, :fan_out]
 
 
 def _agg_pattern(r: int, cols: int, dtype) -> jax.Array:
@@ -266,9 +177,10 @@ class StageStacks:
         zero (a zero crossbar emits zeros, which the gathers below never
         read back into a valid lane);
       * gather maps (int32, precomputed host-side) that express the
-        per-stage tile/aggregate/fold discipline of `tile_inputs`,
-        `_tile_cols`, `stage_dp_from_outputs` and the backward fan-in fold
-        as shape-uniform indexed reads.  Every index either addresses a
+        per-stage tile/aggregate/fold discipline (input tiling over the
+        fan-in tiles, error tiling over the fan-out tiles, Fig.-14
+        aggregation and the backward fan-in fold) as shape-uniform indexed
+        reads.  Every index either addresses a
         valid element or a dedicated always-zero slot, so the SAME traced
         program executes any stage of the ragged stack;
       * ``valid_out`` — ``(S, N_pad)`` output-lane validity, re-masked
@@ -366,12 +278,12 @@ def build_stage_stacks(pl: Placement) -> StageStacks:
     for s in range(S):
         r, c, F, O = rs[s], cs[s], fan_in[s], fan_out[s]
         t = np.arange(Ts[s])
-        # input tiling (tile_inputs): core i*c+j line l <- global line
+        # input tiling: core i*c+j line l <- global line
         # i*rows + l of [bias, x, zeros]; lines past the payload stay on
         # the always-zero bias slot.
         g = (t[:, None] // c) * rows + np.arange(rows)[None, :]
         in_idx[s, :Ts[s]] = np.where((g >= 1) & (g <= F), g, 0)
-        # fan-out tiling (_tile_cols): core i*c+j col k <- lane j*cols+k of
+        # fan-out tiling: core i*c+j col k <- lane j*cols+k of
         # the local error (zero beyond fan_out by construction).
         ds_idx[s, :Ts[s]] = ((t[:, None] % c) * cols
                              + np.arange(cols)[None, :])

@@ -1,4 +1,5 @@
-"""Compiled whole-step execution (ISSUE 5 / DESIGN.md §8).
+"""Compiled whole-step execution (DESIGN.md §8), the simulator's only
+execution path.
 
 Four contracts:
 
@@ -6,8 +7,10 @@ Four contracts:
     four-call sequence (fwd + bwd + dw + pulse) BITWISE over a sweep of
     shapes and ragged zero-padded core stacks, including the 8-bit
     sign-magnitude error path;
-  * the compiled chip/farm/serve paths equal the eager reference path
-    (``REPRO_SIM_COMPILED=0``) numerically, with IDENTICAL counters;
+  * the compiled chip/farm/serve paths equal the plain reference
+    (`core.crossbar.mlp_forward` / `paper_backprop_step`) numerically, a
+    chip with device faults trains on the same programs, and every
+    counter equals the value the static schedule gives EXACTLY;
   * compilation happens exactly once per (topology, batch) shape, the
     conductance stacks are donated (updated in place, allocation-stable);
   * the kernel-side caches are bounded LRUs and the autotune table
@@ -42,16 +45,6 @@ def _layers(dims, seed=0, spec=PAPER_SPEC):
 def _x(dims, n=4, seed=9):
     return jax.random.uniform(jax.random.PRNGKey(seed), (n, dims[0]),
                               minval=-0.5, maxval=0.5)
-
-
-class _eager_sim:
-    """Context manager: force the eager per-stage reference path."""
-
-    def __enter__(self):
-        os.environ["REPRO_SIM_COMPILED"] = "0"
-
-    def __exit__(self, *a):
-        os.environ.pop("REPRO_SIM_COMPILED", None)
 
 
 # ---------------------------------------------------------------------------
@@ -138,61 +131,98 @@ def test_megakernel_compute_y_off_zeroes_forward():
 
 
 # ---------------------------------------------------------------------------
-# Compiled path == eager reference path (chip, farm, serving)
+# Compiled paths == the plain reference (chip, farm, serving); counters ==
+# the static schedule
 # ---------------------------------------------------------------------------
+
+def _schedule(dims):
+    """Per-stage (billed cores, emitting links, routed outputs) of the
+    network's static schedule, from the mapper alone."""
+    from repro.core.mapping import map_network
+    return [(lm.total_cores, lm.row_tiles * lm.col_tiles, lm.routed_outputs)
+            for lm in map_network(dims).layers]
+
+
+def _assert_counters(cc, dims, M, *, phases, io_bits, calls=1):
+    """``cc`` holds ``calls`` waves of ``M`` samples over ``phases``: S·M
+    slots and M·Σ cores core steps a phase a wave, one NoC record per
+    stage a wave, ``io_bits`` a sample."""
+    from repro.sim.noc import LinkRecord
+    sched = _schedule(dims)
+    S, cores = len(sched), sum(c for c, _, _ in sched)
+    for p in ("fwd", "bwd", "update"):
+        n = calls if p in phases else 0
+        assert cc.slots[p] == n * S * M, (p, cc.slots)
+        assert cc.core_steps[p] == n * M * cores, (p, cc.core_steps)
+    assert cc.samples == calls * M
+    assert cc.io_bits == calls * M * io_bits
+    assert cc.noc.records == calls * [
+        LinkRecord(s, routed, links, M)
+        for s, (_, links, routed) in enumerate(sched)]
+
 
 @pytest.mark.parametrize("dims", [[41, 15, 41],
                                   hw.PAPER_NETWORKS["mnist_class"]])
-def test_compiled_chip_matches_eager_reference(dims):
+def test_compiled_chip_matches_plain_reference(dims):
     layers = _layers(dims)
-    x, tgt = _x(dims), _x(dims, seed=3)[:, :dims[-1]]
-    chip_c = VirtualChip([dict(p) for p in layers], PAPER_SPEC)
-    with _eager_sim():
-        chip_e = VirtualChip([dict(p) for p in layers], PAPER_SPEC)
-        ye = chip_e.infer(x)
-        for step in range(2):
-            ee = chip_e.train_step(x, tgt, lr=0.2)
-    yc = chip_c.infer(x)
-    for step in range(2):
-        ec = chip_c.train_step(x, tgt, lr=0.2)
-    np.testing.assert_allclose(np.asarray(yc), np.asarray(ye), atol=1e-6)
-    np.testing.assert_allclose(np.asarray(ec), np.asarray(ee), atol=1e-6)
-    for a, b in zip(chip_c.layers(), chip_e.layers()):
-        np.testing.assert_allclose(np.asarray(a["g_plus"]),
-                                   np.asarray(b["g_plus"]), atol=1e-6)
-    # accounting is schedule-derived, so it must be EXACTLY equal
-    for attr in ("infer_counters", "train_counters"):
-        cc, ce = getattr(chip_c, attr), getattr(chip_e, attr)
-        assert cc.slots == ce.slots
-        assert cc.core_steps == ce.core_steps
-        assert cc.samples == ce.samples and cc.io_bits == ce.io_bits
-        assert cc.noc.routed_outputs == ce.noc.routed_outputs
-        assert cc.noc.max_link_cycles == ce.noc.max_link_cycles
+    M = 4
+    x, tgt = _x(dims, n=M), _x(dims, n=M, seed=3)[:, :dims[-1]]
+    chip = VirtualChip([dict(p) for p in layers], PAPER_SPEC)
+    np.testing.assert_allclose(np.asarray(chip.infer(x)),
+                               np.asarray(xb.mlp_forward(layers, x,
+                                                         PAPER_SPEC)),
+                               atol=1e-5)
+    ref = [dict(p) for p in layers]
+    for step, atol in enumerate((1e-6, 1e-5)):
+        ref, ref_err = xb.paper_backprop_step(ref, x, tgt, PAPER_SPEC,
+                                              lr=0.2)
+        err = chip.train_step(x, tgt, lr=0.2)
+        np.testing.assert_allclose(np.asarray(err), np.asarray(ref_err),
+                                   atol=atol)
+    for a, b in zip(chip.layers(), ref):
+        for k in ("g_plus", "g_minus"):
+            np.testing.assert_allclose(np.asarray(a[k]), np.asarray(b[k]),
+                                       atol=1e-5)
+    # accounting is schedule-derived, so it must be EXACTLY the schedule's
+    out_bits = dims[-1] * hw.ADC_BITS_OUT
+    _assert_counters(chip.infer_counters, dims, M, phases=("fwd",),
+                     io_bits=dims[0] * chip.input_bits + out_bits)
+    _assert_counters(chip.train_counters, dims, M,
+                     phases=("fwd", "bwd", "update"), calls=2,
+                     io_bits=2 * dims[0] * chip.input_bits + out_bits)
 
 
-def test_compiled_farm_serve_matches_eager_reference():
-    dims = [41, 15, 41]
-    x = _x(dims, n=7, seed=5)
-    farm_c = build_farm("kdd_anomaly", 2, seed=0)
-    out_c, stats_c = farm_c.serve(x)
-    with _eager_sim():
-        farm_e = build_farm("kdd_anomaly", 2, seed=0)
-        out_e, stats_e = farm_e.serve(x)
-    np.testing.assert_allclose(np.asarray(out_c), np.asarray(out_e),
+def test_compiled_farm_serve_matches_plain_reference():
+    dims = hw.PAPER_NETWORKS["kdd_anomaly"]
+    Q, C = 7, 2
+    x = _x(dims, n=Q, seed=5)
+    farm = build_farm("kdd_anomaly", C, seed=0)
+    out, stats = farm.serve(x)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(xb.mlp_forward(farm.layers(), x, PAPER_SPEC)), atol=1e-5)
+    chip = VirtualChip(farm.layers(), PAPER_SPEC)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(chip.infer(x)),
                                atol=1e-6)
-    assert stats_c == stats_e
-    for cc, ce in zip(farm_c.chip_infer, farm_e.chip_infer):
-        assert cc.slots == ce.slots and cc.samples == ce.samples
-        assert cc.core_steps == ce.core_steps and cc.io_bits == ce.io_bits
-    assert farm_c.serve_full_beats == farm_e.serve_full_beats
-    assert farm_c.serve_link.sample_bits == farm_e.serve_link.sample_bits
+    # the static schedule: request r enters lane r % C at beat r // C
+    S, beat = len(dims) - 1, farm.beat_us
+    q_max = -(-Q // C)
+    assert stats == {"beats": S - 1 + q_max, "retired": Q, "beat_us": beat,
+                     "makespan_us": (S - 1 + q_max) * beat,
+                     "samples_per_s": Q / (q_max * beat) * 1e6,
+                     "occupancy": Q * S / (S * C * (S - 1 + q_max))}
+    bits = dims[0] * farm.input_bits + dims[-1] * hw.ADC_BITS_OUT
+    for c, cc in enumerate(farm.chip_infer):
+        _assert_counters(cc, dims, -(-(Q - c) // C), phases=("fwd",),
+                         io_bits=bits)
+    assert farm.serve_full_beats == Q // C
+    assert farm.serve_link.sample_bits == Q * bits
 
 
 def test_compiled_serve_keeps_cross_session_microbatch_contract():
-    """The eager server pins one request microbatch per server lifetime;
-    the compiled session path must enforce the same contract (a second
-    session with a different microbatch falls back to the eager path,
-    which raises the documented error)."""
+    """A server pins one request microbatch over its lifetime: a second
+    session with a different microbatch raises the documented error, read
+    from the requests' host shapes before anything is served."""
     from repro.runtime.serve_loop import RequestQueue
     from repro.sim.cluster import FarmServer
     farm = build_farm("kdd_anomaly", 2, seed=0)
@@ -202,23 +232,80 @@ def test_compiled_serve_keeps_cross_session_microbatch_contract():
         server.run(RequestQueue([jnp.zeros((3, 41))] * 4))  # m=3 rejected
 
 
-def test_compiled_farm_train_matches_eager_reference():
-    dims = [41, 15, 41]
-    x = _x(dims, n=8, seed=6)
-    farm_c = build_farm("kdd_anomaly", 2, seed=0)
-    ec = farm_c.train_step(x, x, lr=0.1)
-    with _eager_sim():
-        farm_e = build_farm("kdd_anomaly", 2, seed=0)
-        ee = farm_e.train_step(x, x, lr=0.1)
-    np.testing.assert_allclose(np.asarray(ec), np.asarray(ee), atol=1e-6)
-    for a, b in zip(farm_c.layers(), farm_e.layers()):
-        np.testing.assert_allclose(np.asarray(a["g_plus"]),
-                                   np.asarray(b["g_plus"]), atol=1e-6)
-    assert farm_c.replicas_in_sync()
-    for cc, ce in zip(farm_c.chip_train, farm_e.chip_train):
-        assert cc.slots == ce.slots and cc.core_steps == ce.core_steps
-    assert (farm_c.train_link.reconcile_bits
-            == farm_e.train_link.reconcile_bits)
+def test_compiled_farm_train_matches_plain_reference():
+    dims = hw.PAPER_NETWORKS["kdd_anomaly"]
+    M, C = 8, 2
+    x = _x(dims, n=M, seed=6)
+    farm = build_farm("kdd_anomaly", C, seed=0)
+    layers = [dict(p) for p in farm.layers()]
+    chip = VirtualChip([dict(p) for p in layers], PAPER_SPEC)
+    ef = farm.train_step(x, x, lr=0.1)
+    ref, ref_err = xb.paper_backprop_step(layers, x, x, PAPER_SPEC, lr=0.1)
+    np.testing.assert_allclose(np.asarray(ef), np.asarray(ref_err),
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(ef),
+                               np.asarray(chip.train_step(x, x, lr=0.1)),
+                               atol=1e-6)
+    for a, b, c in zip(farm.layers(), ref, chip.layers()):
+        for k in ("g_plus", "g_minus"):
+            np.testing.assert_allclose(np.asarray(a[k]), np.asarray(b[k]),
+                                       atol=1e-6)
+            np.testing.assert_allclose(np.asarray(a[k]), np.asarray(c[k]),
+                                       atol=1e-6)
+    assert farm.replicas_in_sync()
+    bits = 2 * dims[0] * farm.input_bits + dims[-1] * hw.ADC_BITS_OUT
+    for cc in farm.chip_train:
+        _assert_counters(cc, dims, M // C, phases=("fwd", "bwd", "update"),
+                         io_bits=bits)
+    # every chip sends its dw codes up and takes the pulses back: 8 bits
+    # a placed main-grid cell each way
+    rows, cols = farm.placement.rows, farm.placement.cols
+    cells = sum(links * rows * cols for _, links, _ in _schedule(dims))
+    assert (farm.train_link.reconcile_bits
+            == C * 2 * cells * hw.ERR_BITS_LINK)
+
+
+def test_faulted_chip_trains_on_the_compiled_path(monkeypatch):
+    """A chip that owns device faults trains through `chip_train`: each
+    step equals the reference step followed by the stuck-mask re-assert,
+    and the stuck cells hold after two steps."""
+    from repro.runtime.faults import MemristorFaults
+    from repro.sim.faults import inject_faults, reapply
+    calls = _capture(monkeypatch, "chip_train")
+    dims, w_max = [41, 15, 41], PAPER_SPEC.w_max
+    f = MemristorFaults(stuck_on=0.05, stuck_off=0.2, variation_sigma=0.1,
+                        seed=2)
+    layers = _layers(dims)
+    chip = VirtualChip([dict(p) for p in layers], PAPER_SPEC, faults=f)
+    pl = inject_faults(place_network(layers), f, w_max=w_max)
+    ref = pl.extract_params()
+    x, tgt = _x(dims), _x(dims, seed=3)[:, :dims[-1]]
+    for step, atol in enumerate((1e-6, 1e-5)):
+        ref, ref_err = xb.paper_backprop_step(ref, x, tgt, PAPER_SPEC,
+                                              lr=0.5)
+        ref = reapply(place_network(ref, pl.nmap), f,
+                      w_max=w_max).extract_params()
+        err = chip.train_step(x, tgt, lr=0.5)
+        np.testing.assert_allclose(np.asarray(err), np.asarray(ref_err),
+                                   atol=atol)
+    assert len(calls) == 2
+    for a, b in zip(chip.layers(), ref):
+        for k in ("g_plus", "g_minus"):
+            np.testing.assert_allclose(np.asarray(a[k]), np.asarray(b[k]),
+                                       atol=1e-5)
+    for st in chip.placement.stages:
+        for g, salt in ((st.g_plus, 2 * st.index),
+                        (st.g_minus, 2 * st.index + 1)):
+            on, off = (np.asarray(m) for m in f.masks(g.shape, salt=salt))
+            assert off.any() and on.any()
+            assert not np.asarray(g)[off].any()
+            assert (np.asarray(g)[on] == w_max).all()
+    # the next call runs on the masked stacks, not the pre-mask envelope
+    st = chip._get_stacks()
+    for s, stage in enumerate(chip.placement.stages):
+        T = stage.g_plus.shape[0]
+        np.testing.assert_array_equal(np.asarray(st.g_plus[s, :T]),
+                                      np.asarray(stage.g_plus))
 
 
 def test_forced_kernel_body_matches_reference_math(monkeypatch):
@@ -463,7 +550,7 @@ def test_writeback_keeps_a_rebuilt_results_stacks(monkeypatch, kind):
 
 
 def _eager_envelope(acts, dps, delta, st):
-    """The padded wave as the eager per-stage loop builds it: zeros, then
+    """The padded wave built op by op outside any program: zeros, then
     one ``.at[].set`` per stage and operand."""
     M = delta.shape[0]
     acts_p = jnp.zeros((st.S, M, st.L), jnp.float32)
@@ -477,7 +564,7 @@ def _eager_envelope(acts, dps, delta, st):
 
 def test_pad_wave_equals_the_eager_envelope_bitwise():
     """Each slice of a two-chip pipeline with ragged stages pads its wave
-    in one program into the very envelope the eager loop builds: payload
+    in one program into the very envelope an op-by-op build makes: payload
     lanes, the zero bias slot and every zero lane beyond a stage's
     widths."""
     pipe = _build("pipeline")

@@ -382,18 +382,15 @@ print("OK")
 # ---------------------------------------------------------------------------
 
 def test_meshed_farm_matches_single_device(subproc):
+    """A farm on a 4-device mesh equals the same farm on one device: the
+    compiled programs under ``shard_map`` against the same programs with
+    the chip axis as an array axis, for ``infer``, ``train_step`` and
+    ``serve`` (outputs within 1e-6, counters exactly equal)."""
     out = subproc("""
-import os
-# pin: shard_map over the chip axis is numerically invisible vs the same
-# eager array-axis execution.  The serial reference must run the same
-# (eager) dispatch path — the compiled executor is a different XLA
-# program whose fusion shifts last-bit rounding; compiled==eager is
-# pinned separately in tests/test_compiled_step.py.
-os.environ["REPRO_SIM_COMPILED"] = "0"
 import jax, jax.numpy as jnp
 from repro.configs.paper_apps import PAPER_SPEC
 from repro.core import crossbar as xb
-from repro.sim import ChipFarm, VirtualChip
+from repro.sim import ChipFarm, compiled as csim
 from repro.sim.cluster import make_farm_mesh
 key = jax.random.PRNGKey(0)
 dims = [41, 15, 41]
@@ -402,22 +399,47 @@ L = [xb.init_conductances(jax.random.fold_in(key, i), f, o, PAPER_SPEC)
 mesh = make_farm_mesh(4)
 assert mesh is not None and mesh.shape["chips"] == 4, mesh
 farm = ChipFarm([dict(p) for p in L], PAPER_SPEC, n_chips=4, mesh=mesh)
+one = ChipFarm([dict(p) for p in L], PAPER_SPEC, n_chips=4)
 # each replica's stacks live on the device that runs its chip
 assert len(farm._gp[0].sharding.device_set) == 4
-chip = VirtualChip([dict(p) for p in L], PAPER_SPEC)
+
+def close(a, b):
+    d = float(jnp.abs(a - b).max())
+    assert d <= 1e-6, d
+
 x = jax.random.uniform(jax.random.PRNGKey(9), (8, 41),
                        minval=-0.5, maxval=0.5)
-assert float(jnp.abs(farm.infer(x) - chip.infer(x)).max()) == 0.0
-ef = farm.train_step(x, x, lr=0.1)
-ec = chip.train_step(x, x, lr=0.1)
-assert float(jnp.abs(ef - ec).max()) == 0.0
-for a, b in zip(farm.layers(), chip.layers()):
+close(farm.infer(x), one.infer(x))
+close(farm.train_step(x, x, lr=0.1), one.train_step(x, x, lr=0.1))
+for a, b in zip(farm.layers(), one.layers()):
     for k in ("g_plus", "g_minus"):
-        d = float(jnp.abs(a[k] - b[k]).max())
-        assert d <= 1e-6, (k, d)
-out, _ = farm.serve(x)
-ref = xb.mlp_forward(farm.layers(), x, PAPER_SPEC)
-assert float(jnp.abs(out - ref).max()) <= 1e-5
+        close(a[k], b[k])
+# 10 requests over 4 chips: the last beat leaves two lanes idle
+meshes, scan = [], csim.serve_scan
+def seen(*a, **kw):
+    meshes.append(kw.get("mesh"))
+    return scan(*a, **kw)
+csim.serve_scan = seen
+q = jax.random.uniform(jax.random.PRNGKey(19), (10, 41),
+                       minval=-0.5, maxval=0.5)
+out, stats = farm.serve(q)
+ref, ref_stats = one.serve(q)
+assert meshes == [mesh, None], meshes
+close(out, ref)
+assert stats == ref_stats, (stats, ref_stats)
+assert float(jnp.abs(out - xb.mlp_forward(farm.layers(), q,
+                                          PAPER_SPEC)).max()) <= 1e-5
+for attr in ("chip_infer", "chip_train"):
+    for a, b in zip(getattr(farm, attr), getattr(one, attr)):
+        assert a.slots == b.slots and a.core_steps == b.core_steps, attr
+        assert a.samples == b.samples and a.io_bits == b.io_bits, attr
+        assert a.noc.records == b.noc.records, attr
+for attr in ("serve_beats", "serve_full_beats", "serve_full_samples",
+             "serve_full_requests", "serve_sessions", "train_steps"):
+    assert getattr(farm, attr) == getattr(one, attr), attr
+assert farm.serve_link.sample_bits == one.serve_link.sample_bits
+assert farm.train_link.sample_bits == one.train_link.sample_bits
+assert farm.train_link.reconcile_bits == one.train_link.reconcile_bits
 print("OK")
 """, devices=4)
     assert "OK" in out
@@ -481,6 +503,20 @@ assert out.sharding.spec == jax.sharding.PartitionSpec("chips")
 assert np.array_equal(np.asarray(out), np.asarray(one.infer(x)))
 if reconcile == "none":
     assert float(jnp.abs(out - chip.infer(x)).max()) <= 1e-6
+# serving runs the compiled beat scan on the mesh, each device on its
+# chips' lanes; M + 3 requests leave lanes idle in the last beat
+q = jax.random.uniform(jax.random.PRNGKey(29), (M + 3, dims[0]),
+                       minval=-0.5, maxval=0.5)
+served, stats = farm.serve(q)
+want, want_stats = one.serve(q)
+assert float(jnp.abs(served - want).max()) <= 1e-6
+assert stats == want_stats, (stats, want_stats)
+keys = [k for k in csim.trace_counts() if k[0] == "serve_scan"
+        and k[-1] == (("chips", 4),)]
+assert len(keys) == 1, keys
+for a, b in zip(farm.chip_infer, one.chip_infer):
+    assert a.slots == b.slots and a.core_steps == b.core_steps
+    assert a.samples == b.samples and a.noc.records == b.noc.records
 print("OK")
 """
 
@@ -496,7 +532,9 @@ def test_meshed_farm_runs_the_compiled_step(subproc, dims, chips,
     """A farm on a 4-device mesh runs `train_step` and `infer` through the
     compiled program, sharded: it equals the one-device compiled farm
     bitwise (and the serial chip within re-association), its replicas stay
-    in lockstep, it traces once, and stacks and rows stay split."""
+    in lockstep, it traces once, and stacks and rows stay split.  Its
+    serving sessions run the meshed beat scan and equal the one-device
+    farm's, queue length not a multiple of the chips."""
     out = subproc(MESHED_COMPILED.format(dims=list(dims), chips=chips,
                                          reconcile=reconcile), devices=4)
     assert "OK" in out

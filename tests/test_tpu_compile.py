@@ -4,7 +4,8 @@ The TPU compiler is installed with JAX; it compiles for a chip that is
 described (``v5e:2x2``) and not attached.  Each stacked crossbar kernel is
 compiled at the shapes the three phases of ``chip_smoke.py`` dispatch
 (core stacks of 400x100 crossbars), and the whole compiled ``chip_train``
-program of ``mnist_class`` with the Pallas kernel body.  A compile refused
+program of ``mnist_class`` with the Pallas kernel body, on one chip and
+sharded over four, and that four-chip farm's serving scan.  A compile refused
 here (tiling, VMEM, lowering) would be refused on the chip.
 
 The topology is described inside a fixture, never while a module is
@@ -158,3 +159,40 @@ def test_meshed_mnist_farm_step_compiles_for_four_v5e_chips(topo, one_chip,
     # each chip holds and updates in place its replica's envelope
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= 2 * gp.nbytes // 4
+
+
+def test_meshed_farm_serve_compiles_for_four_v5e_chips(topo, one_chip,
+                                                      monkeypatch):
+    """A serving session of four ``mnist_class`` replicas, one per chip of
+    a 2x2 v5e host: one beat scan with the kernel run per chip on its own
+    lanes.  The lanes never meet: the only collectives gather the answers
+    into request order, after the scan."""
+    import re
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.sim import ChipFarm
+    monkeypatch.setattr(kernel_ops, "_default_interpret", lambda: False)
+    dims = NETWORKS["mnist_class"]
+    key = jax.random.PRNGKey(0)
+    layers = [xb.init_conductances(jax.random.fold_in(key, i), f, o,
+                                   PAPER_SPEC)
+              for i, (f, o) in enumerate(zip(dims, dims[1:]))]
+    farm = ChipFarm(layers, PAPER_SPEC, n_chips=4)
+    st, _, _, _ = farm._get_stacks()
+    cfg = csim.chip_config(st, PAPER_SPEC)._replace(use_kernels=True)
+    mesh = Mesh(topo.devices, (csim.FARM_AXIS,))
+    chips = NamedSharding(mesh, P(csim.FARM_AXIS))
+    alike = NamedSharding(mesh, P())
+    idx = {k: _spec(alike, *v.shape, dtype=v.dtype)
+           for k, v in st.index_pytree().items()}
+    slots = (4, st.S * st.T_max, st.rows, st.cols)
+    q_pad = 16                     # 64 one-row requests over 4 lanes
+    compiled = csim.serve_scan.lower(
+        _spec(chips, *slots), _spec(chips, *slots),
+        _spec(alike, 4 * q_pad, 1, dims[0]), idx, cfg, st.S - 1 + q_pad,
+        mesh=mesh).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    opcodes = re.findall(r" ([a-z-]+)\(", text)
+    assert opcodes.count("all-gather") == 1          # the answers
+    assert not {"all-reduce", "all-to-all", "collective-permute",
+                "reduce-scatter"} & set(opcodes)
