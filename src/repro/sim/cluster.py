@@ -65,7 +65,7 @@ from repro.core.mapping import map_network
 from repro.runtime.serve_loop import RequestQueue
 from repro.sim import compiled as csim
 from repro.sim import spans
-from repro.sim.chip import VirtualChip
+from repro.sim.chip import VirtualChip, as_rows
 from repro.sim.noc import NocTracker
 from repro.sim.placer import (Placement, StageStacks, build_stage_stacks,
                               place_network)
@@ -161,6 +161,8 @@ class ChipFarm:
         self._idx = None                          # index maps of _stacks
         self._held_scalar = (None, None)          # (value, device copy)
         self._stacks_version = -1
+        self._serve_stacks = None                 # (layout, gp_cat, gm_cat)
+        self._serve_stacks_version = -1
 
     # ------------------------------------------------------------------
     # Compiled whole-step executor (repro.sim.compiled, DESIGN.md §8)
@@ -193,6 +195,18 @@ class ChipFarm:
             self._fgm = pad(tuple(self._gm), st.T_max)
             self._stacks_version = self.version
         return self._stacks, self._fgp, self._fgm, self._idx
+
+    def _get_serve_stacks(self):
+        """(layout, gp (C, S*T_max, rows, cols), gm) — the envelope as the
+        serving scan takes it, each chip's stages concatenated on its
+        lane; cut once per conductance version."""
+        if self._serve_stacks_version != self.version:
+            st, gp, gm, _ = self._get_stacks()
+            cat = (self.n_chips, st.S * st.T_max, st.rows, st.cols)
+            self._serve_stacks = (st, jnp.moveaxis(gp, 0, 1).reshape(cat),
+                                  jnp.moveaxis(gm, 0, 1).reshape(cat))
+            self._serve_stacks_version = self.version
+        return self._serve_stacks
 
     def _scatter_back(self, gp, gm, views_plus, views_minus) -> None:
         """Keep the compiled step's donated envelope and hand its
@@ -353,7 +367,7 @@ class ChipFarm:
         """Serve a batch of requests (one per row) through the pipelined
         farm; returns (outputs in request order, serving stats)."""
         with spans.phase(spans.PREPARE):
-            x = jnp.atleast_2d(x)
+            x = as_rows(x)
             if x.shape[0] == 0:
                 return (jnp.zeros((0, self.placement.dims[-1])),
                         {"beats": 0, "retired": 0, "beat_us": self.beat_us,
@@ -361,9 +375,7 @@ class ChipFarm:
                          "occupancy": 0.0})
             server = FarmServer(self)
             queue = RequestQueue(list(x))
-        stats = server.run(queue)
-        with spans.phase(spans.WRITEBACK):
-            out = jnp.stack([r.reshape(-1) for r in queue.results()])
+        stats, out = server.session(queue)
         return out, stats
 
     # ------------------------------------------------------------------
@@ -531,19 +543,20 @@ class FarmServer:
         self._slot_m: int | None = None   # uniform request batch size
 
     def run(self, queue: RequestQueue) -> dict:
-        """Drain the queue in one session; returns serving stats.  The
+        """Drain the queue in one session; returns serving stats."""
+        return self.session(queue)[0]
+
+    def session(self, queue: RequestQueue) -> tuple[dict, jax.Array]:
+        """Drain the queue in one session; returns (serving stats, the
+        session's answers as one (requests * m, out_dim) array).  The
         counters replay the static schedule host-side: lane ``c`` serves
         ``ceil((Q - c) / C)`` requests."""
         farm = self.farm
         C, S = self.C, self.S
         with spans.phase(spans.PREPARE):
             csim.check_fresh("FarmServer", self._version, farm.version)
-            st, gp, gm, _ = farm._get_stacks()
-            gp_cat = jnp.moveaxis(gp, 0, 1).reshape(C, S * st.T_max,
-                                                    st.rows, st.cols)
-            gm_cat = jnp.moveaxis(gm, 0, 1).reshape(C, S * st.T_max,
-                                                    st.rows, st.cols)
-        Q, m, q_max, n_beats = csim.run_serve_session(
+            st, gp_cat, gm_cat = farm._get_serve_stacks()
+        Q, m, q_max, n_beats, out = csim.run_serve_session(
             queue, st, gp_cat, gm_cat, farm.spec, C, self._slot_m,
             mesh=farm.mesh)
         self._slot_m = m
@@ -577,4 +590,4 @@ class FarmServer:
             "samples_per_s": Q * m / (q_max * beat_us) * 1e6,
             # fraction of (chip, stage) slots occupied over the session
             "occupancy": Q * S / max(S * C * n_beats, 1),
-        }
+        }, out

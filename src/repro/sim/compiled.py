@@ -687,16 +687,37 @@ def check_fresh(server: str, built: int, now: int) -> None:
             f"the conductances it was built on")
 
 
+@partial(jax.jit, static_argnames=("q_pad",))
+def pack_requests(xs, q_pad: int):
+    """A session's request inputs as the (q_pad, m, D) slab
+    :func:`serve_scan` takes, in one launch: each input as rows (a 1-D
+    request is one row), stacked in request order, and zero rows past the
+    last request."""
+    x = jnp.stack([jnp.atleast_2d(x) for x in xs]).astype(jnp.float32)
+    return jnp.pad(x, ((0, q_pad - len(xs)), (0, 0), (0, 0)))
+
+
+@partial(jax.jit, static_argnames=("q",))
+def split_answers(out, q: int):
+    """The first ``q`` requests' answers of a :func:`serve_scan` slab, in
+    one launch: each request's (m, out_dim) rows, and all of them as one
+    (q * m, out_dim) array."""
+    return tuple(out[i] for i in range(q)), out[:q].reshape(-1, out.shape[-1])
+
+
 def run_serve_session(queue, stacks, gp_cat, gm_cat, spec, n_lanes: int,
                       slot_m: int | None = None, mesh=None
-                      ) -> tuple[int, int, int, int]:
+                      ) -> tuple[int, int, int, int, jax.Array]:
     """Drain ``queue`` through :func:`serve_scan` (the shared front-end
     driver of `FarmServer` and `PipelineServer`): request ``r`` enters
     lane ``r % n_lanes`` at beat ``r // n_lanes``.  Every request must
     share one microbatch, and the server's earlier sessions' ``slot_m``
     when given; both are checked from host shapes before anything is
-    popped or launched.  Completes every request in order and returns
-    (requests, microbatch m, q_max, beats); the callers replay their own
+    popped or launched.  The requests go to the device in one program
+    (:func:`pack_requests`) and their answers come back in one
+    (:func:`split_answers`).  Completes every request in order and
+    returns (requests, microbatch m, q_max, beats, the session's answers
+    as one (requests * m, out_dim) array); the callers replay their own
     counter/link billing from the same schedule."""
     with spans.phase(spans.PREPARE):
         if not queue.pending:
@@ -716,8 +737,7 @@ def run_serve_session(queue, stacks, gp_cat, gm_cat, spec, n_lanes: int,
             if r is None:
                 break
             reqs.append(r)
-        xs = [jnp.atleast_2d(jnp.asarray(r.x)) for r in reqs]
-        Q, D = len(reqs), xs[0].shape[1]
+        Q = len(reqs)
         q_max = -(-Q // n_lanes)
         # bucket the lane depth to a power of two so varying queue lengths
         # share compiled executables (the scan's shapes are static in Qp
@@ -728,13 +748,13 @@ def run_serve_session(queue, stacks, gp_cat, gm_cat, spec, n_lanes: int,
         # replay) is unchanged, so the returned q_max/beats are the
         # schedule's own.
         q_pad = 1 << (q_max - 1).bit_length()
-        Qp = q_pad * n_lanes
-        x_arr = jnp.zeros((Qp, m, D), jnp.float32).at[:Q].set(jnp.stack(xs))
+        x_arr = pack_requests(tuple(r.x for r in reqs), q_pad * n_lanes)
         idx, cfg = stacks.index_pytree(), chip_config(stacks, spec)
     with spans.phase(spans.DISPATCH):
         out = serve_scan(gp_cat, gm_cat, x_arr, idx, cfg,
                          stacks.S - 1 + q_pad, mesh=mesh)
     with spans.phase(spans.WRITEBACK):
-        for i, r in enumerate(reqs):
-            queue.complete(r.rid, out[i])
-    return Q, m, q_max, stacks.S - 1 + q_max
+        answers, flat = split_answers(out, Q)
+        for r, a in zip(reqs, answers):
+            queue.complete(r.rid, a)
+    return Q, m, q_max, stacks.S - 1 + q_max, flat

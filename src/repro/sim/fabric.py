@@ -114,17 +114,22 @@ class ChipPipeline:
         self.train_samples = 0
         self.batch_per_step = 1
         self.n_micro = 1
-        self._serve_stacks: StageStacks | None = None
+        self._serve_stacks = None
         self._serve_stacks_version = -1
 
-    def _get_serve_stacks(self) -> StageStacks:
-        """Padded full-placement stacks for the compiled serving scan —
-        rebuilt when the fabric's conductances moved (``self.version``
-        tracks every train step; the chip slices alias the parent's
-        `Stage` objects, so a rebuild always sees their latest writes)."""
+    def _get_serve_stacks(self) -> tuple[StageStacks, jax.Array, jax.Array]:
+        """Padded full-placement stacks for the compiled serving scan, and
+        their conductances as the scan takes them ((1, S*T_max, rows,
+        cols), the one lane's stages concatenated) — rebuilt when the
+        fabric's conductances moved (``self.version`` tracks every train
+        step; the chip slices alias the parent's `Stage` objects, so a
+        rebuild always sees their latest writes)."""
         if (self._serve_stacks is None
                 or self._serve_stacks_version != self.version):
-            self._serve_stacks = build_stage_stacks(self.placement)
+            st = build_stage_stacks(self.placement)
+            cat = (1, st.S * st.T_max, st.rows, st.cols)
+            self._serve_stacks = (st, st.g_plus.reshape(cat),
+                                  st.g_minus.reshape(cat))
             self._serve_stacks_version = self.version
         return self._serve_stacks
 
@@ -223,7 +228,7 @@ class ChipPipeline:
         """Serve a batch of requests (one per row) through the pipelined
         fabric; returns (outputs in request order, serving stats)."""
         with spans.phase(spans.PREPARE):
-            x = jnp.atleast_2d(x)
+            x = as_rows(x)
             if x.shape[0] == 0:
                 return (jnp.zeros((0, self.placement.dims[-1])),
                         {"beats": 0, "retired": 0, "beat_us": self.beat_us,
@@ -231,9 +236,7 @@ class ChipPipeline:
                          "latency_us": self.serve_latency_us})
             server = PipelineServer(self)
             queue = RequestQueue(list(x))
-        stats = server.run(queue)
-        with spans.phase(spans.WRITEBACK):
-            out = jnp.stack([r.reshape(-1) for r in queue.results()])
+        stats, out = server.session(queue)
         return out, stats
 
     # ------------------------------------------------------------------
@@ -387,17 +390,20 @@ class PipelineServer:
         self._slot_m: int | None = None
 
     def run(self, queue: RequestQueue) -> dict:
-        """Drain the queue in one session; returns serving stats.  The
+        """Drain the queue in one session; returns serving stats."""
+        return self.session(queue)[0]
+
+    def session(self, queue: RequestQueue) -> tuple[dict, jax.Array]:
+        """Drain the queue in one session; returns (serving stats, the
+        session's answers as one (requests * m, out_dim) array).  The
         counters replay the static schedule host-side: every request
         visits every stage once."""
         pipe = self.pipe
         S = self.S
         with spans.phase(spans.PREPARE):
             csim.check_fresh("PipelineServer", self._version, pipe.version)
-            st = pipe._get_serve_stacks()
-            gp_cat = st.g_plus.reshape(1, S * st.T_max, st.rows, st.cols)
-            gm_cat = st.g_minus.reshape(1, S * st.T_max, st.rows, st.cols)
-        Q, m, _, n_beats = csim.run_serve_session(
+            st, gp_cat, gm_cat = pipe._get_serve_stacks()
+        Q, m, _, n_beats, out = csim.run_serve_session(
             queue, st, gp_cat, gm_cat, pipe.spec, 1, self._slot_m)
         self._slot_m = m
         with spans.phase(spans.ACCOUNT):
@@ -432,7 +438,7 @@ class PipelineServer:
             "samples_per_s": n / (Q * beat_us) * 1e6,
             # fraction of stage slots occupied over the session
             "occupancy": Q * S / max(S * n_beats, 1),
-        }
+        }, out
 
 
 class PipelineFarm:

@@ -11,7 +11,7 @@ host work falls into five *phase spans*, none of which holds another:
     `repro.sim.compiled`;
   * ``repro.writeback``: results handed back to the object's own layout
     (stacks scattered into the placement, padded outputs sliced, answers
-    completed and stacked);
+    split and completed);
   * ``repro.sync``: a blocking device-to-host read (the counters);
   * ``repro.account``: the host's replay of counters (phase slots, NoC,
     links, IO).

@@ -591,10 +591,10 @@ def test_pad_wave_equals_the_eager_envelope_bitwise():
     assert [c._get_stacks().Ts for c in pipe.chips] == [(12,), (6, 1)]
 
 
-def _prepare_launches(log_dir) -> int:
+def _phase_launches(log_dir, phase="repro.prepare") -> int:
     """Program launches (``PjitFunction``) and transfers (``DevicePut``)
-    the host dispatched inside ``repro.prepare`` spans of a profiler
-    trace, an event nested in another counted event counted once."""
+    the host dispatched inside ``phase`` spans of a profiler trace, an
+    event nested in another counted event counted once."""
     import glob
     from jax.profiler import ProfileData
     path, = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
@@ -607,15 +607,26 @@ def _prepare_launches(log_dir) -> int:
             evs = [(ev.name, int(ev.start_ns),
                     int(ev.start_ns) + int(ev.duration_ns))
                    for ev in line.events]
-            prep = [(a, b) for name, a, b in evs if name == "repro.prepare"]
+            spans = [(a, b) for name, a, b in evs if name == phase]
             hits = sorted(((a, -b) for name, a, b in evs
                            if name.startswith(("PjitFunction", "DevicePut"))
-                           and any(p <= a and b <= q for p, q in prep)))
+                           and any(p <= a and b <= q for p, q in spans)))
             end = None
             for a, neg_b in hits:
                 if end is None or a >= end:      # not inside the last one
                     n, end = n + 1, -neg_b
     return n
+
+
+def _traced(log_dir, calls: int, fn) -> None:
+    """``calls`` blocking calls of ``fn`` under the profiler, host events
+    kept."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    with jax.profiler.trace(str(log_dir), profiler_options=opts):
+        for _ in range(calls):
+            jax.block_until_ready(fn())
 
 
 def test_pipeline_train_prepare_launches_one_program_per_chip(tmp_path):
@@ -626,15 +637,60 @@ def test_pipeline_train_prepare_launches_one_program_per_chip(tmp_path):
     x = _x(RAGGED["dims"], n=4)
     tgt = _x(RAGGED["dims"], n=4, seed=3)[:, :RAGGED["dims"][-1]]
     jax.block_until_ready(pipe.train_step(x, tgt, 0.1))   # compile, place
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    opts.host_tracer_level = 2
     steps = 2
-    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
-        for _ in range(steps):
-            jax.block_until_ready(pipe.train_step(x, tgt, 0.1))
-    n = _prepare_launches(tmp_path)
+    _traced(tmp_path, steps, lambda: pipe.train_step(x, tgt, 0.1))
+    n = _phase_launches(tmp_path)
     assert 0 < n <= steps * pipe.n_chips, n
+
+
+@pytest.mark.parametrize("kind", ["pipeline", "farm"])
+def test_serve_session_launches_do_not_grow_with_requests(tmp_path, kind):
+    """A warm serving session moves its requests to the device and its
+    answers back in a fixed number of programs, whatever the number of
+    requests: at most three in each of prepare and writeback."""
+    obj = _build(kind)
+    sessions, per_q = 2, {}
+    for Q in (4, 16):
+        x = _x(RAGGED["dims"], n=Q)
+        jax.block_until_ready(obj.serve(x)[0])            # compile, place
+        log = tmp_path / str(Q)
+        _traced(log, sessions, lambda: obj.serve(x)[0])
+        per_q[Q] = [_phase_launches(log, phase)
+                    for phase in ("repro.prepare", "repro.writeback")]
+    assert per_q[4] == per_q[16], per_q
+    assert all(0 < n <= 3 * sessions for n in per_q[4]), per_q
+
+
+@pytest.mark.parametrize("m", [1, 3])
+@pytest.mark.parametrize("kind", ["pipeline", "farm"])
+def test_serve_session_answers_are_the_scan_rows(monkeypatch, kind, m):
+    """Five requests (padding rows and idle lanes in the slab): each
+    answer is bitwise its request's row of the `serve_scan` output, in
+    the request's microbatch shape, and the session's answers are their
+    concatenation; through `serve` (one-row requests) as well."""
+    from repro.runtime.serve_loop import RequestQueue
+    from repro.sim.cluster import FarmServer
+    from repro.sim.fabric import PipelineServer
+    scans = _capture(monkeypatch, "serve_scan")
+    obj = _build(kind)
+    server = (PipelineServer if kind == "pipeline" else FarmServer)(obj)
+    Q, out_dim = 5, RAGGED["dims"][-1]
+    xs = [_x(RAGGED["dims"], n=m, seed=20 + i) for i in range(Q)]
+    queue = RequestQueue(xs)
+    stats, answers = server.session(queue)
+    assert stats["retired"] == Q * m and queue.drained
+    scan = np.asarray(scans[-1])
+    got = queue.results()
+    for i, a in enumerate(got):
+        assert a.shape == (m, out_dim)
+        np.testing.assert_array_equal(np.asarray(a), scan[i])
+    np.testing.assert_array_equal(np.asarray(answers),
+                                  np.concatenate([np.asarray(a)
+                                                  for a in got]))
+    if m == 1:
+        out, _ = obj.serve(jnp.concatenate(xs))
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(scans[-1])[:Q, 0])
 
 
 @pytest.mark.parametrize("kind", ["chip", "farm"])
