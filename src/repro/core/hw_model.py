@@ -15,7 +15,8 @@ import dataclasses
 import math
 
 from repro.core.mapping import (NetworkMap, map_autoencoder_pretraining,
-                                map_network, split_network)
+                                map_network, map_pretrain_path,
+                                split_network)
 
 # ----- paper constants -----------------------------------------------------
 CROSSBAR_EVAL_NS = 20.0            # "crossbar required 20 ns to be evaluated"
@@ -167,6 +168,92 @@ def network_cost(name: str, dims: list[int], *, pretraining: bool = False,
         io_energy_train_j=_io_energy(io_bits * 2 + out_bits),
         io_energy_infer_j=_io_energy(io_bits + out_bits),
     )
+
+
+@dataclasses.dataclass(frozen=True)
+class PretrainStepCost:
+    """Per-sample analytic cost of one greedy pretraining step of hidden
+    layer ``layer`` (sections III.C-E): the forward wave through the
+    ``layer`` frozen encoders (``frozen``), then forward, backward and
+    update on encoder ``layer`` and its temporary decoder (``pair``), the
+    layers serialized and each part with the routing of its layers'
+    outputs, as `network_cost` prices a layer.  ``step`` is the sum; its
+    energy adds the control logic's draw over the whole step on every
+    core of the path.  Split over modelled chips, the inter-chip traffic
+    per sample: activations cross every boundary
+    forward as 3-bit codes, errors cross back as 8-bit codes only where
+    a trained layer lies upstream of the boundary (0 when unsplit)."""
+    name: str
+    layer: int
+    cores: int                 # placed cores of the path
+    frozen_cores: int          # cores of the frozen prefix (forward only)
+    frozen: PhaseCost
+    pair: PhaseCost
+    step: PhaseCost
+    io_energy_j: float
+    link_bits_fwd: int = 0
+    link_bits_bwd: int = 0
+
+
+def pretrain_step_cost(name: str, dims: list[int], layer: int, *,
+                       rows: int | None = None, cols: int | None = None,
+                       input_bits: int = 8,
+                       max_cores_per_chip: int | None = None,
+                       n_chips: int | None = None) -> PretrainStepCost:
+    """Cost one pretraining step of hidden layer ``layer`` of ``dims`` on
+    the path `mapping.map_pretrain_path` places (frozen encoders, encoder
+    ``layer``, its temporary decoder).  Off-chip IO is the network input
+    in and the reconstruction out: the target is the representation on
+    the chip.  Given ``max_cores_per_chip`` or ``n_chips``, the path is
+    split over modelled chips as `pipeline_cost` splits a network and the
+    inter-chip bits are priced.
+
+    Summed over the hidden layers, ``pair.time_us`` is
+    ``network_cost(..., pretraining=True).train.time_us`` (pinned by
+    ``tests/test_golden_paper.py``); the virtual chip's and the pipeline's
+    pretraining counters reproduce ``step`` to 1%
+    (``tests/test_pretrain.py``)."""
+    from repro.core.mapping import CORE_COLS, CORE_ROWS
+    rows = CORE_ROWS if rows is None else rows
+    cols = CORE_COLS if cols is None else cols
+    nmap = map_pretrain_path(dims, layer, rows, cols)
+    frozen, pair = nmap.layers[:layer], nmap.layers[layer:]
+
+    def route_us(lms) -> float:
+        return sum(lm.routed_outputs for lm in lms) / ROUTING_CLOCK_HZ * 1e6
+
+    frozen_cost = PhaseCost(
+        len(frozen) * FWD_US + route_us(frozen),
+        sum(core_step_energy_j(FWD_US, FWD_MW, lm.total_cores)
+            for lm in frozen))
+    pair_cost = PhaseCost(
+        len(pair) * (FWD_US + BWD_US + UPD_US) + route_us(pair),
+        sum(core_step_energy_j(t, mw, lm.total_cores) for lm in pair
+            for t, mw in ((FWD_US, FWD_MW), (BWD_US, BWD_MW),
+                          (UPD_US, UPD_MW))))
+    step_us = frozen_cost.time_us + pair_cost.time_us
+    step_j = frozen_cost.energy_j + pair_cost.energy_j + core_step_energy_j(
+        step_us, CTRL_MW, sum(lm.total_cores for lm in nmap.layers))
+
+    bits_fwd = bits_bwd = 0
+    if max_cores_per_chip is not None or n_chips is not None:
+        groups = split_network(nmap, max_cores_per_chip=max_cores_per_chip,
+                               n_chips=n_chips)
+        path_dims = list(dims[:layer + 2]) + [dims[layer]]
+        for g in groups[:-1]:
+            d = path_dims[g[-1] + 1]
+            bits_fwd += d * ADC_BITS_OUT
+            if g[-1] >= layer:
+                bits_bwd += d * ERR_BITS_LINK
+    return PretrainStepCost(
+        name=name, layer=layer, cores=nmap.cores,
+        frozen_cores=sum(lm.total_cores for lm in frozen),
+        frozen=frozen_cost, pair=pair_cost,
+        step=PhaseCost(step_us, step_j),
+        io_energy_j=_io_energy(dims[0] * input_bits
+                               + dims[layer] * ADC_BITS_OUT),
+        link_bits_fwd=bits_fwd,
+        link_bits_bwd=bits_bwd)
 
 
 def pipeline_beat_us(slot_cycles: int = 100) -> float:

@@ -220,3 +220,20 @@ def map_autoencoder_pretraining(dims: list[int], rows: int = CORE_ROWS,
     cores = sum(l.placed_cores for l in layer_maps)
     routed = sum(l.routed_outputs for l in layer_maps)
     return NetworkMap(tuple(layer_maps), cores, routed, routing_cycles=routed)
+
+
+def map_pretrain_path(dims: list[int], layer: int, rows: int = CORE_ROWS,
+                      cols: int = CORE_COLS) -> NetworkMap:
+    """The path one pretraining step of hidden layer ``layer`` runs: the
+    frozen encoders ``0 .. layer-1``, encoder ``layer`` and its temporary
+    decoder, as `map_autoencoder_pretraining` provisions them (encoder j
+    is its layer ``2j``, decoder j its layer ``2j + 1``).  The path's
+    widths are ``dims[:layer + 2] + [dims[layer]]``."""
+    if not 0 <= layer < len(dims) - 1:
+        raise ValueError(f"no hidden layer {layer} in {len(dims) - 1} "
+                         f"layers")
+    pre = map_autoencoder_pretraining(dims, rows, cols).layers
+    lms = pre[0:2 * layer + 1:2] + (pre[2 * layer + 1],)
+    routed = sum(lm.routed_outputs for lm in lms)
+    return NetworkMap(lms, sum(lm.placed_cores for lm in lms), routed,
+                      routing_cycles=routed)

@@ -13,7 +13,12 @@ per layer) plus counters; it runs:
   * ``train_step``   — the paper's three phases per layer (Table II):
                        fwd (record inputs + DPs), bwd (8-bit errors through
                        the same conductances), update (pulse-discretized
-                       outer product written into the stacks in place).
+                       outer product written into the stacks in place);
+  * ``pretrain_step`` — greedy layer-wise autoencoder pretraining
+                       (sections III.C-E) of a chip built with temporary
+                       decoders: the wave through the frozen encoders, then
+                       the training phases on one (encoder, decoder) pair
+                       whose target is the representation it encodes.
 
 Every call runs one compiled program (`repro.sim.compiled`) over the
 placement's padded stage stack: the wave is one ``lax.scan`` over the
@@ -49,8 +54,9 @@ from repro.sim import spans
 from repro.sim.faults import inject_faults, reapply
 from repro.sim.noc import NocTracker
 from repro.sim.placer import (Placement, StageStacks, build_stage_stacks,
-                              place_network)
-from repro.sim.report import PhaseCounters, SimReport
+                              place_decoders, place_network, pretrain_path,
+                              stage_params)
+from repro.sim.report import PhaseCounters, PretrainReport, SimReport
 
 
 def as_rows(x) -> jax.Array:
@@ -60,8 +66,61 @@ def as_rows(x) -> jax.Array:
     return x if np.ndim(x) == 2 else jnp.atleast_2d(x)
 
 
-class VirtualChip:
-    """A placed network executing on the simulated core grid."""
+class Pretraining:
+    """Greedy layer-wise autoencoder pretraining (sections III.C-E) as
+    `VirtualChip` and `fabric.ChipPipeline` hold it: the temporary
+    decoders as placed cores (`placer.place_decoders`) for the whole
+    schedule, and over each hidden layer's path (`placer.pretrain_path`)
+    one object of the holder's own class (`_over`), built once.  A path's
+    stages alias the holder's, so its pulse updates land in the network's
+    encoders, and `layers()` hands them to supervised fine-tuning."""
+
+    def _hold_decoders(self, decoders: list[dict] | None) -> None:
+        pl = self.placement
+        self._decoders = None if decoders is None else place_decoders(
+            decoders, pl.dims, pl.rows, pl.cols)
+        self._paths: dict = {}        # layer -> object over its path
+
+    def _pretrain_path(self, layer: int):
+        """The object over hidden layer ``layer``'s pretraining path
+        (encoders ``0 .. layer``, decoder ``layer``); its counters are the
+        layer's."""
+        if self._decoders is None:
+            raise ValueError("pretraining needs an object built with "
+                             "decoders=")
+        if layer not in self._paths:
+            self._paths[layer] = self._over(
+                pretrain_path(self.placement, self._decoders, layer),
+                f"{self.name}.pretrain{layer}")
+        return self._paths[layer]
+
+    def _stale(self, but: int | None = None) -> None:
+        """Invalidate the cached envelopes of every path but layer
+        ``but``'s: their encoder stages moved."""
+        for k, path in self._paths.items():
+            if k != but:
+                path._moved()
+
+    def _pretrained(self, layer: int) -> None:
+        """After a step of layer ``layer``: invalidate every cached
+        envelope that holds encoder ``layer`` besides the writer's own."""
+        self._moved()
+        self._stale(but=layer)
+
+    def decoders(self) -> list[dict[str, jax.Array]]:
+        """The temporary decoders, as per-layer dicts."""
+        if self._decoders is None:
+            return []
+        return [stage_params(st) for st in self._decoders]
+
+
+class VirtualChip(Pretraining):
+    """A placed network executing on the simulated core grid.
+
+    Given ``decoders`` (one temporary decoder per hidden layer, layer
+    ``j``'s of shape ``(dims[j+1], dims[j])``), the chip also holds them
+    as placed cores (`placer.place_decoders`) for the whole greedy
+    schedule of `pretrain_step`."""
 
     def __init__(self, layers: list[dict[str, jax.Array]],
                  spec: CrossbarSpec | None = None, *,
@@ -69,7 +128,7 @@ class VirtualChip:
                  name: str = "app", share_small_layers: bool = False,
                  input_bits: int = 8,
                  placement: Placement | None = None,
-                 faults=None):
+                 faults=None, decoders: list[dict] | None = None):
         if spec is None:
             from repro.configs.paper_apps import PAPER_SPEC
             spec = PAPER_SPEC
@@ -91,6 +150,12 @@ class VirtualChip:
             placement = inject_faults(placement, faults, w_max=spec.w_max)
             self.faults = faults
         self.placement = placement
+        if decoders is not None and (share_small_layers
+                                     or self.faults is not None):
+            raise NotImplementedError(
+                "pretraining places every layer on cores of its own and "
+                "models no device faults")
+        self._hold_decoders(decoders)
         self._stacks: StageStacks | None = None   # compiled-path envelope
         self._held: dict = {}         # name -> (value, device copy)
         self.infer_counters = PhaseCounters(
@@ -129,6 +194,7 @@ class VirtualChip:
         the next call rebuilds the envelope from the masked stacks."""
         st.g_plus, st.g_minus = out[0], out[1]
         st.scatter_back(self.placement, *csim.kept_views(out, cfg))
+        self._stale()
         if self.faults is not None:
             self.placement = reapply(self.placement, self.faults,
                                      w_max=self.spec.w_max)
@@ -256,7 +322,8 @@ class VirtualChip:
     def backward_update(self, acts: list[jax.Array], dps: list[jax.Array],
                         delta: jax.Array, lr: float, *,
                         global_batch: int | None = None,
-                        counters: PhaseCounters | None = None) -> jax.Array:
+                        counters: PhaseCounters | None = None,
+                        first: int = 0) -> jax.Array:
         """Run the bwd + update phases over this chip's stage slice.
 
         ``delta`` is the error arriving at the slice's OUTPUT side — the
@@ -268,7 +335,9 @@ class VirtualChip:
         propagate upstream (the value that would cross the link toward the
         previous chip).  ``global_batch`` is the learning-rate batch
         normalizer, the FULL step batch when this chip is a pipeline slice
-        (defaults to ``delta``'s batch)."""
+        (defaults to ``delta``'s batch).  ``first`` freezes the slice's
+        stages below it (`compiled.chip_backward`); the returned error is
+        then the one entering stage ``first``."""
         M = delta.shape[0]
         B = M if global_batch is None else global_batch
         c = counters if counters is not None else self.train_counters
@@ -280,7 +349,7 @@ class VirtualChip:
         with spans.phase(spans.DISPATCH):
             out = csim.chip_backward(
                 st.g_plus, st.g_minus, acts_p, dps_p, delta_p, idx, cfg,
-                lr_eff=lr_eff)
+                lr_eff=lr_eff, first=first)
         with spans.phase(spans.WRITEBACK):
             delta_prev, bcnt = out[2:]
             self._keep(st, out, cfg)
@@ -294,19 +363,31 @@ class VirtualChip:
         updates into the conductance stacks in place.  Matches
         `core.crossbar.paper_backprop_step` exactly under equal specs.
         Returns the output error (target - prediction)."""
-        c = self.train_counters
-        # the whole step — wave + reversed bwd/update scan — is ONE donated
-        # XLA program; the conductance stacks update in place and the
-        # counters come back in one transfer (DESIGN.md §8).
         with spans.phase(spans.PREPARE):
             x = jnp.atleast_2d(x)
             target = jnp.atleast_2d(target)
-            M = x.shape[0]
+        return self._train_program(
+            x, target, float(lr) / x.shape[0],
+            2 * self.placement.dims[0] * self.input_bits
+            + self.placement.dims[-1] * hw.ADC_BITS_OUT)
+
+    def _train_program(self, x, target, lr_eff, io_bits: int,
+                       first: int = 0) -> jax.Array:
+        """One training step on this chip's envelope: the wave and the
+        reversed bwd/update scan are ONE donated XLA program
+        (`compiled.chip_train`, ``first`` and ``target=None`` as it takes
+        them); the conductance stacks update in place and the counters
+        come back in one transfer (DESIGN.md §8).  Bills the training
+        counters, ``io_bits`` of off-chip IO a sample; returns the
+        error."""
+        c = self.train_counters
+        M = x.shape[0]
+        with spans.phase(spans.PREPARE):
             st = self._get_stacks()
             idx, cfg = st.index_pytree(), self._cfg
         with spans.phase(spans.DISPATCH):
             out = csim.chip_train(st.g_plus, st.g_minus, x, target, idx, cfg,
-                                  lr_eff=float(lr) / M)
+                                  lr_eff=lr_eff, first=first)
         with spans.phase(spans.WRITEBACK):
             err, fcnt, bcnt = out[2:]
             self._keep(st, out, cfg)
@@ -314,8 +395,48 @@ class VirtualChip:
         self._apply_bwd_counters(c, bcnt)
         with spans.phase(spans.ACCOUNT):
             c.samples += M
-            c.record_io(2 * self.placement.dims[0] * self.input_bits
-                        + self.placement.dims[-1] * hw.ADC_BITS_OUT, M)
+            c.record_io(io_bits, M)
+        return err
+
+    # ------------------------------------------------------------------
+    # Layer-wise pretraining (sections III.C-E)
+    # ------------------------------------------------------------------
+
+    def _over(self, placement: Placement, name: str) -> "VirtualChip":
+        return VirtualChip([], self.spec, name=name,
+                           input_bits=self.input_bits, placement=placement)
+
+    def _moved(self) -> None:
+        """The placement's conductances moved outside this chip's own
+        steps: the next call rebuilds the envelope."""
+        self.placement.version += 1
+
+    @spans.entry("chip.pretrain_step")
+    def pretrain_step(self, layer: int, x: jax.Array,
+                      lr: float) -> jax.Array:
+        """One greedy pretraining step of hidden layer ``layer`` on the
+        batch ``x`` of network inputs, in ONE donated program
+        (`compiled.chip_train` with ``first=layer`` and the autoencoder
+        target): the wave through the frozen encoders ``0 .. layer-1``
+        (the network input DAC-driven, 3-bit codes after), encoder
+        ``layer`` and its decoder, whose target is the representation
+        that entered encoder ``layer``; the pulse updates go into encoder
+        ``layer`` and decoder ``layer`` only, the frozen stacks stay
+        bitwise as they were.  Matches `core.autoencoder` pretraining
+        (``_ae_bp`` on the reference representation).  Returns the
+        reconstruction error (target - reconstruction)."""
+        path = self._pretrain_path(layer)
+        with spans.phase(spans.PREPARE):
+            x = as_rows(x)
+            lr_eff = self._on_device("lr_eff",
+                                     np.float32(float(lr) / x.shape[0]))
+        # IO: the input in and the reconstruction out; the target is on
+        # the chip
+        err = path._train_program(
+            x, None, lr_eff, self.placement.dims[0] * self.input_bits
+            + self.placement.dims[layer] * hw.ADC_BITS_OUT, first=layer)
+        with spans.phase(spans.ACCOUNT):
+            self._pretrained(layer)
         return err
 
     # ------------------------------------------------------------------
@@ -323,7 +444,9 @@ class VirtualChip:
     # ------------------------------------------------------------------
 
     def layers(self) -> list[dict[str, jax.Array]]:
-        """Current conductances as per-layer dicts (post-training)."""
+        """Current conductances as per-layer dicts (post-training; after
+        pretraining, the encoders, as supervised fine-tuning takes
+        them)."""
         return self.placement.extract_params()
 
     def report(self) -> SimReport:
@@ -351,4 +474,17 @@ class VirtualChip:
                 else tr.noc.routed_outputs_per_sample(tr.samples)),
             link_utilization=(inf.noc.link_utilization if inf.samples
                               else tr.noc.link_utilization),
+            pretrain=tuple(self._pretrain_report(k) for k in
+                           sorted(self._paths)
+                           if self._paths[k].train_counters.samples),
         )
+
+    def _pretrain_report(self, layer: int) -> PretrainReport:
+        """Hidden layer ``layer``'s pretraining counters beside the
+        analytic cost of its step on this chip's core grid."""
+        pl = self.placement
+        return PretrainReport.measure(
+            layer, [self._paths[layer].train_counters],
+            hw.pretrain_step_cost(self.name, list(pl.dims), layer,
+                                  rows=pl.rows, cols=pl.cols,
+                                  input_bits=self.input_bits))

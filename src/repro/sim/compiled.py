@@ -225,7 +225,7 @@ def _forward_scan(gp, gm, x, idx, quantize_tail, cfg: ChipConfig):
 
 def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
                    lr_eff, reconcile: str | None,
-                   axis_name: str | None = None):
+                   axis_name: str | None = None, first: int = 0):
     """Bwd + update phases as one reversed ``lax.scan``.
 
     ``lr_eff`` (lr / global batch) is a TRACED scalar — an lr schedule
@@ -239,8 +239,11 @@ def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
     replica.  ``axis_name`` is the mesh axis when the program runs under
     ``shard_map`` with this device's chips only: the error full-scale is
     then agreed by `farm_max` and the outer products are psummed by
-    `farm_reduce_sum` over it.  Returns (new gp, new gm, upstream delta,
-    counters)."""
+    `farm_reduce_sum` over it.  ``first`` (static) is the first trained
+    stage: the scan runs over stages ``first ..`` only, and the stacks of
+    the stages below are never written, so a frozen prefix stays bitwise
+    as it was (layer-wise pretraining).  Returns (new gp, new gm, upstream
+    delta — the error entering stage ``first`` — and counters)."""
     from repro.dist.collectives import farm_max, farm_reduce_sum
 
     C, M = delta.shape[0], delta.shape[1]
@@ -330,11 +333,14 @@ def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
             + jnp.array([0, M, 0, M], jnp.int32) * cores_s
         return (delta_prev, cnt), (gp2, gm2)
 
+    per = (gp, gm, acts, dps, idx["in_idx"], idx["ds_idx"], idx["fold_idx"],
+           idx["prev_idx"], idx["core_counts"])
+    if first:
+        per = tuple(a[first:] for a in per)
     (delta_fin, cnt), (gp_new, gm_new) = jax.lax.scan(
-        body, (delta, jnp.zeros(4, jnp.int32)),
-        (gp, gm, acts, dps, idx["in_idx"], idx["ds_idx"], idx["fold_idx"],
-         idx["prev_idx"], idx["core_counts"]),
-        reverse=True)
+        body, (delta, jnp.zeros(4, jnp.int32)), per, reverse=True)
+    if first:
+        gp_new, gm_new = gp.at[first:].set(gp_new), gm.at[first:].set(gm_new)
     return gp_new, gm_new, delta_fin, cnt
 
 
@@ -429,19 +435,26 @@ def _infer(gp, gm, x, idx, cfg: ChipConfig):
 
 
 def _train(gp, gm, x, target, idx, cfg: ChipConfig, lr_eff, reconcile,
-           axis_name: str | None = None):
+           axis_name: str | None = None, first: int = 0):
     """Forward wave and reversed bwd/update scan on chip-stacked ``x`` (C,
     M, fan_in) and ``target``: (gp', gm', err (C, M, out_dim), fwd
-    counters, bwd counters)."""
+    counters, bwd counters).  Stages below ``first`` stay frozen
+    (`_backward_scan`).  ``target is None`` is the autoencoder target of
+    layer-wise pretraining: the activation entering stage ``first`` as
+    the wave carried it (the network input at stage 0, the 3-bit codes
+    after), taken inside the program."""
     acts, dps, _, fcnt = _forward_scan(
         gp, gm, x, idx, jnp.asarray(False), cfg)
     out = hard_sigmoid(dps[-1])
-    C, M = x.shape[0], x.shape[1]
-    tpad = jnp.zeros((C, M, cfg.N_pad), jnp.float32)
-    tpad = tpad.at[:, :, :target.shape[2]].set(target)
+    if target is None:
+        tpad = acts[first][:, :, 1:]     # zero beyond the stage's fan-in
+    else:
+        C, M = x.shape[0], x.shape[1]
+        tpad = jnp.zeros((C, M, cfg.N_pad), jnp.float32)
+        tpad = tpad.at[:, :, :target.shape[2]].set(target)
     delta0 = tpad - out
     gp2, gm2, _, bcnt = _backward_scan(gp, gm, acts, dps, delta0, idx, cfg,
-                                       lr_eff, reconcile, axis_name)
+                                       lr_eff, reconcile, axis_name, first)
     return gp2, gm2, delta0[:, :, :cfg.out_dim], fcnt, bcnt
 
 
@@ -483,10 +496,10 @@ def chip_infer(gp, gm, x, idx, cfg: ChipConfig, mesh=None):
 
 
 @_returns_views(5)
-@partial(jax.jit, static_argnames=("cfg", "reconcile", "mesh"),
+@partial(jax.jit, static_argnames=("cfg", "reconcile", "mesh", "first"),
          donate_argnums=(0, 1))
 def chip_train(gp, gm, x, target, idx, cfg: ChipConfig, lr_eff=0.1,
-               reconcile: str | None = None, mesh=None):
+               reconcile: str | None = None, mesh=None, first: int = 0):
     """Compiled training step — forward wave + reversed bwd/update scan in
     ONE donated program.  Returns the `Stepped` (gp', gm', err, fwd
     counters, bwd counters); the conductance stacks update in place
@@ -499,7 +512,14 @@ def chip_train(gp, gm, x, target, idx, cfg: ChipConfig, lr_eff=0.1,
     step under ``shard_map``: ``x``/``target`` are the global batch, rows split
     over the devices; the error full-scale and the reconciled outer
     products are agreed across devices inside the program; ``err`` comes
-    back (M, out_dim) and the views split by chips like the stacks."""
+    back (M, out_dim) and the views split by chips like the stacks.
+
+    Layer-wise pretraining (serial chip): ``first`` (static) is the first
+    trained stage, the stages below it a frozen prefix whose stacks are
+    never written, and ``target=None`` takes the autoencoder target, the
+    activation entering stage ``first``, inside the program (`_train`).
+    The defaults are the supervised step, whose program they leave as it
+    was."""
     if mesh is not None:
         _mark("chip_train", cfg, x.shape, reconcile,
               tuple(mesh.shape.items()))
@@ -519,34 +539,40 @@ def chip_train(gp, gm, x, target, idx, cfg: ChipConfig, lr_eff=0.1,
             in_specs=(_CHIPS, _CHIPS, _ROWS, _ROWS, _ALL, _ALL),
             out_specs=(_CHIPS, _CHIPS, _ROWS, _ALL, _ALL, views, views),
             check_vma=False)(gp, gm, x, target, idx, lr_eff)
-    _mark("chip_train", cfg, x.shape, reconcile)
+    ae = target is None
+    _mark("chip_train", cfg, x.shape, reconcile,
+          *((first, ae) if first or ae else ()))
     serial = x.ndim == 2
     if serial:
-        x, target = x[None], target[None]
+        x, target = x[None], (target if ae else target[None])
     gp2, gm2, err, fcnt, bcnt = _train(gp, gm, x, target, idx, cfg, lr_eff,
-                                       reconcile)
+                                       reconcile, first=first)
     return (gp2, gm2, (err[0] if serial else err), fcnt, bcnt,
             _stage_views(gp2, cfg), _stage_views(gm2, cfg))
 
 
 @_returns_views(4)
-@partial(jax.jit, static_argnames=("cfg", "reconcile"),
+@partial(jax.jit, static_argnames=("cfg", "reconcile", "first"),
          donate_argnums=(0, 1))
 def chip_backward(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
-                  lr_eff=0.1, reconcile: str | None = None):
+                  lr_eff=0.1, reconcile: str | None = None, first: int = 0):
     """Compiled bwd + update phases over a stage slice (the pipeline
     fabric's per-chip entry point).  ``delta`` arrives padded to N_pad —
     (C, M, N_pad), or (M, N_pad) to rank-bridge the serial case like
     :func:`chip_infer`.  ``lr_eff`` is a traced scalar.  Returns the
     `Stepped` (gp', gm', upstream delta, counters): the upstream delta cut
-    to the slice's fan-in, the views as in :func:`chip_train`."""
-    _mark("chip_backward", cfg, delta.shape, reconcile)
+    to the slice's fan-in, the views as in :func:`chip_train`.  ``first``
+    (static) freezes the slice's stages below it, as in
+    :func:`chip_train`; the delta is then the error entering stage
+    ``first``, cut to its fan-in."""
+    _mark("chip_backward", cfg, delta.shape, reconcile,
+          *((first,) if first else ()))
     serial = delta.ndim == 2
     if serial:
         acts, dps, delta = acts[:, None], dps[:, None], delta[None]
     gp2, gm2, dfin, cnt = _backward_scan(gp, gm, acts, dps, delta, idx,
-                                         cfg, lr_eff, reconcile)
-    dfin = dfin[..., :cfg.fan_in[0]]
+                                         cfg, lr_eff, reconcile, first=first)
+    dfin = dfin[..., :cfg.fan_in[first]]
     return (gp2, gm2, (dfin[0] if serial else dfin), cnt,
             _stage_views(gp2, cfg), _stage_views(gm2, cfg))
 

@@ -37,6 +37,12 @@ and accounting:
     routing slot (flagged by ``link_utilization`` when it would not fit),
     and one sample retires per beat at steady state — the Table IV beat
     survives the split;
+  * `ChipPipeline.pretrain_step` — greedy layer-wise autoencoder
+    pretraining (sections III.C-E) of a pipeline built with temporary
+    decoders: each hidden layer's path (frozen encoders, the encoder, its
+    decoder) is split over the chips like a network, the prefix stays
+    frozen, and the decoder's error crosses back as 8-bit codes into the
+    encoder's chip;
   * `PipelineFarm` — the composition point with the data-parallel farm: N
     lockstep replicas of a K-chip pipeline ("farm of pipelines").  The
     replica axis delegates to `ChipFarm` (reconciled pulse updates, host
@@ -57,14 +63,20 @@ from repro.core.mapping import map_network, split_network
 from repro.runtime.serve_loop import RequestQueue
 from repro.sim import compiled as csim
 from repro.sim import spans
-from repro.sim.chip import VirtualChip, as_rows
+from repro.sim.chip import Pretraining, VirtualChip, as_rows
 from repro.sim.placer import (Placement, StageStacks, build_stage_stacks,
                               place_network, sub_placement)
-from repro.sim.report import InterChipLinkTracker, PipelineReport
+from repro.sim.report import (InterChipLinkTracker, PipelineReport,
+                              PretrainReport)
 
 
-class ChipPipeline:
-    """A network pipeline-split over K virtual chips (DESIGN.md §7)."""
+class ChipPipeline(Pretraining):
+    """A network pipeline-split over K virtual chips (DESIGN.md §7).
+
+    Built over ``layers`` or, as `VirtualChip` is, over a ``placement``.
+    Given ``decoders`` (one temporary decoder per hidden layer), the
+    pipeline holds them as placed cores for the whole greedy schedule of
+    `pretrain_step`, as `VirtualChip` does."""
 
     def __init__(self, layers: list[dict[str, jax.Array]],
                  spec: CrossbarSpec | None = None, *,
@@ -72,7 +84,8 @@ class ChipPipeline:
                  n_chips: int | None = None,
                  rows: int = CORE_ROWS, cols: int = CORE_COLS,
                  name: str = "pipeline", share_small_layers: bool = False,
-                 input_bits: int = 8):
+                 input_bits: int = 8, placement: Placement | None = None,
+                 decoders: list[dict] | None = None):
         if spec is None:
             from repro.configs.paper_apps import PAPER_SPEC
             spec = PAPER_SPEC
@@ -89,12 +102,15 @@ class ChipPipeline:
             max_cores_per_chip = hw.SYSTEM_CORES
         self._split_kw = dict(max_cores_per_chip=max_cores_per_chip,
                               n_chips=n_chips)
-        dims = [int(layers[0]["g_plus"].shape[0])] + \
-               [int(p["g_plus"].shape[1]) for p in layers]
-        nmap = map_network(dims, rows, cols,
-                           share_small_layers=share_small_layers)
-        self.placement: Placement = place_network(layers, nmap, rows, cols)
-        self.groups = split_network(nmap, **self._split_kw)
+        if placement is None:
+            dims = [int(layers[0]["g_plus"].shape[0])] + \
+                   [int(p["g_plus"].shape[1]) for p in layers]
+            nmap = map_network(dims, rows, cols,
+                               share_small_layers=share_small_layers)
+            placement = place_network(layers, nmap, rows, cols)
+        self.placement: Placement = placement
+        dims = placement.dims
+        self.groups = split_network(placement.nmap, **self._split_kw)
         self.n_chips = len(self.groups)
         self.chips = [
             VirtualChip([], spec, name=f"{name}.pp{k}",
@@ -116,6 +132,10 @@ class ChipPipeline:
         self.n_micro = 1
         self._serve_stacks = None
         self._serve_stacks_version = -1
+        if decoders is not None and share_small_layers:
+            raise NotImplementedError(
+                "pretraining places every layer on cores of its own")
+        self._hold_decoders(decoders)
 
     def _get_serve_stacks(self) -> tuple[StageStacks, jax.Array, jax.Array]:
         """Padded full-placement stacks for the compiled serving scan, and
@@ -179,10 +199,37 @@ class ChipPipeline:
         that makes the farm equal the serial chip, DESIGN.md §6.2)."""
         with spans.phase(spans.PREPARE):
             x, target = as_rows(x), as_rows(target)
+        if x.shape[0] % n_micro:
+            raise ValueError(f"batch {x.shape[0]} not divisible by n_micro "
+                             f"{n_micro}")
+        return self._train(x, target, lr, n_micro=n_micro)
+
+    def _owner(self, first: int) -> tuple[int, int]:
+        """The chip holding stage ``first``, and the stage's index in that
+        chip's slice."""
+        k = next(k for k, g in enumerate(self.groups) if first in g)
+        return k, self.groups[k].index(first)
+
+    @staticmethod
+    def _pretrain_target(wave, first: int) -> jax.Array:
+        """The autoencoder target: the representation entering the slice's
+        stage ``first`` as its chip's forward program took it in."""
+        acts, _ = wave
+        return acts[first]
+
+    def _train(self, x: jax.Array, target: jax.Array | None, lr: float, *,
+               first: int = 0, n_micro: int = 1) -> jax.Array:
+        """The step of `train_step` from stage ``first`` on: the forward
+        wave through every chip, then bwd + update from the last chip back
+        to the chip holding stage ``first``, whose program starts the
+        updates at that stage, so the stages below stay frozen.
+        ``target=None`` is the autoencoder target of layer-wise
+        pretraining, the representation entering stage ``first``
+        (`_pretrain_target`).  Returns the error."""
         M = x.shape[0]
-        if M % n_micro:
-            raise ValueError(f"batch {M} not divisible by n_micro {n_micro}")
         last = self.n_chips - 1
+        owner, local = self._owner(first)
+        ae = target is None
 
         h = x
         waves = []
@@ -192,12 +239,15 @@ class ChipPipeline:
             waves.append((acts, dps))
         with spans.phase(spans.WRITEBACK):
             # the step's result, and the error the last chip takes back
+            if ae:
+                target = self._pretrain_target(waves[owner], local)
             err = target - h
         delta = err
-        for k in reversed(range(self.n_chips)):
+        for k in reversed(range(owner, self.n_chips)):
             acts, dps = waves[k]
-            delta = self.chips[k].backward_update(acts, dps, delta, lr,
-                                                  global_batch=M)
+            delta = self.chips[k].backward_update(
+                acts, dps, delta, lr, global_batch=M,
+                first=local if k == owner else 0)
 
         with spans.phase(spans.ACCOUNT):
             for k, chip in enumerate(self.chips):
@@ -205,11 +255,13 @@ class ChipPipeline:
                 if k < last:
                     self.link.record_fwd(
                         k, self.boundary_dims[k] * hw.ADC_BITS_OUT, M)
-            for k in reversed(range(1, self.n_chips)):
+            for k in reversed(range(owner + 1, self.n_chips)):
                 self.link.record_bwd(
                     k - 1, self.boundary_dims[k - 1] * hw.ERR_BITS_LINK, M)
+            # IO: the input (and a supervised target) in, the output out
             self.chips[0].train_counters.record_io(
-                2 * self.placement.dims[0] * self.input_bits, M)
+                (1 if ae else 2) * self.placement.dims[0] * self.input_bits,
+                M)
             self.chips[-1].train_counters.record_io(
                 self.placement.dims[-1] * hw.ADC_BITS_OUT, M)
             self.train_steps += 1
@@ -217,6 +269,43 @@ class ChipPipeline:
             self.batch_per_step = M
             self.n_micro = n_micro
             self.version += 1
+            self._stale()
+        return err
+
+    # ------------------------------------------------------------------
+    # Layer-wise pretraining (sections III.C-E)
+    # ------------------------------------------------------------------
+
+    def _over(self, placement: Placement, name: str) -> "ChipPipeline":
+        return ChipPipeline([], self.spec, name=name,
+                            input_bits=self.input_bits, placement=placement,
+                            **self._split_kw)
+
+    def _moved(self) -> None:
+        """The placement's conductances moved outside this pipeline's own
+        steps: every chip's next call rebuilds its envelope."""
+        self.version += 1
+        for chip in self.chips:
+            chip._moved()
+
+    @spans.entry("pipeline.pretrain_step")
+    def pretrain_step(self, layer: int, x: jax.Array,
+                      lr: float) -> jax.Array:
+        """One greedy pretraining step of hidden layer ``layer`` on the
+        batch ``x`` of network inputs: `_train` from encoder ``layer`` on
+        the pipeline over the layer's path (frozen encoders, the encoder,
+        its decoder), split over chips as a network is.  Activations cross
+        each boundary as 3-bit codes; the target is the representation
+        that entered encoder ``layer``; errors cross back as 8-bit codes
+        as far as the chip holding encoder ``layer``, and no frozen stage
+        is written.  Equals `VirtualChip.pretrain_step` within float
+        re-association.  Returns the reconstruction error."""
+        path = self._pretrain_path(layer)
+        with spans.phase(spans.PREPARE):
+            x = as_rows(x)
+        err = path._train(x, None, lr, first=layer)
+        with spans.phase(spans.ACCOUNT):
+            self._pretrained(layer)
         return err
 
     # ------------------------------------------------------------------
@@ -344,7 +433,23 @@ class ChipPipeline:
             link_bits_total=link.fwd_bits_total + link.bwd_bits_total,
             span_us=span, bubble_fraction=bubble,
             n_micro=self.n_micro, batch_per_step=self.batch_per_step,
-            serve_slot_m=self.serve_slot_m, analytic=analytic)
+            serve_slot_m=self.serve_slot_m, analytic=analytic,
+            pretrain=tuple(self._pretrain_report(k) for k in
+                           sorted(self._paths)
+                           if self._paths[k].train_samples))
+
+    def _pretrain_report(self, layer: int) -> PretrainReport:
+        """Hidden layer ``layer``'s pretraining counters, summed over its
+        path's chips, beside the analytic cost of its step split as the
+        path is."""
+        path, pl = self._paths[layer], self.placement
+        return PretrainReport.measure(
+            layer, [c.train_counters for c in path.chips],
+            hw.pretrain_step_cost(self.name, list(pl.dims), layer,
+                                  rows=pl.rows, cols=pl.cols,
+                                  input_bits=self.input_bits,
+                                  **self._split_kw),
+            link=path.link)
 
 
 def build_pipeline(app: str, *, max_cores_per_chip: int | None = None,

@@ -42,7 +42,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.crossbar import CORE_COLS, CORE_ROWS
-from repro.core.mapping import LayerMap, NetworkMap
+from repro.core.mapping import (LayerMap, NetworkMap,
+                                map_autoencoder_pretraining,
+                                map_pretrain_path)
 
 
 @dataclasses.dataclass
@@ -97,14 +99,16 @@ class Placement:
     def extract_params(self) -> list[dict[str, jax.Array]]:
         """Stacks -> per-layer {"g_plus", "g_minus"} dicts (inverse of
         place_network's tiling, bias row and padding stripped)."""
-        out = []
-        for st in self.stages:
-            F, O = st.lmap.fan_in, st.lmap.fan_out
-            r, c = st.row_tiles, st.col_tiles
-            gp = _untile(st.g_plus, r, c, st.rows, st.cols)[1:F + 1, :O]
-            gm = _untile(st.g_minus, r, c, st.rows, st.cols)[1:F + 1, :O]
-            out.append({"g_plus": gp, "g_minus": gm})
-        return out
+        return [stage_params(st) for st in self.stages]
+
+
+def stage_params(st: Stage) -> dict[str, jax.Array]:
+    """One stage's stacks as its layer's {"g_plus", "g_minus"} dict."""
+    F, O = st.lmap.fan_in, st.lmap.fan_out
+    r, c = st.row_tiles, st.col_tiles
+    return {"g_plus": _untile(st.g_plus, r, c, st.rows, st.cols)[1:F + 1, :O],
+            "g_minus": _untile(st.g_minus, r, c, st.rows, st.cols)[1:F + 1,
+                                                                   :O]}
 
 
 def _tile(g: jax.Array, r: int, c: int, rows: int, cols: int) -> jax.Array:
@@ -365,3 +369,39 @@ def place_network(layers: list[dict[str, jax.Array]],
         stages.append(place_layer(i, p, lm, rows, cols))
     return Placement(stages=stages, dims=tuple(dims), rows=rows, cols=cols,
                      nmap=nmap)
+
+
+def place_decoders(decoders: list[dict[str, jax.Array]], dims,
+                   rows: int = CORE_ROWS, cols: int = CORE_COLS
+                   ) -> list[Stage]:
+    """The temporary decoders of layer-wise pretraining (section III.D) as
+    real cores: decoder ``j`` (``dims[j+1] -> dims[j]``) placed as
+    `map_autoencoder_pretraining` provisions it (its layer ``2j + 1``).
+    Their stage indices follow the ``len(dims) - 1`` encoder stages, so
+    the NoC records of a decoder never share an encoder's id."""
+    L = len(dims) - 1
+    if len(decoders) != L:
+        raise ValueError(f"{len(decoders)} decoders for {L} hidden layers")
+    pre = map_autoencoder_pretraining(list(dims), rows, cols).layers
+    stages = []
+    for j, d in enumerate(decoders):
+        lm = pre[2 * j + 1]
+        got = tuple(d["g_plus"].shape)
+        if got != (lm.fan_in, lm.fan_out):
+            raise ValueError(f"decoder {j}: params {got} != map "
+                             f"({lm.fan_in}, {lm.fan_out})")
+        stages.append(place_layer(L + j, d, lm, rows, cols))
+    return stages
+
+
+def pretrain_path(pl: Placement, decoders: list[Stage],
+                  layer: int) -> Placement:
+    """The placement one pretraining step of hidden layer ``layer`` runs
+    (`mapping.map_pretrain_path`): the encoder stages ``0 .. layer`` of
+    ``pl`` and decoder ``layer``.  Like `sub_placement`, its stage list
+    ALIASES the given `Stage` objects, so a step's pulse updates land in
+    the network's own encoder stages."""
+    nmap = map_pretrain_path(list(pl.dims), layer, pl.rows, pl.cols)
+    return Placement(stages=pl.stages[:layer + 1] + [decoders[layer]],
+                     dims=pl.dims[:layer + 2] + (pl.dims[layer],),
+                     rows=pl.rows, cols=pl.cols, nmap=nmap)

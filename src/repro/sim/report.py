@@ -196,6 +196,73 @@ class InterChipLinkTracker:
 
 
 @dataclasses.dataclass(frozen=True)
+class PretrainReport:
+    """Measured per-sample costs of the pretraining steps of one hidden
+    layer (sections III.C-E): the counters of every chip slice that ran
+    the layer's path (one for the virtual chip), checked against the
+    analytic `hw_model.pretrain_step_cost` by :meth:`compare_hw`.
+    ``frozen_core_steps`` counts the forward core steps of the frozen
+    prefix, measured as the forward core steps the backward phase did not
+    revisit (it runs on the trained stages only)."""
+    layer: int
+    samples: int                      # samples pretrained at this layer
+    frozen_core_steps: int            # all samples, the frozen prefix
+    time_us: float                    # per sample, phases + routing
+    energy_j: float                   # per sample, core + control
+    io_j: float
+    link_bits_fwd: float = 0.0        # per sample, all chip boundaries
+    link_bits_bwd: float = 0.0
+    analytic: "hw.PretrainStepCost | None" = None
+
+    @classmethod
+    def measure(cls, layer: int, counters: list[PhaseCounters],
+                analytic: "hw.PretrainStepCost",
+                link: "InterChipLinkTracker | None" = None
+                ) -> "PretrainReport":
+        """Fold the slices' counters (and the path's link tracker) into
+        per-sample costs, the pipeline's convention: slice times add, and
+        the control logic burns on every core of the path for the whole
+        step."""
+        n = max(counters[0].samples, 1)
+        time_us = sum(c.time_us() for c in counters)
+        cores = sum(c.core_steps["fwd"] / max(c.samples, 1)
+                    for c in counters)
+        energy = sum(c.core_energy_j(include_ctrl=False) for c in counters) \
+            + hw.core_step_energy_j(time_us, hw.CTRL_MW, cores)
+        return cls(
+            layer=layer, samples=counters[0].samples,
+            frozen_core_steps=sum(c.core_steps["fwd"] - c.core_steps["bwd"]
+                                  for c in counters),
+            time_us=time_us, energy_j=energy,
+            io_j=sum(c.io_energy_j() for c in counters),
+            link_bits_fwd=(link.fwd_bits_total / n if link else 0.0),
+            link_bits_bwd=(link.bwd_bits_total / n if link else 0.0),
+            analytic=analytic)
+
+    def compare_hw(self) -> dict[str, float]:
+        """Relative error of each measured quantity vs the analytic
+        pretraining step (<= 1%), keyed ``pretrain<layer>_<quantity>``."""
+        a = self.analytic
+        n = max(self.samples, 1)
+        pre = f"pretrain{self.layer}_"
+        return {pre + "time": _rel(self.time_us, a.step.time_us),
+                pre + "energy": _rel(self.energy_j, a.step.energy_j),
+                pre + "io": _rel(self.io_j, a.io_energy_j),
+                pre + "frozen_cores": _rel(self.frozen_core_steps / n,
+                                           a.frozen_cores),
+                pre + "link_fwd": _rel(self.link_bits_fwd, a.link_bits_fwd),
+                pre + "link_bwd": _rel(self.link_bits_bwd, a.link_bits_bwd)}
+
+
+def compare_pretrain(pretrain) -> dict[str, float]:
+    """Every pretrained layer's `PretrainReport.compare_hw`, merged."""
+    out: dict[str, float] = {}
+    for p in pretrain:
+        out.update(p.compare_hw())
+    return out
+
+
+@dataclasses.dataclass(frozen=True)
 class SimReport:
     """Per-sample measured costs of the virtual chip (one app)."""
     name: str
@@ -213,6 +280,7 @@ class SimReport:
     throughput_sps: float           # 1 sample per beat at steady state
     routed_per_sample: float
     link_utilization: float
+    pretrain: tuple[PretrainReport, ...] = ()   # one per pretrained layer
 
     @property
     def infer_total_j(self) -> float:
@@ -233,7 +301,8 @@ class SimReport:
         The acceptance contract is |err| <= 1% for train/infer time and
         energy; a violation means either the simulator executed something
         the model does not price or the model claims something the chip
-        does not do."""
+        does not do.  Each pretrained layer adds its step's
+        ``pretrain<layer>_*`` errors (`PretrainReport.compare_hw`)."""
         if cost is None:
             cost = hw.network_cost(self.name, list(self.dims),
                                    pretraining=pretraining)
@@ -250,6 +319,7 @@ class SimReport:
                                     cost.train.energy_j),
                 "train_io": rel(self.train_io_j, cost.io_energy_train_j),
             })
+        out.update(compare_pretrain(self.pretrain))
         return out
 
     def vs_gpu(self) -> dict[str, float]:
@@ -503,6 +573,7 @@ class PipelineReport:
     serve_slot_m: float = 1.0         # samples per serving slot (request
                                       # microbatch, measured)
     analytic: "object | None" = None  # pipeline_cost with matching settings
+    pretrain: tuple[PretrainReport, ...] = ()   # one per pretrained layer
 
     @property
     def cores(self) -> int:
@@ -548,6 +619,7 @@ class PipelineReport:
                                            cost.link_bits_bwd),
                 "span": rel(self.span_us, cost.span_us),
             })
+        out.update(compare_pretrain(self.pretrain))
         return out
 
     def rows(self) -> list[dict]:

@@ -72,6 +72,32 @@ def test_pretraining_core_counts_pinned():
         assert nmap.cores == cores, app
 
 
+@pytest.mark.parametrize("app,golden", [
+    # (path cores, frozen-prefix cores, step time us) per hidden layer
+    ("isolet_ae", [(109, 0, 42.65), (210, 60, 84.41), (180, 130, 72.18),
+                   (164, 150, 67.45), (163, 159, 66.57)]),
+    ("mnist_ae", [(17, 0, 11.06), (14, 9, 9.91), (14, 11, 10.18),
+                  (14, 12, 10.05)]),
+])
+def test_pretrain_step_cost_pinned(app, golden):
+    """One greedy pretraining step per hidden layer: the frozen prefix's
+    forward plus the (encoder, temporary decoder) pair's three phases.
+    The pairs' own parts, summed over the schedule, are the pretraining
+    map's training iteration (`network_cost(pretraining=True)`)."""
+    dims = hw.PAPER_NETWORKS[app]
+    costs = [hw.pretrain_step_cost(app, dims, k)
+             for k in range(len(dims) - 1)]
+    assert [(c.cores, c.frozen_cores) for c in costs] == \
+        [g[:2] for g in golden]
+    for c, g in zip(costs, golden):
+        assert c.step.time_us == pytest.approx(g[2], abs=1e-9)
+        assert c.step.time_us == pytest.approx(
+            c.frozen.time_us + c.pair.time_us, abs=1e-9)
+    whole = hw.network_cost(app, dims, pretraining=True)
+    assert sum(c.pair.time_us for c in costs) == pytest.approx(
+        whole.train.time_us, rel=1e-12)
+
+
 # ---------------------------------------------------------------------------
 # Analytic model outputs (the quantities the <=1% contract compares against)
 # ---------------------------------------------------------------------------

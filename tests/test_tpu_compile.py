@@ -196,3 +196,46 @@ def test_meshed_farm_serve_compiles_for_four_v5e_chips(topo, one_chip,
     assert opcodes.count("all-gather") == 1          # the answers
     assert not {"all-reduce", "all-to-all", "collective-permute",
                 "reduce-scatter"} & set(opcodes)
+
+
+def test_isolet_ae_pretraining_compiles_for_v5e_with_kernel_body(
+        one_chip, monkeypatch):
+    """Hidden layer 1's pretraining step of ``isolet_ae`` at batch 64:
+    the backward program of the modelled chip that holds the frozen
+    encoder 0 and encoder 1 (training from stage 1), and the serial
+    chip's whole step with the autoencoder target; both donate their
+    stacks."""
+    from repro.core import hw_model as hw
+    from repro.sim import ChipPipeline
+    monkeypatch.setattr(kernel_ops, "_default_interpret", lambda: False)
+    dims = hw.PAPER_NETWORKS["isolet_ae"]
+    key = jax.random.PRNGKey(0)
+    pairs = list(zip(dims, dims[1:]))
+    enc = [xb.init_conductances(jax.random.fold_in(key, i), f, o,
+                                PAPER_SPEC) for i, (f, o) in enumerate(pairs)]
+    dec = [xb.init_conductances(jax.random.fold_in(key, 9 + i), o, f,
+                                PAPER_SPEC) for i, (f, o) in enumerate(pairs)]
+    s = one_chip
+    path = ChipPipeline(enc, PAPER_SPEC, n_chips=2,
+                        decoders=dec)._pretrain_path(1)
+    assert (path.groups, path._owner(1)) == (((0, 1), (2,)), (0, 1))
+    chip = VirtualChip(enc, PAPER_SPEC, decoders=dec)
+    for st, cfg, program, data in [
+            (path.chips[0]._get_stacks(), path.chips[0]._cfg,
+             csim.chip_backward, "wave"),
+            (chip._pretrain_path(1)._get_stacks(),
+             chip._pretrain_path(1)._cfg, csim.chip_train, "input")]:
+        cfg = cfg._replace(use_kernels=True)
+        idx = {k: _spec(s, *v.shape, dtype=v.dtype)
+               for k, v in st.index_pytree().items()}
+        g = (_spec(s, *st.g_plus.shape), _spec(s, *st.g_minus.shape))
+        if data == "wave":
+            args = (_spec(s, st.S, 64, st.L), _spec(s, st.S, 64, st.N_pad),
+                    _spec(s, 64, st.N_pad))
+        else:
+            args = (_spec(s, 64, dims[0]), None)
+        compiled = program.lower(*g, *args, idx, cfg, lr_eff=_spec(s),
+                                 first=1).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        assert compiled.memory_analysis().alias_size_in_bytes \
+            >= 2 * st.g_plus.nbytes
