@@ -24,11 +24,12 @@ the farm scales it out the way the ROADMAP's serving story requires:
         view), and (c) summed local outer products == the global one.
 
   * ``FarmServer`` — the batched serving front-end: a
-    `runtime.serve_loop.RequestQueue` drained as one compiled scan over
-    pipeline beats (`compiled.serve_scan`): request ``r`` enters chip
-    ``r % C``'s stage-0 slot at beat ``r // C``, all stages of all chips
-    evaluate in one stacked dispatch per beat, and each beat retires one
-    sample per chip at steady state — Table IV's 0.77 us beat, times N.
+    `runtime.serve_loop.RequestQueue` billed as a pipeline beat schedule
+    (request ``r`` enters chip ``r % C``'s stage-0 slot at beat ``r //
+    C``, and each beat retires one sample per chip at steady state —
+    Table IV's 0.77 us beat, times N), and run on the device as one
+    compiled recognition wave (`compiled.serve_scan`), each chip's
+    requests one batch through its own stacks.
 
   * accounting — per-chip `PhaseCounters` (identical conventions to the
     single chip, so the §5.3 contract holds per replica) plus a
@@ -161,8 +162,6 @@ class ChipFarm:
         self._idx = None                          # index maps of _stacks
         self._held_scalar = (None, None)          # (value, device copy)
         self._stacks_version = -1
-        self._serve_stacks = None                 # (layout, gp_cat, gm_cat)
-        self._serve_stacks_version = -1
 
     # ------------------------------------------------------------------
     # Compiled whole-step executor (repro.sim.compiled, DESIGN.md §8)
@@ -195,18 +194,6 @@ class ChipFarm:
             self._fgm = pad(tuple(self._gm), st.T_max)
             self._stacks_version = self.version
         return self._stacks, self._fgp, self._fgm, self._idx
-
-    def _get_serve_stacks(self):
-        """(layout, gp (C, S*T_max, rows, cols), gm) — the envelope as the
-        serving scan takes it, each chip's stages concatenated on its
-        lane; cut once per conductance version."""
-        if self._serve_stacks_version != self.version:
-            st, gp, gm, _ = self._get_stacks()
-            cat = (self.n_chips, st.S * st.T_max, st.rows, st.cols)
-            self._serve_stacks = (st, jnp.moveaxis(gp, 0, 1).reshape(cat),
-                                  jnp.moveaxis(gm, 0, 1).reshape(cat))
-            self._serve_stacks_version = self.version
-        return self._serve_stacks
 
     def _scatter_back(self, gp, gm, views_plus, views_minus) -> None:
         """Keep the compiled step's donated envelope and hand its
@@ -519,19 +506,19 @@ def build_farm(app: str, n_chips: int, *, seed: int = 0,
 
 
 class FarmServer:
-    """Pipelined serving front-end: the whole session is one compiled
-    scan over beats (`compiled.serve_scan`, DESIGN.md §6.3).
+    """Pipelined serving front-end: on the device the whole session is
+    one compiled recognition wave (`compiled.serve_scan`, DESIGN.md
+    §6.3), chip ``r % C`` serving request ``r``.
 
-    Wavefront schedule (Fig. 2 at farm scale): request ``r`` enters chip
-    ``r % C``'s stage-0 slot at beat ``r // C`` and occupies stage ``s``
-    at beat ``r // C + s``; every beat evaluates all stages of all chips
-    in one stacked dispatch and each beat retires one request per chip at
-    steady state.  Numerics are the wave path's — stages are
-    sample-independent — so served outputs equal `mlp_forward`; what the
-    schedule adds is the *time* structure the farm throughput claim is
-    made from, billed host-side in closed form.  On a mesh each device
-    runs its own chips' lanes.  A server serves the conductance version
-    it was built at and one request microbatch over its lifetime.
+    Wavefront schedule (Fig. 2 at farm scale), billed host-side in closed
+    form: request ``r`` enters chip ``r % C``'s stage-0 slot at beat
+    ``r // C`` and occupies stage ``s`` at beat ``r // C + s``, and each
+    beat retires one request per chip at steady state.  The schedule is
+    the *time* structure the farm throughput claim is made from; stages
+    are sample-independent, so its answers are the wave's, and equal
+    `mlp_forward`.  On a mesh each device runs its own chips' lanes.  A
+    server serves the conductance version it was built at and one
+    request microbatch over its lifetime.
     """
 
     def __init__(self, farm: ChipFarm):
@@ -555,10 +542,9 @@ class FarmServer:
         C, S = self.C, self.S
         with spans.phase(spans.PREPARE):
             csim.check_fresh("FarmServer", self._version, farm.version)
-            st, gp_cat, gm_cat = farm._get_serve_stacks()
+            st, gp, gm, _ = farm._get_stacks()
         Q, m, q_max, n_beats, out = csim.run_serve_session(
-            queue, st, gp_cat, gm_cat, farm.spec, C, self._slot_m,
-            mesh=farm.mesh)
+            queue, st, gp, gm, farm.spec, C, self._slot_m, mesh=farm.mesh)
         self._slot_m = m
         with spans.phase(spans.ACCOUNT):
             farm.serve_sessions += 1

@@ -4,7 +4,7 @@
 The paper's chip has no host in the loop: the whole network step is a
 fixed schedule burned into hardware.  This module is that schedule for
 the *simulator*: each hot loop (recognition wave, training step, farm
-step, serving beat loop) is ONE jitted XLA program whose stage loop is a
+step, serving session) is ONE jitted XLA program whose stage loop is a
 ``lax.scan`` over the padded ragged stage stack
 (`repro.sim.placer.StageStacks`), with
 
@@ -597,106 +597,64 @@ def pad_wave(acts, dps, delta, cfg: ChipConfig):
 
 
 # ---------------------------------------------------------------------------
-# Serving beat loop (farm front-end and pipeline front-end)
+# Serving session (farm front-end and pipeline front-end)
 # ---------------------------------------------------------------------------
 
-def _serve_beats(gp_cat, gm_cat, requests, idx, cfg: ChipConfig,
-                 n_beats: int):
-    """The beat scan of :func:`serve_scan` on ``C`` lanes (C = the stacks'
-    chip axis): request row ``b * C + c`` enters lane ``c`` at beat
-    ``b``."""
-    C = gp_cat.shape[0]
+def _serve_wave(gp, gm, requests, idx, cfg: ChipConfig):
+    """The recognition wave of :func:`serve_scan` on ``C`` lanes (C = the
+    envelope's chip axis, 1 for serial stacks): request ``r`` runs on
+    lane ``r % C``, each lane's requests as one batch of rows."""
+    C = gp.shape[1] if gp.ndim == 5 else 1
     Qp, m, D = requests.shape
-    S, T_max, cols = cfg.S, cfg.T_max, cfg.cols
-    in_flat = idx["in_idx"].reshape(S, T_max * cfg.rows)
-    s_ix = jnp.arange(S)
-    quant_out = (s_ix < S - 1).astype(jnp.float32)[:, None]
-
-    def beat(carry, b):
-        H, out_buf = carry                     # H (C, S, m, L)
-        # inject this beat's requests into every chip's stage-0 slot
-        base_in = jnp.minimum(b * C, Qp - C)
-        block = jax.lax.dynamic_slice(requests, (base_in, 0, 0), (C, m, D))
-        H = H.at[:, 0].set(_embed(block, cfg.L))
-        # one fused dispatch over all (chip, stage, core) slots
-        xs = jnp.take_along_axis(H, in_flat[None, :, None, :], axis=3)
-        xs = jnp.moveaxis(xs.reshape(C, S, m, T_max, cfg.rows), 2, 3)
-        ys = _fwd_dispatch(xs.reshape(C, S * T_max, m, cfg.rows),
-                           gp_cat, gm_cat, cfg)
-        ys = jnp.moveaxis(ys.reshape(C, S, T_max, m, cols), 2, 3)
-        ys_flat = jnp.concatenate(
-            [ys.reshape(C, S, m, T_max * cols),
-             jnp.zeros((C, S, m, 1), jnp.float32)], axis=3)
-        dp = jnp.take_along_axis(
-            ys_flat, idx["dp_idx"][None, :, 0, None, :], axis=3)
-        for i in range(1, cfg.r_max):
-            dp = dp + jnp.take_along_axis(
-                ys_flat, idx["dp_idx"][None, :, i, None, :], axis=3)
-        h = hard_sigmoid(dp)                   # (C, S, m, N_pad)
-        if cfg.transport_quant:
-            hq = (q.adc_quantize(h, cfg.adc_bits)
-                  * idx["valid_out"][None, :, None, :])
-            h = hq * quant_out[None, :, :, None] \
-                + h * (1.0 - quant_out)[None, :, :, None]
-        # retire the last stage's outputs into the result buffer
-        base_out = jnp.clip((b - (S - 1)) * C, 0, Qp - C)
-        out_buf = jax.lax.dynamic_update_slice(
-            out_buf, h[:, S - 1, :, :cfg.out_dim], (base_out, 0, 0))
-        # advance the wavefront one stage hop
-        H = jnp.roll(_embed(h.reshape(C * S, m, cfg.N_pad), cfg.L)
-                     .reshape(C, S, m, cfg.L), 1, axis=1)
-        return (H, out_buf), None
-
-    H0 = jnp.zeros((C, S, m, cfg.L), jnp.float32)
-    out0 = jnp.zeros((Qp, m, cfg.out_dim), jnp.float32)
-    (_, out_buf), _ = jax.lax.scan(beat, (H0, out0),
-                                   jnp.arange(n_beats, dtype=jnp.int32))
-    return out_buf
+    lanes = jnp.moveaxis(requests.reshape(Qp // C, C, m, D), 1, 0)
+    out, _ = _infer(gp, gm, lanes.reshape(C, Qp // C * m, D), idx, cfg)
+    out = jnp.moveaxis(out.reshape(C, Qp // C, m, cfg.out_dim), 0, 1)
+    return out.reshape(Qp, m, cfg.out_dim)
 
 
-@partial(jax.jit, static_argnames=("cfg", "n_beats", "mesh"))
-def serve_scan(gp_cat, gm_cat, requests, idx, cfg: ChipConfig,
-               n_beats: int, mesh=None):
-    """The pipelined serving loop as ONE scan over beats (DESIGN.md §8).
+@partial(jax.jit, static_argnames=("cfg", "mesh"))
+def serve_scan(gp, gm, requests, idx, cfg: ChipConfig, mesh=None):
+    """A serving session as ONE stage-major recognition wave (DESIGN.md
+    §8).
 
-    ``requests`` is (Qp, m, fan_in) with Qp a multiple of the chip count;
-    request ``r`` enters chip ``r % C`` at beat ``r // C`` and retires
-    ``S - 1`` beats later — the static schedule of the serving wavefront.
-    Every beat, ALL stages of ALL chips evaluate in one stacked dispatch
-    over the concatenated (C, S*T_max) core stacks; idle/padding slots
-    drive zeros whose outputs are never read back (their retire rows are
-    overwritten by real retirements or sliced away by the caller).
+    ``gp``/``gm`` are the ordinary envelope, serial ``(S, T_max, rows,
+    cols)`` or the farm's ``(S, C, T_max, rows, cols)``; ``requests`` is
+    (Qp, m, fan_in) with Qp a multiple of the chip count C.  Request ``r``
+    is served by chip ``r % C``: each chip's requests go through the
+    ``S`` stages once, as one batch, in the wave of :func:`chip_infer`.
+    Stages are sample-independent, so the answers are those of the beat
+    wavefront the servers bill (request ``r`` entering at beat ``r //
+    C``), which is replayed host-side and never run on the device.
     Returns the (Qp, m, out_dim) outputs in request order.
 
-    With the farm's ``mesh`` each device runs the same beat scan under
+    With the farm's ``mesh`` each device runs the wave under
     ``shard_map`` on its own chips' lanes: the requests, viewed as
     (Qp / C, C, m, fan_in), split on the lane axis, and the outputs come
     back through the inverse view.
     """
     if mesh is None:
-        _mark("serve_scan", cfg, requests.shape, gp_cat.shape)
-        return _serve_beats(gp_cat, gm_cat, requests, idx, cfg, n_beats)
-    _mark("serve_scan", cfg, requests.shape, gp_cat.shape,
+        _mark("serve_scan", cfg, requests.shape, gp.shape)
+        return _serve_wave(gp, gm, requests, idx, cfg)
+    _mark("serve_scan", cfg, requests.shape, gp.shape,
           tuple(mesh.shape.items()))
-    C = gp_cat.shape[0]
+    C = gp.shape[1]
     Qp, m, D = requests.shape
 
     def body(gp, gm, lanes, idx):
         q_pad, c = lanes.shape[:2]
-        out = _serve_beats(gp, gm, lanes.reshape(q_pad * c, m, D), idx,
-                           cfg, n_beats)
+        out = _serve_wave(gp, gm, lanes.reshape(q_pad * c, m, D), idx, cfg)
         return out.reshape(q_pad, c, m, cfg.out_dim)
 
     lanes = P(None, FARM_AXIS)
     out = jax.shard_map(body, mesh=mesh,
-                        in_specs=(_ROWS, _ROWS, lanes, _ALL),
+                        in_specs=(_CHIPS, _CHIPS, lanes, _ALL),
                         out_specs=lanes, check_vma=False)(
-        gp_cat, gm_cat, requests.reshape(Qp // C, C, m, D), idx)
+        gp, gm, requests.reshape(Qp // C, C, m, D), idx)
     return out.reshape(Qp, m, cfg.out_dim)
 
 
 def _microbatch(x) -> int:
-    """Rows of request input ``x`` as the beat slab takes it (a 1-D
+    """Rows of request input ``x`` as the session slab takes it (a 1-D
     request is one row), read from its host-side shape."""
     shape = np.shape(x)
     return 1 if len(shape) < 2 else shape[0]
@@ -731,20 +689,21 @@ def split_answers(out, q: int):
     return tuple(out[i] for i in range(q)), out[:q].reshape(-1, out.shape[-1])
 
 
-def run_serve_session(queue, stacks, gp_cat, gm_cat, spec, n_lanes: int,
+def run_serve_session(queue, stacks, gp, gm, spec, n_lanes: int,
                       slot_m: int | None = None, mesh=None
                       ) -> tuple[int, int, int, int, jax.Array]:
     """Drain ``queue`` through :func:`serve_scan` (the shared front-end
-    driver of `FarmServer` and `PipelineServer`): request ``r`` enters
-    lane ``r % n_lanes`` at beat ``r // n_lanes``.  Every request must
-    share one microbatch, and the server's earlier sessions' ``slot_m``
-    when given; both are checked from host shapes before anything is
-    popped or launched.  The requests go to the device in one program
-    (:func:`pack_requests`) and their answers come back in one
-    (:func:`split_answers`).  Completes every request in order and
-    returns (requests, microbatch m, q_max, beats, the session's answers
-    as one (requests * m, out_dim) array); the callers replay their own
-    counter/link billing from the same schedule."""
+    driver of `FarmServer` and `PipelineServer`): request ``r`` is served
+    on lane ``r % n_lanes``, and the schedule bills it as entering at
+    beat ``r // n_lanes``.  Every request must share one microbatch, and
+    the server's earlier sessions' ``slot_m`` when given; both are checked
+    from host shapes before anything is popped or launched.  The requests
+    go to the device in one program (:func:`pack_requests`), run as one
+    wave, and their answers come back in one (:func:`split_answers`).
+    Completes every request in order and returns (requests, microbatch m,
+    q_max, beats, the session's answers as one (requests * m, out_dim)
+    array); the callers replay their own counter/link billing from the
+    same schedule."""
     with spans.phase(spans.PREPARE):
         if not queue.pending:
             raise ValueError("a serving session needs at least one request")
@@ -766,19 +725,15 @@ def run_serve_session(queue, stacks, gp_cat, gm_cat, spec, n_lanes: int,
         Q = len(reqs)
         q_max = -(-Q // n_lanes)
         # bucket the lane depth to a power of two so varying queue lengths
-        # share compiled executables (the scan's shapes are static in Qp
-        # and n_beats).  The spare lanes/beats drive zeros and re-inject
-        # the final padded block, whose never-retired junk lands —
-        # clamped — only in rows >= q_max*n_lanes >= Q, all sliced away
-        # below; the REAL schedule (and therefore the billing the callers
-        # replay) is unchanged, so the returned q_max/beats are the
-        # schedule's own.
+        # share compiled executables (the wave's shapes are static in
+        # Qp).  The spare rows are zeros whose answers land only in rows
+        # >= Q, all sliced away below; the schedule the callers bill is
+        # the real one, so the returned q_max/beats are its own.
         q_pad = 1 << (q_max - 1).bit_length()
         x_arr = pack_requests(tuple(r.x for r in reqs), q_pad * n_lanes)
         idx, cfg = stacks.index_pytree(), chip_config(stacks, spec)
     with spans.phase(spans.DISPATCH):
-        out = serve_scan(gp_cat, gm_cat, x_arr, idx, cfg,
-                         stacks.S - 1 + q_pad, mesh=mesh)
+        out = serve_scan(gp, gm, x_arr, idx, cfg, mesh=mesh)
     with spans.phase(spans.WRITEBACK):
         answers, flat = split_answers(out, Q)
         for r, a in zip(reqs, answers):

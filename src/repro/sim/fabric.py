@@ -32,11 +32,12 @@ and accounting:
     priced by `hw_model.schedule_1f1b` from the measured slice times and
     cross-validated against `hw_model.pipeline_cost`;
   * `PipelineServer` — drains a `runtime.serve_loop.RequestQueue` through
-    the chip pipeline at one beat per stage hop, as one compiled scan over
-    beats (`compiled.serve_scan`): a boundary hop rides inside the static
-    routing slot (flagged by ``link_utilization`` when it would not fit),
-    and one sample retires per beat at steady state — the Table IV beat
-    survives the split;
+    the chip pipeline, billed at one beat per stage hop: a boundary hop
+    rides inside the static routing slot (flagged by ``link_utilization``
+    when it would not fit), and one sample retires per beat at steady
+    state — the Table IV beat survives the split.  On the device the
+    session is one recognition wave of all its requests
+    (`compiled.serve_scan`);
   * `ChipPipeline.pretrain_step` — greedy layer-wise autoencoder
     pretraining (sections III.C-E) of a pipeline built with temporary
     decoders: each hidden layer's path (frozen encoders, the encoder, its
@@ -137,19 +138,14 @@ class ChipPipeline(Pretraining):
                 "pretraining places every layer on cores of its own")
         self._hold_decoders(decoders)
 
-    def _get_serve_stacks(self) -> tuple[StageStacks, jax.Array, jax.Array]:
-        """Padded full-placement stacks for the compiled serving scan, and
-        their conductances as the scan takes them ((1, S*T_max, rows,
-        cols), the one lane's stages concatenated) — rebuilt when the
-        fabric's conductances moved (``self.version`` tracks every train
-        step; the chip slices alias the parent's `Stage` objects, so a
-        rebuild always sees their latest writes)."""
+    def _get_serve_stacks(self) -> StageStacks:
+        """Padded full-placement stacks for the compiled serving wave —
+        rebuilt when the fabric's conductances moved (``self.version``
+        tracks every train step; the chip slices alias the parent's
+        `Stage` objects, so a rebuild always sees their latest writes)."""
         if (self._serve_stacks is None
                 or self._serve_stacks_version != self.version):
-            st = build_stage_stacks(self.placement)
-            cat = (1, st.S * st.T_max, st.rows, st.cols)
-            self._serve_stacks = (st, st.g_plus.reshape(cat),
-                                  st.g_minus.reshape(cat))
+            self._serve_stacks = build_stage_stacks(self.placement)
             self._serve_stacks_version = self.version
         return self._serve_stacks
 
@@ -472,18 +468,19 @@ def build_pipeline(app: str, *, max_cores_per_chip: int | None = None,
 class PipelineServer:
     """Pipelined serving front-end over the chip fabric.
 
-    The session is one compiled scan over beats (`compiled.serve_scan`):
-    the fabric is the single-lane (C == 1) case of the farm's beat scan
-    over the FULL placement's stage stacks, a request occupying one
-    global stage per beat.  The boundary quantize rule is the scan's
-    ordinary inter-stage ADC; a sample crossing a chip boundary is
-    metered on the inter-chip link, replayed host-side from the static
-    owner map.  The hop rides inside the beat's static routing slot, so
-    the Table IV beat — and therefore the one-sample-per-beat steady state
-    — survives the split.  Numerics equal the wave path (stages are
-    sample-independent), so served outputs equal `mlp_forward`.  A server
-    serves the conductance version it was built at and one request
-    microbatch over its lifetime."""
+    The session is billed as a beat schedule: a request occupies one
+    global stage per beat, and the hop rides inside the beat's static
+    routing slot, so the Table IV beat — and therefore the
+    one-sample-per-beat steady state — survives the split.  On the device
+    it is one recognition wave (`compiled.serve_scan`): the fabric is the
+    single-lane (C == 1) case of the farm's session, all its requests
+    one batch through the FULL placement's stage stacks.  The boundary
+    quantize rule is the wave's ordinary inter-stage ADC; a sample
+    crossing a chip boundary is metered on the inter-chip link, replayed
+    host-side from the static owner map.  Stages are sample-independent,
+    so the answers are the beat schedule's, and equal `mlp_forward`.  A
+    server serves the conductance version it was built at and one
+    request microbatch over its lifetime."""
 
     def __init__(self, pipe: ChipPipeline):
         self.pipe = pipe
@@ -507,9 +504,9 @@ class PipelineServer:
         S = self.S
         with spans.phase(spans.PREPARE):
             csim.check_fresh("PipelineServer", self._version, pipe.version)
-            st, gp_cat, gm_cat = pipe._get_serve_stacks()
+            st = pipe._get_serve_stacks()
         Q, m, _, n_beats, out = csim.run_serve_session(
-            queue, st, gp_cat, gm_cat, pipe.spec, 1, self._slot_m)
+            queue, st, st.g_plus, st.g_minus, pipe.spec, 1, self._slot_m)
         self._slot_m = m
         with spans.phase(spans.ACCOUNT):
             n = Q * m

@@ -693,6 +693,86 @@ def test_serve_session_answers_are_the_scan_rows(monkeypatch, kind, m):
             np.asarray(out), np.asarray(scans[-1])[:Q, 0])
 
 
+def _server_on(kind):
+    """(owner, server, lanes C): a two-chip pipeline (one lane), or a farm
+    of three replicas whose replica 1 holds other conductances than the
+    rest, so that an answer from the wrong lane reads differently."""
+    from repro.sim.cluster import ChipFarm, FarmServer
+    from repro.sim.fabric import PipelineServer
+    if kind == "pipeline":
+        obj = _build("pipeline")
+        return obj, PipelineServer(obj), 1
+    geo = dict(rows=RAGGED["rows"], cols=RAGGED["cols"])
+    obj = ChipFarm(_layers(RAGGED["dims"]), PAPER_SPEC, n_chips=3, **geo)
+    obj._gp = [g.at[1].set(g[1][..., ::-1]) for g in obj._gp]
+    obj._gm = [g.at[1].set(0.5 * g[1]) for g in obj._gm]
+    obj.version += 1
+    return obj, FarmServer(obj), 3
+
+
+@pytest.mark.parametrize("m", [1, 3])
+@pytest.mark.parametrize("kind", ["pipeline", "farm"])
+def test_serve_session_answers_are_chip_infer_on_their_lane(kind, m):
+    """Five requests (a ragged last lane on the farm): each answer is
+    bitwise `chip_infer` of its request's rows over the same stacks, in
+    lane ``r % C`` — the session is the recognition wave, on the lane the
+    schedule names.  The reference batch is built here from the schedule:
+    lane ``c`` holds its requests' rows in order, zero rows after, as
+    deep as the session's power-of-two lane depth (the contraction's
+    rounding may follow the batch's shape)."""
+    from repro.runtime.serve_loop import RequestQueue
+    obj, server, C = _server_on(kind)
+    Q, D = 5, RAGGED["dims"][0]
+    xs = [_x(RAGGED["dims"], n=m, seed=40 + i) for i in range(Q)]
+    queue = RequestQueue(xs)
+    server.session(queue)
+    if kind == "pipeline":
+        st = obj._get_serve_stacks()
+        gp, gm = st.g_plus, st.g_minus
+    else:
+        st, gp, gm, _ = obj._get_stacks()
+        assert not np.array_equal(np.asarray(gp[:, 1]), np.asarray(gp[:, 0]))
+    q_max = -(-Q // C)
+    depth = 1 << (q_max - 1).bit_length()
+    lanes = np.zeros((C, depth * m, D), np.float32)
+    for r, x in enumerate(xs):
+        lanes[r % C, r // C * m:(r // C + 1) * m] = np.asarray(x)
+    cfg = csim.chip_config(st, PAPER_SPEC)
+    want, _ = csim.chip_infer(gp, gm, jnp.asarray(lanes), st.index_pytree(),
+                              cfg)
+    # every lane's rows moved one lane on: where lane 1 takes part, they
+    # read other conductances, so a wrong lane would give another answer
+    moved, _ = csim.chip_infer(gp, gm, jnp.asarray(np.roll(lanes, 1, 0)),
+                               st.index_pytree(), cfg)
+    want, moved = np.asarray(want), np.asarray(moved)
+    for r, a in enumerate(queue.results()):
+        rows = slice(r // C * m, (r // C + 1) * m)
+        np.testing.assert_array_equal(np.asarray(a), want[r % C, rows])
+        if 1 in (r % C, (r + 1) % C) and C > 1:
+            assert not np.array_equal(np.asarray(a),
+                                      moved[(r + 1) % C, rows])
+
+
+def test_serve_sessions_reuse_one_compile_per_lane_depth():
+    """Sessions of 3 and 64 requests, twice each, and one of 4 (the same
+    power-of-two lane depth as 3): one `serve_scan` compile per lane
+    depth, none per session."""
+    from repro.sim.fabric import ChipPipeline
+    dims = [29, 23, 13, 7]         # a topology no other test compiles
+    pipe = ChipPipeline(_layers(dims, seed=5), PAPER_SPEC, n_chips=2,
+                        rows=20, cols=10)
+    for Q in (3, 64, 3, 4, 64):
+        pipe.serve(_x(dims, n=Q, seed=Q))
+    st = pipe._get_serve_stacks()
+    cfg = csim.chip_config(st, PAPER_SPEC)
+    keys = {k: n for k, n in csim.trace_counts().items()
+            if k[:2] == ("serve_scan", cfg)}
+    assert keys == {("serve_scan", cfg, (4, 1, dims[0]),
+                     st.g_plus.shape): 1,
+                    ("serve_scan", cfg, (64, 1, dims[0]),
+                     st.g_plus.shape): 1}, keys
+
+
 @pytest.mark.parametrize("kind", ["chip", "farm"])
 def test_views_outlive_the_donated_envelope(monkeypatch, kind):
     """Over three donated steps the envelope keeps its buffer, and the

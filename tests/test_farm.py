@@ -503,7 +503,7 @@ assert out.sharding.spec == jax.sharding.PartitionSpec("chips")
 assert np.array_equal(np.asarray(out), np.asarray(one.infer(x)))
 if reconcile == "none":
     assert float(jnp.abs(out - chip.infer(x)).max()) <= 1e-6
-# serving runs the compiled beat scan on the mesh, each device on its
+# serving runs the compiled wave on the mesh, each device on its
 # chips' lanes; M + 3 requests leave lanes idle in the last beat
 q = jax.random.uniform(jax.random.PRNGKey(29), (M + 3, dims[0]),
                        minval=-0.5, maxval=0.5)
@@ -533,7 +533,7 @@ def test_meshed_farm_runs_the_compiled_step(subproc, dims, chips,
     compiled program, sharded: it equals the one-device compiled farm
     bitwise (and the serial chip within re-association), its replicas stay
     in lockstep, it traces once, and stacks and rows stay split.  Its
-    serving sessions run the meshed beat scan and equal the one-device
+    serving sessions run the meshed wave and equal the one-device
     farm's, queue length not a multiple of the chips."""
     out = subproc(MESHED_COMPILED.format(dims=list(dims), chips=chips,
                                          reconcile=reconcile), devices=4)

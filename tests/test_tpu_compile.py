@@ -5,8 +5,10 @@ described (``v5e:2x2``) and not attached.  Each stacked crossbar kernel is
 compiled at the shapes the three phases of ``chip_smoke.py`` dispatch
 (core stacks of 400x100 crossbars), and the whole compiled ``chip_train``
 program of ``mnist_class`` with the Pallas kernel body, on one chip and
-sharded over four, and that four-chip farm's serving scan.  A compile refused
-here (tiling, VMEM, lowering) would be refused on the chip.
+sharded over four, and that four-chip farm's serving session.  A compile
+refused here (tiling, VMEM, lowering) would be refused on the chip.  The
+recognition program of ``mnist_class`` at 1024 rows is pinned to its
+lowered text.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU library at a time.
@@ -26,13 +28,14 @@ ROWS, COLS = 400, 100       # one crossbar core of the stage stacks
 
 # (op, cores T, microbatch M) per dispatch of the chip_smoke phases:
 #   chip mnist_class: 6 cores per stage, infer 1024, train batch 64;
-#   pipeline isolet_class k2: serve scan over 5 stages x 60 cores at one
-#     request per beat, train slices of 40 and 60 cores at batch 16;
-#   farm kdd_anomaly c4: serve scan over 4 chips x 2 stages, train at 16
-#     samples per chip through the reconciled bwd + dw path.
+#   pipeline isolet_class k2: a serving session of 256 requests, one wave
+#     over 60 cores a stage, train slices of 40 and 60 cores at batch 16;
+#   farm kdd_anomaly c4: a serving session of 256 requests, 64 rows on
+#     each of 4 chips' cores, train at 16 samples per chip through the
+#     reconciled bwd + dw path.
 CASES = [
-    ("fwd", 6, 1024), ("fwd", 6, 64), ("fwd", 300, 1), ("fwd", 60, 16),
-    ("fwd", 8, 1), ("fwd", 4, 16),
+    ("fwd", 6, 1024), ("fwd", 6, 64), ("fwd", 60, 256), ("fwd", 60, 16),
+    ("fwd", 4, 64), ("fwd", 4, 16),
     ("train", 6, 64), ("train", 40, 16), ("train", 60, 16),
     ("bwd", 4, 16), ("dw", 4, 16), ("pulse", 6, 64),
 ]
@@ -164,9 +167,10 @@ def test_meshed_mnist_farm_step_compiles_for_four_v5e_chips(topo, one_chip,
 def test_meshed_farm_serve_compiles_for_four_v5e_chips(topo, one_chip,
                                                       monkeypatch):
     """A serving session of four ``mnist_class`` replicas, one per chip of
-    a 2x2 v5e host: one beat scan with the kernel run per chip on its own
-    lanes.  The lanes never meet: the only collectives gather the answers
-    into request order, after the scan."""
+    a 2x2 v5e host: one recognition wave with the kernel run per chip on
+    its own lanes, over the envelope split by chip.  The lanes never
+    meet: the only collective gathers the answers into request order,
+    after the wave."""
     import re
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro.sim import ChipFarm
@@ -177,18 +181,18 @@ def test_meshed_farm_serve_compiles_for_four_v5e_chips(topo, one_chip,
                                    PAPER_SPEC)
               for i, (f, o) in enumerate(zip(dims, dims[1:]))]
     farm = ChipFarm(layers, PAPER_SPEC, n_chips=4)
-    st, _, _, _ = farm._get_stacks()
+    st, gp, _, _ = farm._get_stacks()
+    assert gp.shape == (st.S, 4, st.T_max, st.rows, st.cols)
     cfg = csim.chip_config(st, PAPER_SPEC)._replace(use_kernels=True)
     mesh = Mesh(topo.devices, (csim.FARM_AXIS,))
-    chips = NamedSharding(mesh, P(csim.FARM_AXIS))
+    chips = NamedSharding(mesh, P(None, csim.FARM_AXIS))
     alike = NamedSharding(mesh, P())
     idx = {k: _spec(alike, *v.shape, dtype=v.dtype)
            for k, v in st.index_pytree().items()}
-    slots = (4, st.S * st.T_max, st.rows, st.cols)
     q_pad = 16                     # 64 one-row requests over 4 lanes
     compiled = csim.serve_scan.lower(
-        _spec(chips, *slots), _spec(chips, *slots),
-        _spec(alike, 4 * q_pad, 1, dims[0]), idx, cfg, st.S - 1 + q_pad,
+        _spec(chips, *gp.shape), _spec(chips, *gp.shape),
+        _spec(alike, 4 * q_pad, 1, dims[0]), idx, cfg,
         mesh=mesh).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
@@ -239,3 +243,40 @@ def test_isolet_ae_pretraining_compiles_for_v5e_with_kernel_body(
         assert "tpu_custom_call" in compiled.as_text()
         assert compiled.memory_analysis().alias_size_in_bytes \
             >= 2 * st.g_plus.nbytes
+
+
+#: sha256 of the lowered text of `chip_infer` for ``mnist_class`` at 1024
+#: rows on one v5e, kernel body, source locations left out: the program
+#: of ``mnist_class.chip_infer.b1024``.  A change to it is a change to
+#: that cell's program, and moves this digest on purpose.
+CHIP_INFER_MNIST_1024 = (
+    "e23d17bffae91a2d774ad2a56a5d7740166b769f7562aacbac6ccd55114d19dc")
+
+
+def test_mnist_chip_infer_lowers_to_its_pinned_program(one_chip,
+                                                       monkeypatch):
+    """The recognition program at 1024 rows lowers to the text it had
+    when pinned: serving's programs share its body without moving it."""
+    import hashlib
+    monkeypatch.setattr(kernel_ops, "_default_interpret", lambda: False)
+    dims = NETWORKS["mnist_class"]
+    key = jax.random.PRNGKey(0)
+    layers = [xb.init_conductances(jax.random.fold_in(key, i), f, o,
+                                   PAPER_SPEC)
+              for i, (f, o) in enumerate(zip(dims, dims[1:]))]
+    chip = VirtualChip(layers, PAPER_SPEC)
+    st = chip._get_stacks()
+    s = one_chip
+    idx = {k: _spec(s, *v.shape, dtype=v.dtype)
+           for k, v in st.index_pytree().items()}
+    limit = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        text = csim.chip_infer.lower(
+            _spec(s, *st.g_plus.shape), _spec(s, *st.g_minus.shape),
+            _spec(s, 1024, dims[0]), idx,
+            chip._cfg._replace(use_kernels=True)).as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+    assert "tpu_custom_call" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == CHIP_INFER_MNIST_1024
