@@ -14,9 +14,10 @@ from __future__ import annotations
 import dataclasses
 import math
 
-from repro.core.mapping import (NetworkMap, map_autoencoder_pretraining,
-                                map_network, map_pretrain_path,
-                                split_network)
+from repro.core.mapping import (ConvShape, NetworkMap,
+                                map_autoencoder_pretraining,
+                                map_conv_network, map_network,
+                                map_pretrain_path, split_network)
 
 # ----- paper constants -----------------------------------------------------
 CROSSBAR_EVAL_NS = 20.0            # "crossbar required 20 ns to be evaluated"
@@ -93,6 +94,7 @@ class AppCost:
     infer: PhaseCost
     io_energy_train_j: float
     io_energy_infer_j: float
+    conv: tuple["ConvLayerCost", ...] = ()   # per conv layer, per sample
 
     @property
     def train_total_j(self) -> float:
@@ -168,6 +170,58 @@ def network_cost(name: str, dims: list[int], *, pretraining: bool = False,
         io_energy_train_j=_io_energy(io_bits * 2 + out_bits),
         io_energy_infer_j=_io_energy(io_bits + out_bits),
     )
+
+
+@dataclasses.dataclass(frozen=True)
+class ConvLayerCost:
+    """Per-sample counts of one conv layer (`mapping.map_conv_layer`):
+    its core stepped once per output position in each phase
+    (``core_steps``), the codes it routes to the router
+    (``routed_codes``) and the pooled values the router forms and routes
+    on (``pool_sums``)."""
+    core_steps: int
+    routed_codes: int
+    pool_sums: int
+
+
+def conv_network_cost(name: str, shapes: list[ConvShape], dims: list[int],
+                      *, input_bits: int = 8, rows: int | None = None,
+                      cols: int | None = None) -> AppCost:
+    """Cost one training iteration and one recognition pass of a conv
+    network (`mapping.map_conv_network`) as `network_cost` prices a dense
+    one, with each conv layer's core time-multiplexed: ``positions``
+    serialized core steps of each phase a sample (a dense layer takes
+    one), and its routed codes and the router's pooled values in the
+    routing time.  The control logic burns on every placed core for the
+    whole step.  Off-chip IO is the input map in (twice when training)
+    and the output codes out.  The virtual chip's counters reproduce it
+    to 1% (``tests/test_conv.py``)."""
+    from repro.core.mapping import CORE_COLS, CORE_ROWS
+    rows = CORE_ROWS if rows is None else rows
+    cols = CORE_COLS if cols is None else cols
+    nmap = map_conv_network(list(shapes), list(dims), rows, cols)
+    n = len(nmap.conv)
+    steps = [c.positions for c in nmap.conv] + [1] * (len(nmap.layers) - n)
+    route_us = nmap.routed_outputs / ROUTING_CLOCK_HZ * 1e6
+    train_us = sum(steps) * (FWD_US + BWD_US + UPD_US) + route_us
+    infer_us = sum(steps) * FWD_US + route_us
+    train_j = infer_j = 0.0
+    for k, lm in zip(steps, nmap.layers):
+        infer_j += core_step_energy_j(FWD_US, FWD_MW, k * lm.total_cores)
+        for t, mw in ((FWD_US, FWD_MW), (BWD_US, BWD_MW), (UPD_US, UPD_MW)):
+            train_j += core_step_energy_j(t, mw, k * lm.total_cores)
+        train_j += core_step_energy_j(train_us, CTRL_MW, lm.total_cores)
+    io_bits = nmap.dims[0] * input_bits
+    out_bits = dims[-1] * ADC_BITS_OUT
+    return AppCost(
+        name=name, cores=nmap.cores,
+        train=PhaseCost(train_us, train_j),
+        infer=PhaseCost(infer_us, infer_j),
+        io_energy_train_j=_io_energy(io_bits * 2 + out_bits),
+        io_energy_infer_j=_io_energy(io_bits + out_bits),
+        conv=tuple(ConvLayerCost(c.positions * c.core.total_cores,
+                                 c.routed_codes, c.pool.sums)
+                   for c in nmap.conv))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -18,6 +18,13 @@ The mapper also emits the static routing schedule length (cycles) used by the
 hardware model.  This is the compile-time "who sends what when" table that,
 at pod scale, becomes the XLA SPMD collective schedule (DESIGN.md section 2).
 
+A convolution layer (`map_conv_layer`, DESIGN.md "Conv stages") is one
+core holding the ``k*k*C_in x C_out`` kernel, time-multiplexed: the core
+is stepped once per output position of the feature map, so a sample
+costs ``positions`` core steps per phase and routes ``positions*C_out``
+codes.  The ``pool x pool`` subsampling after it uses no core: the router
+sums each window's codes and forwards the sum (`PoolMap`).
+
 A :class:`NetworkMap` is also the placement contract consumed by the
 executable virtual chip (``repro.sim``, DESIGN.md "Virtual chip"): the sim
 materializes each LayerMap's ``row_tiles x col_tiles`` grid as stacked
@@ -57,11 +64,105 @@ class LayerMap:
 
 
 @dataclasses.dataclass(frozen=True)
+class ConvShape:
+    """One convolution layer: ``out`` maps of a ``k x k`` kernel over an
+    ``in_shape`` (H, W, C) map, stride 1, no padding, then a ``pool x
+    pool`` mean formed in the router.  Maps are flattened row-major,
+    channels last; a patch's lanes are ordered (dy, dx, c)."""
+    in_shape: tuple[int, int, int]
+    k: int
+    out: int
+    pool: int = 1
+
+    @classmethod
+    def from_config(cls, d: dict) -> "ConvShape":
+        """From a configuration entry ``{"in": [H, W, C], "k", "out",
+        "pool"}``."""
+        return cls(tuple(int(v) for v in d["in"]), int(d["k"]),
+                   int(d["out"]), int(d.get("pool", 1)))
+
+    @property
+    def out_hw(self) -> tuple[int, int]:
+        """Output positions of the kernel, (H', W')."""
+        h, w, _ = self.in_shape
+        return h - self.k + 1, w - self.k + 1
+
+    @property
+    def positions(self) -> int:
+        """Core steps per sample: one per output position."""
+        return self.out_hw[0] * self.out_hw[1]
+
+    @property
+    def fan_in(self) -> int:
+        """Lanes of one patch, ``k*k*C_in``."""
+        return self.k * self.k * self.in_shape[2]
+
+    @property
+    def in_size(self) -> int:
+        """Lanes of the input map, flattened."""
+        return self.in_shape[0] * self.in_shape[1] * self.in_shape[2]
+
+    @property
+    def pooled_hw(self) -> tuple[int, int]:
+        h, w = self.out_hw
+        return h // self.pool, w // self.pool
+
+    @property
+    def pooled_size(self) -> int:
+        """Lanes handed to the next layer: the pooled map, flattened."""
+        h, w = self.pooled_hw
+        return h * w * self.out
+
+
+@dataclasses.dataclass(frozen=True)
+class PoolMap:
+    """A ``window x window`` subsampling in the router, on no core: per
+    sample ``sums`` pooled values, each the sum of ``window**2`` codes,
+    each routed on to the next layer's core."""
+    window: int
+    sums: int
+
+
+@dataclasses.dataclass(frozen=True)
+class ConvLayerMap:
+    """A convolution layer placed as one time-multiplexed core
+    (``core``, `map_layer` of the kernel) stepped over ``positions``
+    output positions a sample, routing ``routed_codes`` codes to the
+    router, then its `PoolMap`."""
+    shape: ConvShape
+    core: LayerMap
+    positions: int
+    routed_codes: int
+    pool: PoolMap
+
+    @property
+    def routed_outputs(self) -> int:
+        """Per-sample outputs crossing the routing network: the core's
+        codes and the pooled values."""
+        return self.routed_codes + self.pool.sums
+
+
+@dataclasses.dataclass(frozen=True)
 class NetworkMap:
     layers: tuple[LayerMap, ...]
     cores: int
     routed_outputs: int     # per forward pass
     routing_cycles: int     # 8-bit link, one output per cycle per link
+    conv: tuple[ConvLayerMap, ...] = ()   # leading conv layers (their
+                                          # stage maps lead ``layers``)
+
+    @property
+    def dims(self) -> tuple[int, ...]:
+        """Widths at the layer boundaries: the network input, then each
+        layer's output as the next layer takes it (a conv layer's pooled
+        map, flattened)."""
+        n = len(self.conv)
+        if not n:
+            return (self.layers[0].fan_in,) + tuple(
+                lm.fan_out for lm in self.layers)
+        return ((self.conv[0].shape.in_size,)
+                + tuple(c.shape.pooled_size for c in self.conv)
+                + tuple(lm.fan_out for lm in self.layers[n:]))
 
 
 def map_layer(fan_in: int, fan_out: int, rows: int = CORE_ROWS,
@@ -117,6 +218,50 @@ def map_network(dims: list[int], rows: int = CORE_ROWS,
     cores = sum(l.placed_cores for l in layer_maps)
     routed = sum(l.routed_outputs for l in layer_maps)
     return NetworkMap(tuple(layer_maps), cores, routed, routing_cycles=routed)
+
+
+def map_conv_layer(shape: ConvShape, rows: int = CORE_ROWS,
+                   cols: int = CORE_COLS) -> ConvLayerMap:
+    """A convolution layer's core and pool (module doc).  The kernel must
+    fit one core: a kernel split over cores would need its partial sums
+    aggregated at every position, which neither the mapper nor the sim
+    models."""
+    h, w = shape.out_hw
+    if h < 1 or w < 1 or h % shape.pool or w % shape.pool:
+        raise ValueError(f"{shape}: the pool must tile the {h}x{w} "
+                         f"output map")
+    core = map_layer(shape.fan_in, shape.out, rows, cols)
+    if core.cores != 1:
+        raise NotImplementedError(
+            f"{shape}: a {shape.fan_in}x{shape.out} kernel needs "
+            f"{core.cores} cores; conv layers are placed on one core")
+    P = shape.positions
+    pooled = shape.pooled_size if shape.pool > 1 else 0
+    return ConvLayerMap(shape, core, P, P * shape.out,
+                        PoolMap(shape.pool, pooled))
+
+
+def map_conv_network(shapes: list[ConvShape], dims: list[int],
+                     rows: int = CORE_ROWS, cols: int = CORE_COLS
+                     ) -> NetworkMap:
+    """A conv network: the conv layers ``shapes`` in order, then the
+    dense chain ``dims`` (``dims[0]`` is the last pooled map's size).
+    Each conv layer's stage map is its core with the layer's routed
+    outputs (codes and pooled values); `map_network(dims)` and its
+    callers are untouched."""
+    convs = tuple(map_conv_layer(s, rows, cols) for s in shapes)
+    widths = [c.shape.pooled_size for c in convs]
+    ins = [c.shape.in_size for c in convs[1:]] + [dims[0]]
+    if widths != ins:
+        raise ValueError(f"conv layers hand on {widths} lanes, the next "
+                         f"layers take {ins}")
+    dense = map_network(dims, rows, cols)
+    layers = tuple(dataclasses.replace(c.core,
+                                       routed_outputs=c.routed_outputs)
+                   for c in convs) + dense.layers
+    routed = sum(lm.routed_outputs for lm in layers)
+    return NetworkMap(layers, sum(lm.placed_cores for lm in layers), routed,
+                      routing_cycles=routed, conv=convs)
 
 
 def split_network(nmap: NetworkMap, *, max_cores_per_chip: int | None = None,

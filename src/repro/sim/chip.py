@@ -20,6 +20,12 @@ per layer) plus counters; it runs:
                        the training phases on one (encoder, decoder) pair
                        whose target is the representation it encodes.
 
+A chip built with ``conv=`` holds a conv network (DESIGN.md "Conv
+stages"): its first layers are convolutions, each one core stepped over
+every position of its feature map, with the router's pool after it;
+``infer``, ``train_step`` and ``layers`` run it through the same compiled
+programs, and its numerics match `core.conv`.
+
 Every call runs one compiled program (`repro.sim.compiled`) over the
 placement's padded stage stack: the wave is one ``lax.scan`` over the
 stages, and a training step (wave + reversed bwd/update scan) is ONE
@@ -47,7 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.crossbar import CORE_COLS, CORE_ROWS, CrossbarSpec
-from repro.core.mapping import map_network
+from repro.core.mapping import ConvShape, map_conv_network, map_network
 from repro.core import hw_model as hw
 from repro.sim import compiled as csim
 from repro.sim import spans
@@ -120,7 +126,12 @@ class VirtualChip(Pretraining):
     Given ``decoders`` (one temporary decoder per hidden layer, layer
     ``j``'s of shape ``(dims[j+1], dims[j])``), the chip also holds them
     as placed cores (`placer.place_decoders`) for the whole greedy
-    schedule of `pretrain_step`."""
+    schedule of `pretrain_step`.
+
+    Given ``conv`` (a `mapping.ConvShape` per leading conv layer), the
+    first ``len(conv)`` of ``layers`` are the conv kernels, layer ``l``'s
+    of shape ``(k*k*C_in, C_out)``, and the rest the dense chain after
+    the last pooled map (`mapping.map_conv_network`)."""
 
     def __init__(self, layers: list[dict[str, jax.Array]],
                  spec: CrossbarSpec | None = None, *,
@@ -128,7 +139,8 @@ class VirtualChip(Pretraining):
                  name: str = "app", share_small_layers: bool = False,
                  input_bits: int = 8,
                  placement: Placement | None = None,
-                 faults=None, decoders: list[dict] | None = None):
+                 faults=None, decoders: list[dict] | None = None,
+                 conv: list[ConvShape] | None = None):
         if spec is None:
             from repro.configs.paper_apps import PAPER_SPEC
             spec = PAPER_SPEC
@@ -139,11 +151,18 @@ class VirtualChip(Pretraining):
         self.spec = spec
         self.name = name
         self.input_bits = input_bits
+        if conv and (share_small_layers or decoders is not None
+                     or faults is not None):
+            raise NotImplementedError(
+                "a conv network places every layer on cores of its own, "
+                "trains supervised and models no device faults")
         if placement is None:
-            dims = [int(layers[0]["g_plus"].shape[0])] + \
-                   [int(p["g_plus"].shape[1]) for p in layers]
-            nmap = map_network(dims, rows, cols,
-                               share_small_layers=share_small_layers)
+            dense = layers[len(conv or ()):]
+            dims = [int(dense[0]["g_plus"].shape[0])] + \
+                   [int(p["g_plus"].shape[1]) for p in dense]
+            nmap = (map_conv_network(list(conv), dims, rows, cols) if conv
+                    else map_network(dims, rows, cols,
+                                     share_small_layers=share_small_layers))
             placement = place_network(layers, nmap, rows, cols)
         self.faults = None
         if faults is not None and not faults.is_null:
@@ -211,14 +230,20 @@ class VirtualChip(Pretraining):
         if counters is None:
             return
         with spans.phase(spans.SYNC):
-            slots, steps = (int(v) for v in np.asarray(fcnt))
+            slots, steps, *conv = (int(v) for v in np.asarray(fcnt))
         with spans.phase(spans.ACCOUNT):
             counters.slots["fwd"] += slots
             counters.core_steps["fwd"] += steps
             st = self._get_stacks()
+            routed = list(st.routed)
+            for l in range(len(st.conv)):
+                c_steps, codes, pooled = conv[3 * l:3 * l + 3]
+                counters.record_conv(l, c_steps, codes, pooled,
+                                     st.Ts[l] * M)
+                routed[l] = (codes + pooled) // M
             for s in range(st.S):
                 counters.noc.record(self.placement.stages[s].index,
-                                    st.routed[s], st.Ts[s], M)
+                                    routed[s], st.Ts[s], M)
 
     @staticmethod
     def _apply_bwd_counters(counters: PhaseCounters | None, bcnt) -> None:
@@ -474,6 +499,8 @@ class VirtualChip(Pretraining):
                 else tr.noc.routed_outputs_per_sample(tr.samples)),
             link_utilization=(inf.noc.link_utilization if inf.samples
                               else tr.noc.link_utilization),
+            conv_shapes=tuple(c.shape for c in self.placement.nmap.conv),
+            conv=(tr if tr.samples else inf).conv_per_sample(),
             pretrain=tuple(self._pretrain_report(k) for k in
                            sorted(self._paths)
                            if self._paths[k].train_counters.samples),

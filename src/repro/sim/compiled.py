@@ -124,6 +124,7 @@ class ChipConfig(NamedTuple):
     update_levels: int
     w_max: float
     use_kernels: bool = False
+    conv: tuple = ()      # `mapping.ConvShape` of each leading conv stage
 
 
 def chip_config(stacks, spec) -> ChipConfig:
@@ -139,7 +140,8 @@ def chip_config(stacks, spec) -> ChipConfig:
         error_quant=bool(spec.error_quant), err_bits=int(spec.err_bits),
         update_quant=bool(spec.update_quant),
         max_update=float(spec.max_update),
-        update_levels=int(spec.update_levels), w_max=float(spec.w_max))
+        update_levels=int(spec.update_levels), w_max=float(spec.w_max),
+        conv=stacks.conv)
 
 
 # ---------------------------------------------------------------------------
@@ -223,6 +225,51 @@ def _forward_scan(gp, gm, x, idx, quantize_tail, cfg: ChipConfig):
     return acts, dps, h_last[:, :, 1:cfg.N_pad + 1], cnt
 
 
+def _stage_update(gp_s, gm_s, xs, ds, cfg: ChipConfig, lr_eff):
+    """One stage's bwd + update on its own chip (the serial chip, a
+    pipeline slice, a conv stage): the error through the conductances
+    ``dxs`` (C, T, M, rows) and the pulse-updated stacks.  ``xs`` (C, T,
+    M, rows) are the cores' inputs, ``ds`` (C, T, M, cols) their local
+    errors; the conductances carry the chip axis only on the farm
+    path."""
+    serial = gp_s.ndim == 3          # conductances without a chip axis
+    if cfg.use_kernels:
+        kxs, kds = (xs[0], ds[0]) if serial else (xs, ds)
+        if cfg.update_quant:
+            # fused megakernel: bwd + dw + pulse, conductances read
+            # once (the compiled step's per-stage training body).
+            # The kernel's lr is a compile-time constant, so the
+            # traced lr_eff rides in as a pre-scale on x — x only
+            # feeds the dw contraction here (compute_y=False).
+            _, dxs, gp2, gm2 = kernel_ops.crossbar_train_stacked(
+                gp_s, gm_s, kxs * lr_eff, kds, lr=1.0,
+                max_dw=cfg.max_update,
+                levels=cfg.update_levels, w_max=cfg.w_max,
+                compute_y=False)
+        else:
+            dxs = kernel_ops.crossbar_bwd_stacked(kds, gp_s, gm_s)
+            dw = 2.0 * lr_eff * jnp.einsum("tmk,tmn->tkn", kxs, kds,
+                                           precision=F32)
+            gp2 = jnp.clip(gp_s + 0.5 * dw, 0.0, cfg.w_max)
+            gm2 = jnp.clip(gm_s - 0.5 * dw, 0.0, cfg.w_max)
+        if serial:
+            dxs = dxs[None]
+        return dxs, gp2, gm2
+    # reference-math body (same fused structure, one read of w):
+    # per-chip pulse applied locally, exactly the megakernel math.
+    w = (gp_s - gm_s).astype(jnp.float32)
+    bspec = "tkn" if serial else "ctkn"
+    dxs = jnp.einsum(f"ctmn,{bspec}->ctmk", ds, w, precision=F32)
+    dwe = "ctmk,ctmn->tkn" if serial else "ctmk,ctmn->ctkn"
+    dw = 2.0 * lr_eff * jnp.einsum(dwe, xs, ds, precision=F32)
+    if cfg.update_quant:
+        dw = q.pulse_discretize(dw, cfg.max_update,
+                                cfg.update_levels, None)
+    gp2 = jnp.clip(gp_s + 0.5 * dw, 0.0, cfg.w_max)
+    gm2 = jnp.clip(gm_s - 0.5 * dw, 0.0, cfg.w_max)
+    return dxs, gp2, gm2
+
+
 def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
                    lr_eff, reconcile: str | None,
                    axis_name: str | None = None, first: int = 0):
@@ -266,41 +313,8 @@ def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
         ds = jnp.moveaxis(local_ext[:, :, ds_s], 1, 2)  # (C, T_max, M, cols)
         xs = jnp.moveaxis(act_s[:, :, in_s], 1, 2)      # (C, T_max, M, rows)
 
-        serial = gp_s.ndim == 3          # conductances without a chip axis
-        if reconcile is None and cfg.use_kernels:
-            kxs, kds = (xs[0], ds[0]) if serial else (xs, ds)
-            if cfg.update_quant:
-                # fused megakernel: bwd + dw + pulse, conductances read
-                # once (the compiled step's per-stage training body).
-                # The kernel's lr is a compile-time constant, so the
-                # traced lr_eff rides in as a pre-scale on x — x only
-                # feeds the dw contraction here (compute_y=False).
-                _, dxs, gp2, gm2 = kernel_ops.crossbar_train_stacked(
-                    gp_s, gm_s, kxs * lr_eff, kds, lr=1.0,
-                    max_dw=cfg.max_update,
-                    levels=cfg.update_levels, w_max=cfg.w_max,
-                    compute_y=False)
-            else:
-                dxs = kernel_ops.crossbar_bwd_stacked(kds, gp_s, gm_s)
-                dw = 2.0 * lr_eff * jnp.einsum("tmk,tmn->tkn", kxs, kds,
-                                               precision=F32)
-                gp2 = jnp.clip(gp_s + 0.5 * dw, 0.0, cfg.w_max)
-                gm2 = jnp.clip(gm_s - 0.5 * dw, 0.0, cfg.w_max)
-            if serial:
-                dxs = dxs[None]
-        elif reconcile is None:
-            # reference-math body (same fused structure, one read of w):
-            # per-chip pulse applied locally, exactly the megakernel math.
-            w = (gp_s - gm_s).astype(jnp.float32)
-            bspec = "tkn" if serial else "ctkn"
-            dxs = jnp.einsum(f"ctmn,{bspec}->ctmk", ds, w, precision=F32)
-            dwe = "ctmk,ctmn->tkn" if serial else "ctmk,ctmn->ctkn"
-            dw = 2.0 * lr_eff * jnp.einsum(dwe, xs, ds, precision=F32)
-            if cfg.update_quant:
-                dw = q.pulse_discretize(dw, cfg.max_update,
-                                        cfg.update_levels, None)
-            gp2 = jnp.clip(gp_s + 0.5 * dw, 0.0, cfg.w_max)
-            gm2 = jnp.clip(gm_s - 0.5 * dw, 0.0, cfg.w_max)
+        if reconcile is None:
+            dxs, gp2, gm2 = _stage_update(gp_s, gm_s, xs, ds, cfg, lr_eff)
         else:
             if cfg.use_kernels:
                 dxs = kernel_ops.crossbar_bwd_stacked(ds, gp_s, gm_s)
@@ -342,6 +356,165 @@ def _backward_scan(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
     if first:
         gp_new, gm_new = gp.at[first:].set(gp_new), gm.at[first:].set(gm_new)
     return gp_new, gm_new, delta_fin, cnt
+
+
+# ---------------------------------------------------------------------------
+# Conv stages (DESIGN.md "Conv stages")
+# ---------------------------------------------------------------------------
+#
+# A conv network's first ``len(cfg.conv)`` stages are conv layers, each one
+# core stepped over every position of its feature map.  They do not ride
+# the uniform stage scan, which would pad every dense stage to the conv
+# stages' positions: each runs as its own step of the same program, its
+# positions folded into the rows of the same stacked kernels, and the
+# dense stages after them run the scan on their own part of the envelope.
+
+#: the dense stages' index maps, cut past the conv stages
+_DENSE_IDX = ("in_idx", "ds_idx", "dp_idx", "fold_idx", "prev_idx",
+              "valid_out", "core_counts")
+
+
+def _dense_part(gp, gm, idx, cfg: ChipConfig):
+    """The dense stages after the conv stages as an envelope of their
+    own: (gp, gm, index maps, config)."""
+    n = len(cfg.conv)
+    sub = cfg._replace(S=cfg.S - n, Ts=cfg.Ts[n:], fan_in=cfg.fan_in[n:],
+                       fan_out=cfg.fan_out[n:], conv=())
+    return gp[n:], gm[n:], {k: idx[k][n:] for k in _DENSE_IDX}, sub
+
+
+def _pool(a, s, cfg: ChipConfig):
+    """The router's pool of one conv stage: (C, M*P, out) activations ->
+    (C, M, pooled size).  Each activation leaves its core as a 3-bit
+    code, a window's codes are summed and the next core takes the sum
+    over the window exactly (`core.conv.pool`)."""
+    Ho, Wo = s.out_hw
+    p = s.pool
+    win = (a.shape[0], -1, Ho // p, p, Wo // p, p, s.out)
+    if cfg.transport_quant:
+        levels = 2 ** cfg.adc_bits - 1
+        codes = jnp.round((q.adc_quantize(a, cfg.adc_bits) + 0.5) * levels)
+        v = codes.reshape(win).sum((3, 5)) / (p * p * levels) - 0.5
+    else:
+        v = a.reshape(win).sum((3, 5)) / (p * p)
+    return v.reshape(v.shape[0], v.shape[1], -1)
+
+
+def _unpool(e, s):
+    """The pool's backward pass: (C, M, pooled size) errors -> (C, M*P,
+    out), each lane of a window taking the window's error over the
+    window's size."""
+    C, M = e.shape[0], e.shape[1]
+    Ho, Wo = s.out_hw
+    p = s.pool
+    e = e.reshape(C, M, Ho // p, 1, Wo // p, 1, s.out) / (p * p)
+    e = jnp.broadcast_to(e, (C, M, Ho // p, p, Wo // p, p, s.out))
+    return e.reshape(C, M * s.positions, s.out)
+
+
+def _conv_forward(gp, gm, x, idx, cfg: ChipConfig):
+    """The conv stages' wave on chip-stacked ``x`` (C, M, in size): each
+    stage's patches gathered by its position-indexed map, its core
+    stepped over every position as ONE kernel call with the positions
+    folded into the rows (M*P rows), then the router's pool.  Returns
+    (patches (C, 1, M*P, rows) and dot products (C, M*P, out) of each
+    stage, the last pooled map (C, M, pooled size), conv core steps, and
+    per stage [core steps, routed codes, pool sums])."""
+    if gp.ndim != 4:
+        raise NotImplementedError("conv stages run on the serial chip")
+    C, M = x.shape[0], x.shape[1]
+    h, xs_all, dps, per = x, [], [], []
+    with jax.named_scope("repro.conv"):
+        for l, s in enumerate(cfg.conv):
+            h_ext = jnp.concatenate(
+                [jnp.zeros((C, M, 1), jnp.float32), h], axis=2)
+            xs = h_ext[:, :, idx["conv_patch"][l]].reshape(
+                C, 1, M * s.positions, cfg.rows)
+            dp = _fwd_dispatch(xs, gp[l, :1], gm[l, :1], cfg)[:, 0, :,
+                                                                :s.out]
+            h = _pool(hard_sigmoid(dp), s, cfg)
+            xs_all.append(xs)
+            dps.append(dp)
+            per += [dp.shape[1], dp.shape[1] * dp.shape[2],
+                    h.shape[1] * h.shape[2] if s.pool > 1 else 0]
+    return xs_all, dps, h, sum(per[0::3]), per
+
+
+def _conv_backward(gp, gm, xs_all, dps, delta, idx, cfg: ChipConfig,
+                   lr_eff):
+    """Bwd + update of the conv stages, the last first, from ``delta``
+    (C, M, pooled size), the error entering the last stage's pooled map:
+    the pool's error split over its window, 8-bit codes, the local error
+    through the kernel's derivative, and the fused training body on the
+    stage's core (`_stage_update`) with the positions folded into the
+    rows, so its outer-product sum runs over rows and positions.  The
+    error into the stage below is col2im of the core-line errors, a
+    sequential sum over the transposed map; none is formed below the
+    first stage.  Returns each stage's updated (gp, gm) core stack."""
+    C, M = delta.shape[0], delta.shape[1]
+    new = [None] * len(cfg.conv)
+    with jax.named_scope("repro.conv"):
+        for l in reversed(range(len(cfg.conv))):
+            s = cfg.conv[l]
+            e = _unpool(delta, s)
+            if cfg.error_quant:
+                e = (q.error_quantize(e.reshape(C * M, -1), cfg.err_bits)
+                     .dequantize().reshape(e.shape))
+            local = e * hard_sigmoid_deriv(dps[l])
+            ds = jnp.pad(local, ((0, 0), (0, 0), (0, cfg.cols - s.out)))
+            dxs, gp2, gm2 = _stage_update(gp[l, :1], gm[l, :1], xs_all[l],
+                                          ds[:, None], cfg, lr_eff)
+            # the kernel stays an operation of its own, not fused into
+            # the write of its stacks into the envelope
+            new[l] = jax.lax.optimization_barrier((gp2, gm2))
+            if l:
+                dx = jnp.concatenate(
+                    [dxs[:, 0].reshape(C, M, s.positions * cfg.rows),
+                     jnp.zeros((C, M, 1), jnp.float32)], axis=2)
+                ci = idx["conv_col2im"][l]
+                delta = dx[:, :, ci[:, 0]]
+                for j in range(1, s.k * s.k):
+                    delta = delta + dx[:, :, ci[:, j]]
+    return new
+
+
+def _conv_infer(gp, gm, x, idx, cfg: ChipConfig):
+    """`_infer` of a conv network: the conv stages, then the dense
+    stages' scan on the last pooled map.  The counters are the wave's
+    [slots, core steps] followed by each conv stage's [core steps,
+    routed codes, pool sums]."""
+    _, _, h, steps, per = _conv_forward(gp, gm, x, idx, cfg)
+    gp_d, gm_d, idx_d, cfg_d = _dense_part(gp, gm, idx, cfg)
+    _, dps, _, cnt = _forward_scan(gp_d, gm_d, h, idx_d, jnp.asarray(False),
+                                   cfg_d)
+    cnt = jnp.concatenate([cnt + steps, jnp.asarray(per, jnp.int32)])
+    return hard_sigmoid(dps[-1])[:, :, :cfg.out_dim], cnt
+
+
+def _conv_train(gp, gm, x, target, idx, cfg: ChipConfig, lr_eff):
+    """`_train` of a conv network: the conv stages' wave, the dense
+    stages' forward and reversed bwd/update scans on their own part of
+    the envelope, then the conv stages' bwd/update from the error the
+    dense scan hands back.  Counters as `_conv_infer`'s, and the bwd
+    counters with the conv stages' steps added."""
+    n = len(cfg.conv)
+    xs_all, cdps, h, steps, per = _conv_forward(gp, gm, x, idx, cfg)
+    gp_d, gm_d, idx_d, cfg_d = _dense_part(gp, gm, idx, cfg)
+    acts, dps, _, fcnt = _forward_scan(gp_d, gm_d, h, idx_d,
+                                       jnp.asarray(False), cfg_d)
+    out = hard_sigmoid(dps[-1])
+    tpad = jnp.zeros(out.shape, jnp.float32)
+    delta0 = tpad.at[:, :, :target.shape[2]].set(target) - out
+    gp_d2, gm_d2, d_in, bcnt = _backward_scan(
+        gp_d, gm_d, acts, dps, delta0, idx_d, cfg_d, lr_eff, None)
+    new = _conv_backward(gp, gm, xs_all, cdps,
+                         d_in[:, :, :cfg.conv[-1].pooled_size], idx, cfg,
+                         lr_eff)
+    gp2, gm2 = gp.at[n:].set(gp_d2), gm.at[n:].set(gm_d2)
+    for l, (p, m) in enumerate(new):
+        gp2, gm2 = gp2.at[l, :1].set(p), gm2.at[l, :1].set(m)
+    fcnt = jnp.concatenate([fcnt + steps, jnp.asarray(per, jnp.int32)])
+    return gp2, gm2, delta0[:, :, :cfg.out_dim], fcnt, bcnt + steps
 
 
 def _stage_views(g, cfg: ChipConfig) -> tuple[jax.Array, ...]:
@@ -417,6 +590,7 @@ def chip_forward(gp, gm, x, idx, quantize_tail, cfg: ChipConfig):
     returns arrays without the chip axis.  Counters: int32 [fwd_slots,
     fwd_core_steps] per chip."""
     _mark("chip_forward", cfg, x.shape)
+    _dense_only(cfg, "chip_forward")
     serial = x.ndim == 2
     acts, dps, h, cnt = _forward_scan(
         gp, gm, x[None] if serial else x, idx, quantize_tail, cfg)
@@ -427,9 +601,19 @@ def chip_forward(gp, gm, x, idx, quantize_tail, cfg: ChipConfig):
             h[..., :cfg.out_dim], cnt)
 
 
+def _dense_only(cfg: ChipConfig, program: str) -> None:
+    """Refuse a conv network in a program that runs stage slices (the
+    pipeline's): conv stages run whole, in `chip_train`/`chip_infer`."""
+    if cfg.conv:
+        raise NotImplementedError(f"{program} runs dense stages only; a "
+                                  f"conv network runs chip_train/chip_infer")
+
+
 def _infer(gp, gm, x, idx, cfg: ChipConfig):
     """Recognition wave on chip-stacked ``x`` (C, M, fan_in): (out (C, M,
     out_dim), counters)."""
+    if cfg.conv:
+        return _conv_infer(gp, gm, x, idx, cfg)
     _, dps, _, cnt = _forward_scan(gp, gm, x, idx, jnp.asarray(False), cfg)
     return hard_sigmoid(dps[-1])[:, :, :cfg.out_dim], cnt
 
@@ -442,7 +626,13 @@ def _train(gp, gm, x, target, idx, cfg: ChipConfig, lr_eff, reconcile,
     (`_backward_scan`).  ``target is None`` is the autoencoder target of
     layer-wise pretraining: the activation entering stage ``first`` as
     the wave carried it (the network input at stage 0, the 3-bit codes
-    after), taken inside the program."""
+    after), taken inside the program.  A conv network (``cfg.conv``)
+    trains by `_conv_train`, supervised and on the serial chip only."""
+    if cfg.conv:
+        if reconcile is not None or first or target is None:
+            raise NotImplementedError(
+                "a conv network trains supervised on the serial chip")
+        return _conv_train(gp, gm, x, target, idx, cfg, lr_eff)
     acts, dps, _, fcnt = _forward_scan(
         gp, gm, x, idx, jnp.asarray(False), cfg)
     out = hard_sigmoid(dps[-1])
@@ -567,6 +757,7 @@ def chip_backward(gp, gm, acts, dps, delta, idx, cfg: ChipConfig,
     ``first``, cut to its fan-in."""
     _mark("chip_backward", cfg, delta.shape, reconcile,
           *((first,) if first else ()))
+    _dense_only(cfg, "chip_backward")
     serial = delta.ndim == 2
     if serial:
         acts, dps, delta = acts[:, None], dps[:, None], delta[None]

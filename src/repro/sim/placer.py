@@ -29,6 +29,13 @@ Each network layer becomes one pipeline *stage* (DESIGN.md "Virtual chip"):
     both sides to count the same chip — so the sim executes what the
     mapper prices.
 
+A conv layer (`mapping.map_conv_layer`) is a stage too, of one core: its
+kernel placed like a dense layer's ``(k*k*C_in, C_out)`` matrix.  Its
+index maps are position-indexed (`conv_patch_idx`: im2col as a gather
+of the previous map's lanes) and so are their transposes for the
+backward pass (`conv_col2im_idx`); the pool needs none (DESIGN.md "Conv
+stages").
+
 The placement is mutable state: the compiled training programs hand each
 stage its updated stacks (`StageStacks.scatter_back`), and
 `Placement.extract_params` slices the stacks back into the per-layer
@@ -42,7 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.crossbar import CORE_COLS, CORE_ROWS
-from repro.core.mapping import (LayerMap, NetworkMap,
+from repro.core.mapping import (ConvShape, LayerMap, NetworkMap,
                                 map_autoencoder_pretraining,
                                 map_pretrain_path)
 
@@ -224,13 +231,21 @@ class StageStacks:
     valid_out: jax.Array           # (S, N_pad) float32 {0, 1}
     core_counts: jax.Array         # (S,) int32 (traced counter feed)
     built_version: int = -1
+    # conv stages (the first len(conv) stages; empty for a dense network)
+    conv: tuple[ConvShape, ...] = ()
+    conv_patch: tuple[jax.Array, ...] = ()    # (P, rows) per conv stage
+    conv_col2im: tuple[jax.Array, ...] = ()   # (H*W*C, k*k) per conv stage
 
     def index_pytree(self) -> dict[str, jax.Array]:
         """The traced (non-donated) operands of the compiled programs."""
-        return {"in_idx": self.in_idx, "ds_idx": self.ds_idx,
-                "dp_idx": self.dp_idx, "fold_idx": self.fold_idx,
-                "prev_idx": self.prev_idx, "valid_out": self.valid_out,
-                "core_counts": self.core_counts}
+        idx = {"in_idx": self.in_idx, "ds_idx": self.ds_idx,
+               "dp_idx": self.dp_idx, "fold_idx": self.fold_idx,
+               "prev_idx": self.prev_idx, "valid_out": self.valid_out,
+               "core_counts": self.core_counts}
+        if self.conv:
+            idx["conv_patch"] = self.conv_patch
+            idx["conv_col2im"] = self.conv_col2im
+        return idx
 
     def scatter_back(self, pl: "Placement", views_plus, views_minus) -> None:
         """Hand the compiled step's per-stage views (`compiled.kept_views`
@@ -304,6 +319,7 @@ def build_stage_stacks(pl: Placement) -> StageStacks:
         prev_idx[s, :F] = np.arange(F) + 1
         valid[s, :O] = 1.0
 
+    conv = tuple(c.shape for c in pl.nmap.conv)
     return StageStacks(
         S=S, T_max=T_max, r_max=r_max, c_max=c_max, rows=rows, cols=cols,
         L=L, N_pad=N_pad, out_dim=fan_out[-1], Ts=tuple(Ts),
@@ -315,7 +331,51 @@ def build_stage_stacks(pl: Placement) -> StageStacks:
         dp_idx=jnp.asarray(dp_idx), fold_idx=jnp.asarray(fold_idx),
         prev_idx=jnp.asarray(prev_idx), valid_out=jnp.asarray(valid),
         core_counts=jnp.asarray([st.n_cores for st in stages], jnp.int32),
-        built_version=pl.version)
+        built_version=pl.version, conv=conv,
+        conv_patch=tuple(jnp.asarray(conv_patch_idx(c, rows))
+                         for c in conv),
+        conv_col2im=tuple(jnp.asarray(conv_col2im_idx(c, rows))
+                          for c in conv))
+
+
+def conv_patch_idx(s: ConvShape, rows: int):
+    """im2col as an index map: core line ``l`` of position ``p`` (row
+    major over the H' x W' outputs) reads lane ``idx[p, l]`` of the
+    previous map with its bias slot in front ([0, map]): line 0 is the
+    bias line, lines ``1 .. k*k*C`` the patch in (dy, dx, c) order, and
+    the lines beyond stay on the always-zero slot 0."""
+    import numpy as np
+    H, W, C = s.in_shape
+    Ho, Wo = s.out_hw
+    y, x = np.divmod(np.arange(s.positions), Wo)
+    dy, rest = np.divmod(np.arange(s.fan_in), s.k * C)
+    dx, c = np.divmod(rest, C)
+    lane = ((y[:, None] + dy[None]) * W + x[:, None] + dx[None]) * C + c
+    idx = np.zeros((s.positions, rows), np.int32)
+    idx[:, 1:s.fan_in + 1] = lane + 1
+    return idx
+
+
+def conv_col2im_idx(s: ConvShape, rows: int):
+    """The transpose of `conv_patch_idx` for the backward pass (col2im):
+    lane ``j`` of the previous map sums, in (dy, dx) order, the patch
+    errors ``idx[j, i]`` of the positions that read it through offset
+    ``i = dy*k + dx``, indices into the (P*rows + 1) flattened core-line
+    errors of a sample; an offset that reads no position points at the
+    always-zero last slot."""
+    import numpy as np
+    H, W, C = s.in_shape
+    Ho, Wo = s.out_hw
+    zero = s.positions * rows
+    idx = np.full((s.in_size, s.k * s.k), zero, np.int32)
+    y, x, c = np.unravel_index(np.arange(s.in_size), (H, W, C))
+    for i in range(s.k * s.k):
+        dy, dx = divmod(i, s.k)
+        py, px = y - dy, x - dx
+        ok = (py >= 0) & (py < Ho) & (px >= 0) & (px < Wo)
+        line = 1 + i * C + c
+        idx[ok, i] = ((py * Wo + px) * rows + line)[ok]
+    return idx
 
 
 def place_layer(index: int, params: dict[str, jax.Array], lmap: LayerMap,
@@ -344,12 +404,14 @@ def place_network(layers: list[dict[str, jax.Array]],
 
     ``nmap`` defaults to the unshared `map_network` placement of the layer
     dims; pass a `map_network(..., share_small_layers=True)` map to model
-    loopback packing (same stage execution, fewer placed cores)."""
-    dims = [int(layers[0]["g_plus"].shape[0])] + \
-           [int(p["g_plus"].shape[1]) for p in layers]
+    loopback packing (same stage execution, fewer placed cores), or a
+    `mapping.map_conv_network` map, whose conv layers take the first
+    ``layers`` as their ``(k*k*C_in, C_out)`` kernels."""
     if nmap is None:
         from repro.core.mapping import map_network
-        nmap = map_network(dims, rows, cols)
+        nmap = map_network([int(layers[0]["g_plus"].shape[0])]
+                           + [int(p["g_plus"].shape[1]) for p in layers],
+                           rows, cols)
     if len(nmap.layers) != len(layers):
         raise ValueError(f"NetworkMap has {len(nmap.layers)} layers, "
                          f"params have {len(layers)}")
@@ -367,7 +429,7 @@ def place_network(layers: list[dict[str, jax.Array]],
                 f"layer {i}: {lm.row_tiles} fan-in tiles need multi-level "
                 f"aggregation, which neither the mapper nor the sim models")
         stages.append(place_layer(i, p, lm, rows, cols))
-    return Placement(stages=stages, dims=tuple(dims), rows=rows, cols=cols,
+    return Placement(stages=stages, dims=nmap.dims, rows=rows, cols=cols,
                      nmap=nmap)
 
 

@@ -40,6 +40,10 @@ class PhaseCounters:
     core_steps: dict = dataclasses.field(
         default_factory=lambda: {"fwd": 0, "bwd": 0, "update": 0})
     io_bits: int = 0
+    # conv layer -> its forward counts, all samples: "core_steps" (the
+    # core stepped over every position), "routed_codes", "pool_sums" and
+    # "cores" (the core, once a sample)
+    conv: dict = dataclasses.field(default_factory=dict)
 
     def record_phase(self, phase: str, cores: int, samples: int) -> None:
         """One serialized time slot of ``phase`` on ``cores`` cores for each
@@ -52,6 +56,23 @@ class PhaseCounters:
     def record_io(self, bits: int, samples: int) -> None:
         """Off-chip TSV IO: ``bits`` per sample for ``samples`` samples."""
         self.io_bits += bits * samples
+
+    def record_conv(self, layer: int, core_steps: int, routed_codes: int,
+                    pool_sums: int, cores: int) -> None:
+        """One conv layer's forward counts over a batch (``cores`` its
+        cores times the batch's samples)."""
+        c = self.conv.setdefault(layer, dict.fromkeys(
+            ("core_steps", "routed_codes", "pool_sums", "cores"), 0))
+        for k, v in (("core_steps", core_steps), ("routed_codes",
+                     routed_codes), ("pool_sums", pool_sums),
+                     ("cores", cores)):
+            c[k] += v
+
+    def conv_per_sample(self) -> tuple[dict, ...]:
+        """Each conv layer's forward counts per sample, in layer order."""
+        n = max(self.samples, 1)
+        return tuple({k: v / n for k, v in self.conv[l].items()}
+                     for l in sorted(self.conv))
 
     # ---- per-sample derived quantities ---------------------------------
 
@@ -76,9 +97,12 @@ class PhaseCounters:
         if include_ctrl:
             # control logic burns CTRL_MW on every core of every placed
             # layer for the whole step; the per-sample fwd core-steps ARE
-            # sum(total_cores) over layers, measured.
+            # sum(total_cores) over layers, measured, once a conv core's
+            # steps over its positions count as its cores.
+            cores = self.core_steps["fwd"] - sum(
+                c["core_steps"] - c["cores"] for c in self.conv.values())
             e += hw.core_step_energy_j(self.time_us(), hw.CTRL_MW,
-                                       self.core_steps["fwd"] / n)
+                                       cores / n)
         return e
 
     def io_energy_j(self) -> float:
@@ -281,6 +305,9 @@ class SimReport:
     routed_per_sample: float
     link_utilization: float
     pretrain: tuple[PretrainReport, ...] = ()   # one per pretrained layer
+    conv_shapes: tuple = ()         # `mapping.ConvShape` of each conv layer
+    conv: tuple[dict, ...] = ()     # per conv layer, per sample (training's
+                                    # counters when training ran)
 
     @property
     def infer_total_j(self) -> float:
@@ -303,7 +330,11 @@ class SimReport:
         the model does not price or the model claims something the chip
         does not do.  Each pretrained layer adds its step's
         ``pretrain<layer>_*`` errors (`PretrainReport.compare_hw`)."""
-        if cost is None:
+        if cost is None and self.conv_shapes:
+            cost = hw.conv_network_cost(
+                self.name, list(self.conv_shapes),
+                list(self.dims[len(self.conv_shapes):]))
+        elif cost is None:
             cost = hw.network_cost(self.name, list(self.dims),
                                    pretraining=pretraining)
         rel = _rel
@@ -320,6 +351,15 @@ class SimReport:
                 "train_io": rel(self.train_io_j, cost.io_energy_train_j),
             })
         out.update(compare_pretrain(self.pretrain))
+        for l, want in enumerate(cost.conv):
+            got = (self.conv[l] if l < len(self.conv) else
+                   {"core_steps": 0, "routed_codes": 0, "pool_sums": 0})
+            out.update({f"conv{l}_core_steps": rel(got["core_steps"],
+                                                   want.core_steps),
+                        f"conv{l}_routed_codes": rel(got["routed_codes"],
+                                                     want.routed_codes),
+                        f"conv{l}_pool_sums": rel(got["pool_sums"],
+                                                  want.pool_sums)})
         return out
 
     def vs_gpu(self) -> dict[str, float]:

@@ -5,7 +5,8 @@ described (``v5e:2x2``) and not attached.  Each stacked crossbar kernel is
 compiled at the shapes the three phases of ``chip_smoke.py`` dispatch
 (core stacks of 400x100 crossbars), and the whole compiled ``chip_train``
 program of ``mnist_class`` with the Pallas kernel body, on one chip and
-sharded over four, and that four-chip farm's serving session.  A compile
+sharded over four, that four-chip farm's serving session, and LeNet-5's
+``chip_train`` with its conv stages.  A compile
 refused here (tiling, VMEM, lowering) would be refused on the chip.  The
 recognition program of ``mnist_class`` at 1024 rows is pinned to its
 lowered text.
@@ -122,6 +123,39 @@ def test_mnist_chip_train_compiles_for_v5e_with_kernel_body(one_chip,
     mem = compiled.memory_analysis()
     # the conductance stacks are donated: updated in place, not copied
     assert mem.alias_size_in_bytes >= 2 * st.g_plus.nbytes
+
+
+def test_lenet5_chip_train_compiles_for_v5e_with_kernel_body(one_chip,
+                                                             monkeypatch):
+    """LeNet-5's training step at batch 64, the program of
+    ``lenet5_mnist.chip_conv_train.b64``: the conv stages' kernels at
+    their folded rows (50,176 for C1, 6,400 for C3) beside the dense
+    stages' scan, one program, the stacks donated."""
+    import jax.numpy as jnp
+
+    from repro.core import conv as cref
+    monkeypatch.setattr(kernel_ops, "_default_interpret", lambda: False)
+    shapes, dims = list(cref.LENET5_CONV), list(cref.LENET5_DIMS)
+    widths = [(c.fan_in, c.out) for c in shapes] + list(zip(dims, dims[1:]))
+    layers = [{"g_plus": jnp.zeros(w), "g_minus": jnp.zeros(w)}
+              for w in widths]
+    chip = VirtualChip(layers, PAPER_SPEC, conv=shapes)
+    st = chip._get_stacks()
+    cfg = chip._cfg._replace(use_kernels=True)
+    s = one_chip
+    idx = jax.tree.map(lambda v: _spec(s, *v.shape, dtype=v.dtype),
+                       st.index_pytree())
+    compiled = csim.chip_train.lower(
+        _spec(s, *st.g_plus.shape), _spec(s, *st.g_minus.shape),
+        _spec(s, 64, 32 * 32), _spec(s, 64, dims[-1]), idx, cfg,
+        lr_eff=_spec(s)).compile()
+    text = compiled.as_text()
+    # conv fwd and train kernels of each conv stage, the dense scan's two
+    assert text.count("tpu_custom_call") == 6
+    # one core: the kernels take the folded rows without a core axis
+    assert "f32[50176,400]" in text and "f32[6400,400]" in text
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * st.g_plus.nbytes
 
 
 def test_meshed_mnist_farm_step_compiles_for_four_v5e_chips(topo, one_chip,
